@@ -2,7 +2,14 @@
 processes over the shared-directory heartbeat transport against one
 synthetic library and report wall-clock GiB/s. One JSON line per run on
 stdout: {"nproc", "rep", "seconds", "gib_per_sec", "pieces", "valid",
-"per_process", "fleet_bottleneck"}.
+"device", "per_process", "fleet_bottleneck"}.
+
+This launcher never imports JAX: with ``--hasher tpu`` each worker is
+handed its own chip through its environment (``utils.device.worker_env``).
+``device`` is what worker 0's JAX reported, not the flag, every entry of
+``per_process`` carries its own worker's, and the run fails unless each
+is one TPU chip: more workers than chips is refused by the surplus
+worker's fatal backend init, never measured on the host's CPU.
 
 ``per_process`` embeds every worker's pipeline-ledger breakdown (stage
 busy/bytes/utilization, bottleneck verdict, overlap) straight from its
@@ -60,11 +67,14 @@ def build_library(root: str, n_torrents: int, mb_per: int, piece_kb: int):
 
 
 def run_once(tdir, ddir, hb, nproc, hasher, batch_target):
-    env = dict(os.environ)
-    env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
+    from torrent_tpu.utils.device import worker_env
+
+    base = dict(os.environ)
+    base["PYTHONPATH"] = REPO + os.pathsep + base.get("PYTHONPATH", "")
+    errs = [open(os.path.join(hb, f"worker_{p}.err"), "w+") for p in range(nproc)]
     t0 = time.perf_counter()
-    workers = [
-        subprocess.Popen(
+    workers = {
+        p: subprocess.Popen(
             [
                 sys.executable, "-m", "torrent_tpu", "fabric-verify",
                 tdir, ddir, "--hasher", hasher,
@@ -72,40 +82,53 @@ def run_once(tdir, ddir, hb, nproc, hasher, batch_target):
                 "--heartbeat-dir", hb, "--batch-target", str(batch_target),
                 "--result-file", os.path.join(hb, f"result_{p}.json"),
             ],
-            env=env, stdout=subprocess.DEVNULL, stderr=subprocess.PIPE, text=True,
+            env=worker_env(base, hasher, p),
+            stdout=subprocess.DEVNULL, stderr=errs[p],
         )
         for p in range(nproc)
-    ]
+    }
     try:
-        for p, w in enumerate(workers):
-            _, err = w.communicate(timeout=3600)
-            if w.returncode != 0:
-                raise RuntimeError(f"worker {p} rc={w.returncode}: {err[-1500:]}")
+        # the first worker to fail fails the run at once: its peers would
+        # otherwise adopt its units and bank a rate for fewer processes
+        running = dict(workers)
+        while running:
+            for p, w in list(running.items()):
+                if w.poll() is None:
+                    continue
+                del running[p]
+                if w.returncode != 0:
+                    errs[p].seek(0)
+                    raise RuntimeError(
+                        f"worker {p} rc={w.returncode}: {errs[p].read()[-1500:]}"
+                    )
+            if time.perf_counter() - t0 > 3600:
+                raise RuntimeError(f"workers {sorted(running)} still running after 3600 s")
+            time.sleep(0.01)
     finally:
-        for w in workers:
+        for w in workers.values():
             if w.poll() is None:
                 w.kill()
-                w.communicate()
+                w.wait()
+        for f in errs:
+            f.close()
     seconds = time.perf_counter() - t0
-    rec = json.load(open(os.path.join(hb, "result_0.json")))
+    # every worker's result file: its device as its own JAX reported it,
+    # and its pipeline-ledger attribution, so the rung's record explains
+    # its rate instead of just banking it
+    recs = [json.load(open(os.path.join(hb, f"result_{p}.json"))) for p in range(nproc)]
+    rec = recs[0]
     if rec["n_valid"] != rec["n_pieces"]:
         raise RuntimeError(f"incomplete verify: {rec['n_valid']}/{rec['n_pieces']}")
-    # per-process ledger/overlap breakdowns: every worker's result file
-    # embeds its own attribution report (fabric-verify writes it), so
-    # the rung's record explains its rate instead of just banking it
     per_process = []
-    for p in range(nproc):
-        if p == 0:
-            wrec = rec  # already loaded (and rate-checked) above
-        else:
-            try:
-                wrec = json.load(open(os.path.join(hb, f"result_{p}.json")))
-            except (OSError, ValueError):
-                continue
+    for p, wrec in enumerate(recs):
+        device = wrec.get("device") or {}
+        if hasher != "cpu" and (device.get("platform"), device.get("count")) != ("tpu", 1):
+            raise RuntimeError(f"worker {p} did not hash on its own chip: device={device}")
         led = wrec.get("ledger") or {}
         per_process.append(
             {
                 "pid": wrec.get("pid", p),
+                "device": device,
                 "pieces_verified": wrec.get("pieces_verified"),
                 "units_done": wrec.get("units_done"),
                 "units_adopted": wrec.get("units_adopted"),
@@ -156,6 +179,7 @@ def main() -> int:
                     "valid": rec["n_valid"],
                     "plan": rec["plan"],
                     "hasher": args.hasher,
+                    "device": rec.get("device"),
                     "per_process": rec.get("per_process", []),
                     "fleet_bottleneck": fleet.get("bottleneck"),
                 }

@@ -1,22 +1,21 @@
-"""Summarize the live bank — markdown table or machine-readable trajectory.
+"""Summarize a record bank — markdown table or machine-readable trajectory.
 
-Default mode walks `.bench/live/<metric>.json` (the stable best-record
-names the driver's replay reads) plus the loose `.bench/*.json` rung
-artifacts, and prints one row per metric with value, vs_baseline,
-measurement shape, platform, and when/where it was measured — so a
-reviewer can check every performance claim against its artifact in one
-look.
+The bank is the directory this script sits in. Default mode walks its
+`live/<metric>.json` (stable best-record names) plus the loose `*.json`
+rung artifacts beside the script, and prints one row per metric with
+value, vs_baseline, measurement shape, platform, and when/where it was
+measured — so a reviewer can check every performance claim against its
+artifact in one look.
 
 ``--trajectory [OUT]`` instead aggregates EVERY banked record — the
 stable live names, their timestamped audit copies (the per-metric
 history), and the loose rung artifacts — into one machine-readable
 ``BENCH_trajectory.json`` (schema ``torrent-tpu-bench-trajectory/1``)
 for the ``torrent-tpu bench --compare`` regression gate. Shape caveats
-are preserved: a record carrying a ``like_for_like`` annotation (the
-BENCH_CONFIGS_r05 discipline — e.g. the B=512 narrow-batch record that
-must not be compared to the B=8192 flagship) is marked
-``non_like_for_like: true`` so the comparator never gates across
-shapes.
+are preserved: a record carrying a ``like_for_like`` annotation (e.g. a
+B=512 narrow-batch record that must not be compared to the B=8192
+flagship) is marked ``non_like_for_like: true`` so the comparator never
+gates across shapes.
 
 Usage:
   python .bench/summarize.py [--all]          markdown table (--all
@@ -66,14 +65,13 @@ def _normalize(rec: dict, artifact: str) -> dict:
         "platform": rec.get("platform"),
         "banked_at_utc": _when(rec),
         "artifact": artifact,
-        # a like_for_like annotation exists ONLY to caveat a shape
-        # (BENCH_CONFIGS_r05): its PRESENCE means "do not gate other
-        # shapes against this record" (an author writing
-        # `"like_for_like": false` means exactly that too)
+        # a like_for_like annotation exists ONLY to caveat a shape: its
+        # PRESENCE means "do not gate other shapes against this record"
+        # (an author writing `"like_for_like": false` means that too)
         "non_like_for_like": "like_for_like" in rec,
     }
     for key in ("shape", "like_for_like", "provenance", "pre_median_contract",
-                "replayed", "status", "n_runs", "spread", "end_to_end_pps",
+                "status", "n_runs", "spread", "end_to_end_pps",
                 "h2d_mib_s", "rung", "ledger",
                 # the controller A/B record schema (bench controller):
                 # both sides of the A/B, the throttle that framed it,
@@ -189,8 +187,8 @@ def main() -> None:
         if name.count(".") > 1:
             continue
         rec = _load(path)
-        # same null filter as the --all branch: a null/tpu_unavailable
-        # record landing in live/ must never print as the current best
+        # same null filter as the --all branch: a null record landing
+        # in live/ must never print as the current best
         if rec and rec.get("value") is not None:
             rows.append((rec, "live/" + name))
     if "--all" in args:

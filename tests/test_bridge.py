@@ -137,6 +137,40 @@ class TestForeignClientCurl:
         run(go())
 
 
+def test_info_names_the_device_from_jax_not_from_the_flag():
+    """`--hasher tpu` is a strategy; what it runs on is whatever JAX
+    resolved. /v1/info reports platform, device_kind and count as JAX
+    names them (probed off-loop), and a hashlib bridge claims no device."""
+    import jax
+
+    async def info_of(hasher):
+        server = await _start(hasher)
+        try:
+            for _ in range(200):
+                reader, writer = await asyncio.open_connection("127.0.0.1", server.port)
+                writer.write(b"GET /v1/info HTTP/1.1\r\nHost: x\r\n\r\n")
+                await writer.drain()
+                raw = await reader.read()
+                writer.close()
+                info = bdecode(raw.split(b"\r\n\r\n", 1)[1])
+                if info[b"platform"]:
+                    return info
+                await asyncio.sleep(0.05)
+            raise AssertionError("device probe never landed")
+        finally:
+            server.close()
+            await server.wait_closed()
+
+    tpu = run(info_of("tpu"))
+    d = jax.devices()[0]
+    assert tpu[b"backend"] == b"tpu"  # the flag, unchanged
+    assert tpu[b"platform"] == d.platform.encode() == b"cpu"
+    assert tpu[b"device_kind"] == d.device_kind.encode()
+    assert tpu[b"devices"] == len(jax.devices())
+    cpu = run(info_of("cpu"))
+    assert (cpu[b"platform"], cpu[b"device_kind"], cpu[b"devices"]) == (b"cpu", b"hashlib", 0)
+
+
 def _frames(pieces, expected=None):
     out = bytearray()
     for i, p in enumerate(pieces):

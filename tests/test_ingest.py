@@ -476,6 +476,23 @@ class TestLedgerAcceptance:
         assert rec["staging_outstanding"] == 0
         assert rec["ledger"]["stages"].get("stage", {}).get("bytes", 0) == 0
         assert "overlap" in rec["ledger"]
+        # hashlib on the host: no JAX device is claimed
+        assert (rec["platform"], rec["device_kind"], rec["plane"]) == (
+            "cpu", "hashlib", "cpu")
+
+    def test_bench_e2e_names_the_platform_from_jax_not_the_flag(self):
+        """`--hasher tpu` on a CPU-only host runs XLA-CPU; the record
+        must say so (a banked record once wrote the flag there and was
+        read as a chip number)."""
+        import jax
+
+        from torrent_tpu.tools.bench_cli import _e2e
+
+        rec = run(_e2e(2, 256, 4, "tpu"))
+        assert rec["value"] is not None and rec["valid"] == rec["pieces"]
+        assert rec["plane"] == "tpu"
+        assert rec["platform"] == jax.devices()[0].platform == "cpu"
+        assert rec["device_kind"] == jax.devices()[0].device_kind
 
 
 class TestStagedSha256:

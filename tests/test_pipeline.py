@@ -549,15 +549,44 @@ class TestTopRendering:
 
 
 class TestTrajectoryAggregation:
+    @staticmethod
+    def _bank(tmp_path):
+        """A record bank under tmp_path with the aggregator beside it
+        (summarize.py's bank is the directory it sits in): two stable
+        live records — one carrying a shape caveat —, a timestamped
+        audit copy, a loose rung artifact and a null that must be
+        filtered."""
+        import shutil
+
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        bank = tmp_path / "bank"
+        (bank / "live").mkdir(parents=True)
+        script = bank / "summarize.py"
+        shutil.copy(os.path.join(repo, ".bench", "summarize.py"), script)
+        metric = "sha1_recheck_256KiB_pieces_per_sec"
+        wide = {"metric": metric, "value": 137804.6, "unit": "pieces/s",
+                "vs_baseline": 24.11, "platform": "tpu", "batch": 8192,
+                "banked_at_utc": "2026-07-30T07:10:51Z"}
+        narrow = {**wide, "value": 246511.0, "batch": 512,
+                  "banked_at_utc": "2026-08-02T15:50:39Z",
+                  "like_for_like": "B=512 x 24 dispatches; not the B=8192 shape"}
+        (bank / "live" / f"{metric}.json").write_text(json.dumps(wide))
+        (bank / "live" / f"{metric}.20260802T155039Z.json").write_text(
+            json.dumps(narrow))
+        (bank / "cfg_author.json").write_text(json.dumps(
+            {"metric": "sha1_author_256KiB_pieces_per_sec", "value": 133480.8,
+             "unit": "pieces/s", "platform": "tpu"}))
+        (bank / "null.json").write_text(json.dumps(
+            {"metric": metric, "value": None, "unit": "pieces/s"}))
+        return str(script)
+
     def test_summarize_trajectory_marks_shape_caveats(self, tmp_path):
-        """.bench/summarize.py --trajectory aggregates the live bank
-        into one machine-readable file, preserving the BENCH_CONFIGS_r05
-        like-for-like caveats."""
+        """summarize.py --trajectory aggregates a bank into one
+        machine-readable file, preserving like-for-like caveats."""
         repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
         out = str(tmp_path / "traj.json")
         proc = subprocess.run(
-            [sys.executable, os.path.join(repo, ".bench", "summarize.py"),
-             "--trajectory", out],
+            [sys.executable, self._bank(tmp_path), "--trajectory", out],
             capture_output=True, text=True, timeout=60,
         )
         assert proc.returncode == 0, proc.stderr
@@ -565,15 +594,13 @@ class TestTrajectoryAggregation:
             data = json.load(f)
         assert data["schema"] == "torrent-tpu-bench-trajectory/1"
         recs = data["records"]
-        assert recs, "no records aggregated"
+        assert len(recs) == 3, recs  # the null record is filtered
         assert all(r["value"] is not None for r in recs)
         # the B=512 narrow-batch record carries its shape caveat
         caveated = [r for r in recs if r["non_like_for_like"]]
-        assert any(
-            r["metric"] == "sha1_recheck_256KiB_pieces_per_sec"
-            and r.get("batch") == 512
-            for r in caveated
-        ), recs
+        assert [(r["metric"], r["batch"]) for r in caveated] == [
+            ("sha1_recheck_256KiB_pieces_per_sec", 512)
+        ], recs
         # the committed trajectory matches the aggregator's schema
         committed = os.path.join(repo, "BENCH_trajectory.json")
         with open(committed) as f:
@@ -581,11 +608,10 @@ class TestTrajectoryAggregation:
 
     def test_regeneration_preserves_self_banked_records(self, tmp_path):
         """`bench --bank` records exist only in the trajectory file;
-        regenerating it from the .bench bank must merge them back or
-        the CI comparator they armed is silently disarmed."""
+        regenerating it from a bank must merge them back or the CI
+        comparator they armed is silently disarmed."""
         from torrent_tpu.tools import bench_cli
 
-        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
         out = str(tmp_path / "traj.json")
         banked = {"metric": "sha1_recheck_smoke_256KiB_pieces_per_sec",
                   "value": 3000.0, "unit": "pieces/s", "platform": "cpu",
@@ -593,13 +619,12 @@ class TestTrajectoryAggregation:
                   "schema": "torrent-tpu-bench/1"}
         bench_cli.bank_record(banked, out)
         proc = subprocess.run(
-            [sys.executable, os.path.join(repo, ".bench", "summarize.py"),
-             "--trajectory", out],
+            [sys.executable, self._bank(tmp_path), "--trajectory", out],
             capture_output=True, text=True, timeout=60,
         )
         assert proc.returncode == 0, proc.stderr
         records = bench_cli.load_trajectory(out)
         kept = [r for r in records if r["metric"] == banked["metric"]]
         assert kept and kept[0]["value"] == 3000.0, records
-        # and aggregated .bench records are present alongside it
+        # and the bank's aggregated records are present alongside it
         assert any(r.get("artifact") for r in records)

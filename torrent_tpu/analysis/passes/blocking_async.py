@@ -2,7 +2,7 @@
 
 The PR 3 / PR 4 hazard class: a coroutine that calls ``time.sleep``,
 does sync file or socket IO, blocks on a ``Future.result()``, probes
-``jax.devices()`` (can hang for minutes behind a wedged device tunnel)
+``jax.devices()`` (backend init: seconds on a TPU, longer on a busy one)
 or dispatches jitted work stalls the WHOLE serving loop — every
 concurrent connection, heartbeat, and deadline timer stops with it.
 

@@ -13,15 +13,15 @@ plane; the merkle levels above them reduce one ``sha256_pairs`` dispatch
 per level per shape group across ALL files (``roots_batched``).
 ``hasher='cpu'`` is device-free END TO END — hashlib leaves AND hashlib
 merkle folds (``_root_cpu``) — so an explicitly-CPU author/verify never
-touches the jax backend (on hosts whose default device is remote or
-wedged, the first dispatch would hang). The independent spec oracle
-lives in tests/test_v2.py.
+touches the jax backend (and so never takes the chip from the process
+that holds it). The independent spec oracle lives in tests/test_v2.py.
 """
 
 from __future__ import annotations
 
 import functools
 import hashlib
+import time
 
 import numpy as np
 
@@ -41,11 +41,8 @@ from torrent_tpu.ops.sha256_jax import make_sha256_fn
 from torrent_tpu.utils.env import env_int
 
 # Leaf blocks hashed per device launch: 32768 × 16 KiB = 512 MiB
-# staging. Dispatch size is the dominant throughput knob on a remote
-# device (a ~55 ms fixed per-dispatch cost swamps 64 MiB launches —
-# measured 1.9 GiB/s at 4096 leaves vs the kernel's much higher
-# sustained rate); memory-constrained hosts can dial it back via the
-# env knob.
+# staging (chosen on a retired setup, not measured on this one);
+# memory-constrained hosts can dial it back via the env knob.
 LEAF_BATCH = env_int("TORRENT_TPU_LEAF_BATCH", 32768)
 
 # A "source" is either resident bytes or a filesystem path (str) that is
@@ -102,32 +99,54 @@ def _make_leaf_fn(b: int, backend: str):
     """SHA-256 fn for a ``b``-row leaf batch; ``auto`` prefers Pallas.
 
     The pallas kernel pads launches to a ``tile_sub*128``-row multiple and
-    only compiles for real (non-interpret) on TPU-kind devices — anywhere
-    else (CPU, GPU, or a jax without pallas at all) the scan backend
-    wins. tile_sub is a call parameter now, so any 1024-row-multiple
-    batch qualifies: pick the largest sublane count that divides ``b``
-    (pow-2 bucketed batches of 1024/2048 rows keep the fast path at
-    tile_sub 8/16 instead of silently falling back to the scan backend).
+    only compiles for real (non-interpret) on the TPU platform — anywhere
+    else the scan backend wins. Any 1024-row-multiple batch qualifies:
+    pick the largest sublane count that divides ``b`` (pow-2 bucketed
+    batches of 1024/2048 rows keep the fast path at tile_sub 8/16); a
+    smaller batch drops to the scan backend. Returns ``(fn, kernel)``:
+    ``kernel`` names which it is (``"pallas"`` or ``"scan"``), and every
+    launch is counted under that name (:data:`LEAF_LAUNCH_HIST`).
     """
     if backend == "auto":
-        try:
-            from torrent_tpu.ops.sha1_pallas import _auto_interpret
+        from torrent_tpu.ops.sha1_pallas import _auto_interpret
 
-            backend = "jax"
-            if not _auto_interpret():
-                from torrent_tpu.ops import sha256_pallas as sp256
+        backend = "jax"
+        if not _auto_interpret():
+            from torrent_tpu.ops import sha256_pallas as sp256
 
-                # try the tuned TORRENT_TPU_SHA256_TILE_SUB first — the
-                # knob must actually reach this hot path or the sweep
-                # tool's winner would be a no-op here
-                for ts in dict.fromkeys((sp256.TILE_SUB, 32, 16, 8)):
-                    if b % (ts * 128) == 0:
-                        return lambda d, nb, _ts=ts: sp256.sha256_pieces_pallas(
-                            d, nb, tile_sub=_ts
-                        )
-        except ImportError:
-            backend = "jax"
-    return make_sha256_fn(backend)
+            # try the tuned TORRENT_TPU_SHA256_TILE_SUB first — the
+            # knob must actually reach this hot path or the sweep
+            # tool's winner would be a no-op here
+            for ts in dict.fromkeys((sp256.TILE_SUB, 32, 16, 8)):
+                if b % (ts * 128) == 0:
+                    fn = functools.partial(sp256.sha256_pieces_pallas, tile_sub=ts)
+                    return fn, "pallas"
+    return make_sha256_fn(backend), "pallas" if backend == "pallas" else "scan"
+
+
+# Per-launch wall time of the v2 leaf path by kernel. Its per-kernel
+# counts are how a drop from Mosaic to the scan backend (a leaf batch
+# that is not a 1024-row multiple, a non-TPU platform) stays visible.
+LEAF_LAUNCH_HIST = (
+    "torrent_tpu_v2_leaf_launch_seconds",
+    "v2 leaf-plane launch wall time (h2d + kernel + d2h) by kernel",
+)
+
+
+def _launch_leaves(leaf_fn, padded, nblocks) -> np.ndarray:
+    """One counted launch of a :func:`_make_leaf_fn` pair → host
+    ``u32[b, 8]``."""
+    import jax.numpy as jnp
+
+    from torrent_tpu.obs.hist import histograms
+
+    fn, kernel = leaf_fn
+    t0 = time.monotonic()
+    words = np.asarray(fn(jnp.asarray(padded), jnp.asarray(nblocks)))
+    histograms().get(*LEAF_LAUNCH_HIST, kernel=kernel).observe(
+        time.monotonic() - t0
+    )
+    return words
 
 
 def _leaf_words_from_chunks(chunks, total: int, backend: str) -> np.ndarray:
@@ -138,11 +157,9 @@ def _leaf_words_from_chunks(chunks, total: int, backend: str) -> np.ndarray:
     file sizes share a handful of compiled executables instead of one per
     block count; sentinel rows carry ``nblocks=0`` and never run.
     """
-    import jax
-
     n = max(1, -(-total // BLOCK))
     b = min(LEAF_BATCH, max(16, 1 << (n - 1).bit_length()))
-    fn = _make_leaf_fn(b, backend)
+    leaf_fn = _make_leaf_fn(b, backend)
     out = np.zeros((n, 8), dtype=np.uint32)
     padded, view = alloc_padded(b, BLOCK)
     start = 0
@@ -159,15 +176,14 @@ def _leaf_words_from_chunks(chunks, total: int, backend: str) -> np.ndarray:
             lengths[full] = rem
         nblocks = pad_in_place(padded, lengths)
         nblocks[k:] = 0
-        words = np.asarray(fn(jax.numpy.asarray(padded), jax.numpy.asarray(nblocks)))
-        out[start : start + k] = words[:k]
+        out[start : start + k] = _launch_leaves(leaf_fn, padded, nblocks)[:k]
         start += k
     if total == 0:  # empty source: single zero-length leaf
         lengths = np.zeros(b, dtype=np.int64)
         padded[:] = 0
         nblocks = pad_in_place(padded, lengths)
         nblocks[1:] = 0
-        out[0] = np.asarray(fn(jax.numpy.asarray(padded), jax.numpy.asarray(nblocks)))[0]
+        out[0] = _launch_leaves(leaf_fn, padded, nblocks)[0]
     return out
 
 
@@ -196,8 +212,7 @@ def _root_cpu(words: np.ndarray, pad_to: int, pad_digest: bytes = b"\x00" * 32) 
     """hashlib pair-fold of ``u32[n, 8]`` leaf/node words padded to
     ``pad_to`` with ``pad_digest`` — the device-free merkle reduction the
     ``hasher='cpu'`` paths use (a pure-CPU run must never touch the jax
-    backend: on hosts where the default device is remote or wedged, a
-    'cpu' author/verify would otherwise hang on the first dispatch)."""
+    backend)."""
     nodes = list(words32_to_digests(words)) + [pad_digest] * (pad_to - words.shape[0])
     while len(nodes) > 1:
         nodes = [
@@ -347,8 +362,6 @@ def hash_file_v2(
     if hasher == "cpu":
         leaves = _leaf_words_cpu(source)
         # device=False keeps a 'cpu' run off the jax backend entirely
-        # (on hosts with a remote/wedged default device the first
-        # dispatch would hang an explicitly-CPU author/verify)
         return roots_batched([(total, leaves)], piece_length, device=False)[0]
     leaves = _leaf_words_device(source, "auto")
     if total <= piece_length:
@@ -484,7 +497,7 @@ def _hybrid_hash_file(
         v1_digs.extend(hash_batch([tail.ljust(plen, b"\x00") if pad_tail else tail]))
 
     # device=False for 'cpu' keeps explicitly-CPU hybrid authoring off
-    # the jax backend (same remote/wedged-device hazard as hash_file_v2)
+    # the jax backend (as in hash_file_v2)
     root, layer = roots_batched([(total, leaves)], plen, device=hasher != "cpu")[0]
     return root, layer, v1_digs
 

@@ -1,28 +1,41 @@
 """Build the native IO engine shared library with g++.
 
 No pybind11/setuptools machinery needed for a C-ABI .so; one compiler
-invocation, cached next to the source and rebuilt when the source is
-newer. Import-time use goes through ``load()`` which returns None (pure-
-Python fallback) whenever a toolchain or binary is unavailable — the
-framework never hard-requires the native engine.
+invocation, cached next to the source under a name that carries a hash
+of the source — so a binary built from other source (a stale one, or
+one that travelled with a copy of the tree, where mtimes mean nothing)
+is never loaded. Import-time use goes through ``load()`` which returns
+None (pure-Python fallback) whenever a toolchain or binary is
+unavailable — the framework never hard-requires the native engine.
 """
 
 from __future__ import annotations
 
+import hashlib
 import os
 import pathlib
 import subprocess
 
 _SRC = pathlib.Path(__file__).with_name("io_engine.cpp")
-_LIB = pathlib.Path(__file__).with_name("libtorrent_tpu_io.so")
+_LIB_STEM = "libtorrent_tpu_io"
+
+
+def lib_path() -> pathlib.Path:
+    """Where the binary built from the committed source lives."""
+    digest = hashlib.sha256(_SRC.read_bytes()).hexdigest()[:16]
+    return _SRC.with_name(f"{_LIB_STEM}.{digest}.so")
 
 
 def build(force: bool = False) -> pathlib.Path | None:
     """Compile the engine if needed; returns the .so path or None."""
     if not _SRC.exists():
         return None
-    if not force and _LIB.exists() and _LIB.stat().st_mtime >= _SRC.stat().st_mtime:
-        return _LIB
+    lib = lib_path()
+    if not force and lib.exists():
+        return lib
+    # compile beside the target and rename: a concurrent process never
+    # loads a half-written binary
+    tmp = lib.with_name(f"{lib.name}.{os.getpid()}.tmp")
     cmd = [
         os.environ.get("CXX", "g++"),
         "-O2",
@@ -32,13 +45,18 @@ def build(force: bool = False) -> pathlib.Path | None:
         "-pthread",
         str(_SRC),
         "-o",
-        str(_LIB),
+        str(tmp),
     ]
     try:
         subprocess.run(cmd, check=True, capture_output=True, timeout=120)
+        os.replace(tmp, lib)
     except (OSError, subprocess.SubprocessError):
+        tmp.unlink(missing_ok=True)
         return None
-    return _LIB
+    for old in _SRC.parent.glob(f"{_LIB_STEM}*.so"):
+        if old != lib:  # binaries of other source versions
+            old.unlink(missing_ok=True)
+    return lib
 
 
 def load():
@@ -51,7 +69,7 @@ def load():
     try:
         lib = ctypes.CDLL(str(path))
     except OSError:
-        # Stale/foreign binary (other arch, older glibc): rebuild from
+        # Foreign binary (other arch, older glibc): rebuild from
         # source once before giving up on the native engine.
         path = build(force=True)
         if path is None:

@@ -1,10 +1,10 @@
 """Always-on pipeline ledger: per-stage byte/time/occupancy accounting.
 
-The hash plane banks 60.18 GiB/s while the end-to-end recheck in the
-SAME record measured 3.1 p/s — and the only way anyone knew the gap was
-host→device transfer was a human reconstructing it from bench logs
-(BENCH_r05). The ledger makes that attribution continuous and
-machine-readable: every stage boundary of the verify pipeline
+A bench record once paired a hash-plane rate with an end-to-end rate
+orders of magnitude lower, and the only way anyone knew which stage
+owned the gap was a human reconstructing it from bench logs. The
+ledger makes that attribution continuous and machine-readable: every
+stage boundary of the verify pipeline
 
     recv → read → stage → h2d → launch → digest → verdict
 

@@ -2,10 +2,10 @@
 
 The stack's five planes (scheduler, faults/breaker, pallas fast path,
 fabric, sanitizer) interact per *ticket*, but until now the only way to
-attribute an end-to-end latency to a stage was bench archaeology
-(BENCH_r05: the 60 GiB/s plane collapsing to 3.1 p/s end-to-end had to
-be diagnosed by hand). The tracer records one bounded span tree per
-trace:
+attribute an end-to-end latency to a stage was bench archaeology (a
+hash-plane rate orders of magnitude above the end-to-end rate of the
+same run had to be diagnosed by hand). The tracer records one bounded
+span tree per trace:
 
 * **Trace IDs are minted at the bridge** — an ``X-Trace-Id`` request
   header is honored (and echoed back), otherwise the bridge mints one —

@@ -25,7 +25,7 @@ def padded_len_for(piece_len: int) -> int:
     of 0x80 marker plus the 8-byte length field beyond the message. On
     top of that the row is rounded up to a 128-byte multiple: a device
     batch ``u8[B, padded_len]`` whose minor dim isn't lane-aligned (128)
-    forces XLA into padded relayouts — at 512 KiB pieces the AOT compiler
+    forces XLA into padded layout changes — at 512 KiB pieces the AOT compiler
     materializes a 32x-padded copy and dies with a 16 GiB allocation.
     Rows never exceed ``num_blocks_for`` blocks on device: the ghost tail
     block sits beyond every row's block count and is masked off by both

@@ -29,7 +29,6 @@ from torrent_tpu.ops.sha1_pallas import (
     TILE_LANE,
     TILE_SUB as _SHA1_TILE_SUB,
     UNROLL as _SHA1_UNROLL,
-    _COMPILER_PARAMS_CLS,
     _check_tiling,
     _swizzle_tile,
 )
@@ -298,7 +297,7 @@ def _sha256_pallas_aligned(
             (1, 8, tile_sub, TILE_LANE), lambda i, k: (i, 0, 0, 0), memory_space=pltpu.VMEM
         ),
         out_shape=jax.ShapeDtypeStruct((1, 8, tile_sub, TILE_LANE), jnp.uint32),
-        compiler_params=_COMPILER_PARAMS_CLS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -103,11 +103,9 @@ def _count_fn(mesh):
     on every batch of the recheck hot loop."""
     import jax
     import jax.numpy as jnp
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
-    from torrent_tpu.parallel.mesh import compat_shard_map
-
-    shard_map, sm_kw = compat_shard_map()
     spec = P((HOST_AXIS, DP_AXIS))
 
     def _count(ok_local):
@@ -116,7 +114,7 @@ def _count_fn(mesh):
         )
 
     return jax.jit(
-        shard_map(_count, mesh=mesh, in_specs=(spec,), out_specs=P(), **sm_kw)
+        shard_map(_count, mesh=mesh, in_specs=(spec,), out_specs=P(), check_vma=False)
     )
 
 

@@ -30,22 +30,6 @@ DP_AXIS = "dp"
 HOST_AXIS = "hosts"
 
 
-def compat_shard_map():
-    """``(shard_map, kwargs)`` for whichever jax this is: ~0.5 moved
-    ``shard_map`` out of ``jax.experimental`` and renamed its
-    replication-check kwarg ``check_rep`` → ``check_vma``. Every mesh
-    call site splats the returned kwargs instead of carrying its own
-    version probe."""
-    try:
-        from jax import shard_map
-
-        return shard_map, {"check_vma": False}
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
-
-        return shard_map, {"check_rep": False}
-
-
 def make_mesh(devices=None, n_hosts: int | None = None) -> Mesh:
     """Build a ``(hosts, dp)`` mesh over ``devices`` (default: all).
 

@@ -179,10 +179,8 @@ def verify_pieces_v2_tpu(
     """
     from torrent_tpu.codec.metainfo_v2 import BLOCK
     from torrent_tpu.models.merkle import merkle_root, words32_to_digests
-    from torrent_tpu.models.v2 import _make_leaf_fn
+    from torrent_tpu.models.v2 import _launch_leaves, _make_leaf_fn
     from torrent_tpu.ops.padding import alloc_padded, pad_in_place
-
-    import jax.numpy as jnp
 
     n = info.num_pieces
     bitfield = np.zeros(n, dtype=bool)
@@ -196,7 +194,7 @@ def verify_pieces_v2_tpu(
         by_pad.setdefault(info.piece_pad_leaves[idx], []).append(idx)
     n_todo = sum(len(v) for v in by_pad.values())
     leaf_rows = 1024  # device rows per leaf dispatch (pow2-bucketed fn)
-    fn = _make_leaf_fn(leaf_rows, "auto")
+    leaf_fn = _make_leaf_fn(leaf_rows, "auto")
     padded, view = alloc_padded(leaf_rows, BLOCK)
     done = 0
     for pad, group in by_pad.items():
@@ -225,7 +223,7 @@ def verify_pieces_v2_tpu(
                     row_len[r] = blen
                 nblocks = pad_in_place(padded, row_len)
                 nblocks[len(chunk) :] = 0
-                words = np.asarray(fn(jnp.asarray(padded), jnp.asarray(nblocks)))
+                words = _launch_leaves(leaf_fn, padded, nblocks)
                 for r, (i, bi, _blen) in enumerate(chunk):
                     grid[i, bi] = words[r]
             roots = words32_to_digests(merkle_root(grid))
@@ -504,8 +502,9 @@ def verify_pieces(
     ``hasher`` mirrors the BASELINE API contract: ``"cpu"`` (default,
     streaming hashlib — the reference's std/crypto analogue) or ``"tpu"``
     (batched device path; on CPU-only hosts XLA still runs it, so the flag
-    selects *strategy*, not hardware availability). v2 session infos
-    (session/v2.py) route to the merkle recheck automatically.
+    selects *strategy*, not hardware — ``utils.device.device_info()``
+    names what it ran on). v2 session infos (session/v2.py) route to the
+    merkle recheck automatically.
     """
     if info.num_pieces == 0:
         return np.zeros(0, dtype=bool)

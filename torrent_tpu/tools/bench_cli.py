@@ -5,8 +5,7 @@ Replaces the ad-hoc ``.bench/*.sh`` rung logic with one command: every
 rung is named, emits ONE banked-schema JSON line, and (for in-process
 rungs) embeds the pipeline ledger's per-stage breakdown, so every
 record carries its own bottleneck attribution instead of needing bench
-archaeology. The moment a quiet device window opens, banking a rung is
-one command.
+archaeology.
 
 Rungs::
 
@@ -50,10 +49,9 @@ Rungs::
 
 ``--smoke`` is an alias for the smoke rung (CI spells it that way).
 Device rungs shell out to the repo's ``bench.py`` / ``.bench/
-measure_fabric.py`` with the same env the retired rung scripts
-exported, and pass the child's record through wrapped in the bench
-schema; they obey bench.py's wedge-safety rules (never kill a
-TPU-touching process).
+measure_fabric.py`` and pass the child's record through wrapped in the
+bench schema. This process stays off JAX on those rungs, so the child
+is the one process that holds the chip.
 
 Record schema (``"schema": "torrent-tpu-bench/1"``): the banked-record
 fields bench.py already emits (metric/value/unit/vs_baseline/batch/
@@ -70,8 +68,8 @@ trajectory (``BENCH_trajectory.json``, built by ``.bench/summarize.py
 --trajectory`` and appended to by ``--bank``). Like-for-like means an
 identical measurement shape — ``metric``, ``platform``, ``batch``,
 payload shape (``piece_kb``/``bytes``), and host class (``nproc``) —
-and the banked record is not flagged ``non_like_for_like`` (the
-BENCH_CONFIGS_r05 shape caveats).
+and the banked record is not flagged ``non_like_for_like`` (a shape
+caveat).
 With no like-for-like banked record the comparator reports itself
 **unarmed** and exits 0 — the CI gate arms itself only once a
 comparable record is banked. ``--report-only`` never fails the run.
@@ -108,8 +106,8 @@ DEFAULT_TOLERANCE = 0.10
 # autopilot's grown batches measurably amortize the fixed cost
 CONTROLLER_FAULT = "latency_ms=25"
 
-# env the retired .bench rung scripts exported, reproduced per rung
-# (r6_sha256_rung.sh leg 2; the flagship shape from BENCH_CONFIGS_r05)
+# bench.py env per device rung (shapes chosen on a retired setup, not
+# measured on this one)
 _DEVICE_RUNG_ENV = {
     "v2": {
         "BENCH_CONFIG": "v2",
@@ -117,14 +115,12 @@ _DEVICE_RUNG_ENV = {
         "BENCH_V2_NRES": "3",
         "BENCH_E2E_MB": "16",
         "BENCH_H2D_MB": "8",
-        "BENCH_NO_REPLAY": "1",
         "TORRENT_TPU_SHA256_BACKEND": "pallas",
     },
     "flagship": {
         "BENCH_CONFIG": "headline",
         "BENCH_BATCH": "8192",
         "BENCH_TOTAL_MB": "2048",
-        "BENCH_NO_REPLAY": "1",
     },
 }
 
@@ -304,6 +300,9 @@ async def _e2e(
     n_valid = int(res.bitfields[0].sum())
     pieces = info.num_pieces
     value = round(pieces / seconds, 1) if seconds > 0 else None
+    from torrent_tpu.utils.device import hasher_device
+
+    device = hasher_device(hasher)  # what it ran on, not the flag
     return {
         "schema": SCHEMA,
         "rung": "e2e",
@@ -317,7 +316,8 @@ async def _e2e(
         "gib_per_sec": round(info.length / seconds / 2**30, 3) if seconds else None,
         "batch": batch_target,
         "piece_kb": piece_kb,
-        "platform": hasher,
+        "platform": device["platform"],
+        "device_kind": device["kind"],
         "plane": hasher,
         "nproc": os.cpu_count(),
         # zero-copy health facts alongside the rate: stage-copy bytes
@@ -1009,8 +1009,8 @@ async def _seed_rung(total_mb: int, piece_kb: int, leechers: int) -> dict:
 
 def _run_bench_py(rung: str, timeout: float | None) -> dict:
     """Run the repo bench.py with the rung's env; pass its record
-    through wrapped in the bench schema. Wedge safety is bench.py's own
-    (never kills a TPU process; emits tpu_unavailable markers)."""
+    through wrapped in the bench schema. bench.py exits non-zero and
+    prints no record when it finds no accelerator."""
     bench_py = os.path.join(_repo_root(), "bench.py")
     if not os.path.exists(bench_py):
         raise FileNotFoundError(
@@ -1045,9 +1045,15 @@ def _run_bench_py(rung: str, timeout: float | None) -> dict:
 
 
 def _run_fabric_rung(timeout: float | None) -> dict:
-    """The r7 scaling rung: 1/2/4-process CPU fabric verify, median-of-3
-    per process count, value = the 4-process GiB/s. The record embeds
-    every leg's PER-PROCESS ledger/overlap breakdown (last rep) plus the
+    """The r7 scaling rung: 1/2/4-process fabric verify (hashlib workers
+    unless FABRIC_HASHER says otherwise), median-of-3 per process count,
+    value = the 4-process GiB/s. The launcher hands each device worker
+    its own chip and fails a leg unless every worker's JAX reported one
+    TPU chip, so ``FABRIC_HASHER=tpu`` needs a four-chip host: on fewer
+    chips the rung fails at the first leg with more workers than chips
+    rather than bank CPU workers under ``platform: "tpu"``. The record
+    embeds every leg's PER-PROCESS ledger/overlap breakdown (last rep,
+    each with its worker's own ``device``) plus the
     fleet's two-level bottleneck verdict — the rate banks WITH its
     attribution, so a scaling regression names the process and stage
     that caused it instead of needing bench archaeology."""
@@ -1059,6 +1065,8 @@ def _run_fabric_rung(timeout: float | None) -> dict:
     results: dict[int, list[float]] = {}
     per_process: dict[str, list] = {}
     fleet_bottleneck: dict[str, dict | None] = {}
+    hasher = os.environ.get("FABRIC_HASHER", "cpu")
+    device: dict = {}
     with tempfile.TemporaryDirectory(prefix="tt_bench_fabric_") as work:
         for nproc in (1, 2, 4):
             proc = subprocess.run(
@@ -1066,9 +1074,8 @@ def _run_fabric_rung(timeout: float | None) -> dict:
                     sys.executable, measure, "--workdir", work,
                     "--nproc", str(nproc), "--reps", "3",
                     "--torrents", "8", "--mb-per-torrent", "64",
-                    "--hasher", os.environ.get("FABRIC_HASHER", "cpu"),
+                    "--hasher", hasher,
                 ],
-                env={**os.environ, "JAX_PLATFORMS": "cpu"},
                 capture_output=True, text=True, timeout=timeout,
             )
             if proc.returncode != 0:
@@ -1083,6 +1090,7 @@ def _run_fabric_rung(timeout: float | None) -> dict:
                     results.setdefault(rec["nproc"], []).append(
                         rec["gib_per_sec"]
                     )
+                    device = rec.get("device") or device
                     # last rep wins: one representative breakdown per leg
                     if rec.get("per_process"):
                         per_process[str(rec["nproc"])] = rec["per_process"]
@@ -1101,7 +1109,9 @@ def _run_fabric_rung(timeout: float | None) -> dict:
         "contract": "median-of-3",
         "scaling": {str(n): v for n, v in med.items()},
         "speedup_4p": round(med[4] / base, 2) if base and med.get(4) else None,
-        "platform": os.environ.get("FABRIC_HASHER", "cpu"),
+        "platform": device.get("platform"),
+        "device_kind": device.get("kind"),
+        "hasher": hasher,
         "batch": None,
         "measured_at_utc": _utcnow(),
         # subprocess rung: the parent's own ledger stays null, but the
@@ -1145,8 +1155,7 @@ _LIKE_KEYS = ("metric", "platform", "batch", "piece_kb", "bytes", "nproc")
 def like_for_like(records: list[dict], cand: dict) -> list[dict]:
     """Banked records the candidate may be gated against: identical
     measurement shape (:data:`_LIKE_KEYS`), value present, and not
-    carrying a non-like-for-like shape caveat (the BENCH_CONFIGS_r05
-    discipline)."""
+    carrying a non-like-for-like shape caveat."""
     return [
         r
         for r in records

@@ -140,6 +140,226 @@ class TestStagingReuse:
         run(go())
 
 
+def _ragged(n: int, plen: int, salt: int = 0) -> list[bytes]:
+    """``n`` payloads of ragged lengths up to ``plen``: a zero-length
+    piece, a full one, and lengths around the SHA-1 block edges."""
+    edges = [0, plen, 55, 56, 63, 64, 65, 1]
+    return [
+        bytes([(i * 7 + salt) % 251]) * min(plen, edges[i % 8] if i < 8 else (i * 37 + salt) % (plen + 1))
+        for i in range(n)
+    ]
+
+
+def _sha1s(payloads) -> list[bytes]:
+    return [hashlib.sha1(p).digest() for p in payloads]
+
+
+@pytest.fixture(scope="module", params=["mesh", "one_device"])
+def ladder_plane(request):
+    """A built 256-row SHA-1 device plane at 192-byte pieces: over the
+    tests' 8 virtual devices (the sharded upload road) and over one
+    device (the flat road a one-chip host takes)."""
+    import jax
+
+    from torrent_tpu.models import verifier as verifier_mod
+    from torrent_tpu.sched.scheduler import _Sha1DevicePlane
+
+    mp = pytest.MonkeyPatch()
+    if request.param == "one_device":
+        real = verifier_mod.make_mesh
+        mp.setattr(verifier_mod, "make_mesh", lambda devices=None: real(jax.devices()[:1]))
+    try:
+        plane = _Sha1DevicePlane(192, 256)
+    finally:
+        mp.undo()
+    assert plane._verifier.mesh.size == (1 if request.param == "one_device" else 8)
+    return plane
+
+
+def _step_cache_sizes(plane) -> tuple[int, int]:
+    """Programs held by the plane's two jitted digest steps (flat road,
+    sharded road)."""
+    v = plane._verifier
+    return v._digest_step_flat._cache_size(), v._digest_step_donated._cache_size()
+
+
+class TestSha1RowLadder:
+    """The SHA-1 device plane launches at the smallest rung of a fixed,
+    warmed row ladder that holds the chunk (PR 25)."""
+
+    @pytest.mark.parametrize(
+        "batch,granule,want",
+        [
+            (256, 1, (32, 64, 128, 256)),
+            (256, 8, (32, 64, 128, 256)),
+            (512, 1, (32, 64, 128, 256, 512)),
+            (4096, 1, (32, 128, 512, 2048, 4096)),
+            (100, 1, (32, 64, 100)),
+            (96, 6, (36, 66, 96)),
+            (32, 1, (32,)),
+            (8, 8, (8,)),
+        ],
+    )
+    def test_ladder_is_fixed_by_the_batch(self, batch, granule, want):
+        from torrent_tpu.sched.scheduler import _row_ladder
+
+        got = _row_ladder(batch, granule)
+        assert got == want
+        assert len(got) <= 5 and got[-1] == batch
+        assert all(r % granule == 0 for r in got)
+
+    @pytest.mark.parametrize(
+        "n,rows",
+        [(1, 32), (15, 32), (16, 32), (17, 32), (32, 32), (33, 64), (64, 64),
+         (65, 128), (200, 256), (256, 256), (257, 256 + 32), (512 + 40, 512 + 64)],
+    )
+    def test_rung_chosen_for_a_chunk(self, ladder_plane, n, rows):
+        assert ladder_plane._ladder == (32, 64, 128, 256)
+        assert ladder_plane.launch_rows(n) == rows
+        if n <= 256:
+            assert ladder_plane._rung_for(n) == rows
+
+    @pytest.mark.parametrize("n", [1, 15, 32, 33, 64, 100, 128, 200, 256, 257])
+    def test_digests_match_hashlib_at_every_rung(self, ladder_plane, n):
+        payloads = _ragged(n, 192, salt=n)
+        assert b"" in payloads
+        before = ladder_plane._slots.stats()
+        assert ladder_plane.run(payloads) == _sha1s(payloads)
+        outstanding, checkouts = ladder_plane._slots.stats()
+        assert outstanding == 0
+        assert checkouts - before[1] == -(-n // 256)  # one slot a chunk
+
+    @pytest.mark.parametrize("order", [(256, 5, 256), (5, 256, 40), (256, 40, 5, 130, 256)])
+    def test_slot_reuse_across_rungs_zeroes_stale_tails(self, ladder_plane, order):
+        """A full 256-row launch dirties every row to full width; a
+        32-row launch after it restages only its rung; the next wide
+        launch still finds rows 32.. as the first one left them and
+        must tail-zero them for its shorter pieces (and the reverse)."""
+        for k, n in enumerate(order):
+            plen = (192, 70, 9, 120, 33)[k]  # shrinking and growing tails
+            payloads = [bytes([(i + k) % 251 + 1]) * (plen if i % 3 else plen // 2) for i in range(n)]
+            assert ladder_plane.run(payloads) == _sha1s(payloads), (k, n)
+            # sequential launches keep reusing the one slot of the pool
+            assert len(ladder_plane._slots._slots) == 1
+
+    def test_every_rung_is_warmed_at_the_build(self, ladder_plane):
+        """Launches at every rung, after the build, add no program to
+        the jitted steps: nothing is traced or compiled in a window."""
+        sizes = _step_cache_sizes(ladder_plane)
+        flat = ladder_plane._verifier.mesh.size == 1
+        assert sizes == ((4, 0) if flat else (0, 4))  # one road, every rung
+        for n in (1, 32, 33, 64, 65, 128, 129, 256, 300):
+            payloads = _pieces(n, 192, salt=n)
+            assert ladder_plane.run(payloads) == _sha1s(payloads)
+        assert _step_cache_sizes(ladder_plane) == sizes
+
+    def test_geometry_hook_leaves_targets_alone(self, ladder_plane):
+        assert ladder_plane.launch_geometry(100, 192)[0] == 100
+        assert ladder_plane.launch_geometry(1, 192)[0] == 1
+
+    @pytest.mark.parametrize("target", [1, 37, 100, 255, 300])
+    def test_set_lane_target_applies_any_row_count_on_a_built_lane(self, target):
+        async def go():
+            sched = HashPlaneScheduler(
+                SchedulerConfig(batch_target=64, flush_deadline=0.01), hasher="tpu"
+            )
+            try:
+                pieces = _pieces(3, 64)
+                assert await sched.submit("t", pieces, piece_length=64) == _sha1s(pieces)
+                assert sched._lanes[("sha1", 64)].plane is not None
+                assert sched.control_surface()["lanes"]["sha1/64"]["granule"] == 1
+                assert sched.set_lane_target("sha1/64", target) == target
+                more = _pieces(70, 64, salt=3)  # past the plane's batch: two chunks
+                assert await sched.submit("t", more, piece_length=64) == _sha1s(more)
+            finally:
+                await sched.close()
+
+        run(go())
+
+    def test_deadline_flush_counts_its_pad_and_launched_rows(self):
+        """14 pieces flushed by the deadline on a 256 target launch at
+        the 32-row rung: pad = 32 - 14 (it read 0 before PR 25)."""
+
+        async def go():
+            from torrent_tpu.utils.metrics import render_sched_metrics
+
+            sched = HashPlaneScheduler(
+                SchedulerConfig(batch_target=256, flush_deadline=0.02), hasher="tpu"
+            )
+            try:
+                pieces = _ragged(14, 64)
+                assert await sched.submit("t", pieces, piece_length=64) == _sha1s(pieces)
+                snap = sched.metrics_snapshot()
+                lane = snap["lane_stats"]["sha1/64"]
+                assert lane["kernel"] == "scan" and lane["target"] == 256
+                assert snap["launches"] == 1 and snap["flush_reasons"]["deadline"] == 1
+                assert lane["launched_rows_total"] == 32
+                assert lane["pad_rows_total"] == 32 - 14
+                full = _pieces(256, 64, salt=9)  # a full take: the top rung, no pad
+                assert await sched.submit("t", full, piece_length=64) == _sha1s(full)
+                lane = sched.metrics_snapshot()["lane_stats"]["sha1/64"]
+                assert lane["launched_rows_total"] == 32 + 256
+                assert lane["pad_rows_total"] == 32 - 14
+                text = render_sched_metrics(sched)
+                assert 'torrent_tpu_sched_launch_pad_rows_total{lane="sha1/64"} 18' in text
+                assert 'torrent_tpu_sched_launch_rows_total{lane="sha1/64"} 288' in text
+            finally:
+                await sched.close()
+
+        run(go())
+
+    def test_bisection_halves_take_lower_rungs(self):
+        """A poisoned piece fails its 40-piece launch (the 64-row rung);
+        the halves relaunch with fewer payloads and so at the 32-row
+        rung on their own. Every attempt is charged the rows it staged."""
+        from torrent_tpu.sched import SchedLaunchError
+        from torrent_tpu.sched.faults import FaultPlan
+
+        async def go():
+            plan = FaultPlan(payload_prefix=b"\xde\xad")
+            sched = HashPlaneScheduler(
+                SchedulerConfig(
+                    batch_target=64, flush_deadline=0.01,
+                    plane_factory=plan.plane_factory(hasher="tpu"),
+                ),
+                hasher="tpu",
+            )
+            attempts: list[int] = []
+            try:
+                warm = _pieces(2, 64)
+                assert await sched.submit("t", warm, piece_length=64) == _sha1s(warm)
+                lane = sched._lanes[("sha1", 64)]
+                assert lane.plane.inner._ladder == (32, 64)
+                real = lane.plane.inner.run
+                lane.plane.inner.run = lambda payloads: (attempts.append(len(payloads)), real(payloads))[1]
+                before = sched.metrics_snapshot()["lane_stats"]["sha1/64"]
+                pieces = _pieces(40, 64, salt=5)
+                pieces[7] = b"\xde\xad" + pieces[7][2:]
+                got = await asyncio.gather(
+                    *(sched.submit("t", [p], piece_length=64) for p in pieces),
+                    return_exceptions=True,
+                )
+                for i, (g, p) in enumerate(zip(got, pieces)):
+                    if i == 7:
+                        assert isinstance(g, SchedLaunchError), g
+                    else:
+                        assert g == _sha1s([p]), i
+                after = sched.metrics_snapshot()
+                assert after["bisections"] >= 1
+                # the poisoned halves never reach the inner plane; the
+                # clean ones did, each with fewer rows than the take
+                assert attempts and max(attempts) < 40
+                stats = after["lane_stats"]["sha1/64"]
+                launched = stats["launched_rows_total"] - before["launched_rows_total"]
+                pad = stats["pad_rows_total"] - before["pad_rows_total"]
+                assert launched % 32 == 0 and launched >= 64 + 32 + 32
+                assert launched - pad >= 40 + 39  # live rows of every attempt
+            finally:
+                await sched.close()
+
+        run(go())
+
+
 class TestParity:
     @pytest.mark.parametrize("hasher", ["cpu", "tpu"])
     def test_digests_match_hashlib(self, hasher):

@@ -191,6 +191,13 @@ class TPUVerifier:
         # 4 concurrent upload streams: chosen on a retired setup, not
         # measured on this one.
         self._upload_chunks = env_int("TORRENT_TPU_UPLOAD_CHUNKS", 4)
+        # Row counts the flat road takes on a one-device mesh: the
+        # verifier's own batch, and what a caller warms besides (the
+        # scheduler's SHA-1 plane adds its row ladder). Any other shape
+        # takes the sharded step. Read on the chip (PERF.md, PR 25): at
+        # 32 rows of 256 KiB the flat road uploads in 1.7 ms and steps
+        # in 5.8, the sharded one in 2.2 and 6.7.
+        self.flat_rows = {self.batch_size}
         self._upload_pool: ThreadPoolExecutor | None = None
         # verify_batch/digest_batch may be called from several threads on a
         # shared verifier (the bridge does); first-use pool init must not race
@@ -215,7 +222,9 @@ class TPUVerifier:
         return (
             self.mesh.size == 1
             and isinstance(padded, np.ndarray)
-            and padded.shape == (self.batch_size, self.padded_len)
+            and padded.ndim == 2
+            and padded.shape[1] == self.padded_len
+            and padded.shape[0] in self.flat_rows
         )
 
     def _put_flat(self, padded: np.ndarray) -> list[jax.Array]:

@@ -201,6 +201,13 @@ class FaultyPlane:
             return n_rows, 0
         return hook(n_rows, bucket)
 
+    def launch_rows(self, n_rows: int) -> int:
+        """Likewise for the rows a copying launch stages (the SHA-1
+        plane's row ladder); row-exact where the wrapped plane says
+        nothing."""
+        rows_of = getattr(self.inner, "launch_rows", None)
+        return n_rows if rows_of is None else rows_of(n_rows)
+
     def _apply_faults(self, payloads) -> None:
         """Count the launch and raise per the plan. ``payloads`` may be
         bytes or the scheduler's zero-copy ``SlotRow`` views — both

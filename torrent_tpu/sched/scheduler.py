@@ -88,6 +88,12 @@ N+1's transfer overlaps batch N's kernel. Slabs are reference counted
 ``outstanding`` gauge must return to 0 — see ARCHITECTURE.md
 "Zero-copy ingest" for ownership rules and the fallback matrix.
 
+The SHA-1 device plane's copying road launches at the rows a flush
+holds: the smallest rung of a fixed, warmed row ladder
+(:func:`_row_ladder`) that takes the chunk, so a sparse deadline flush
+stages, uploads and hashes 32 rows where a full take uses the plane's
+whole batch. ``lane_stats`` counts every attempt's launched and pad rows.
+
 The scheduler autopilot (``sched/control.py``) closes the observe→act
 loop over these sensors: a periodic controller turns ledger/attribution
 snapshot deltas into bounded actuator moves through the seams below —
@@ -111,6 +117,7 @@ rather than raw payload bytes. See ARCHITECTURE.md "The v2 hash plane".
 from __future__ import annotations
 
 import asyncio
+import bisect
 import hashlib
 import time
 from collections import deque
@@ -347,7 +354,7 @@ class _Lane:
         "algo", "bucket", "target", "queues", "rotation", "pending_pieces",
         "event", "task", "plane", "build_lock", "sem", "inflight",
         "breaker", "cpu_plane", "backend", "deadline",
-        "launches", "fill_sum", "pad_rows_total",
+        "launches", "fill_sum", "pad_rows_total", "launched_rows_total",
     )
 
     def __init__(
@@ -384,6 +391,7 @@ class _Lane:
         self.launches = 0
         self.fill_sum = 0.0
         self.pad_rows_total = 0
+        self.launched_rows_total = 0
 
     def oldest_ts(self) -> float:
         return min(q[0].ts for q in self.queues.values() if q)
@@ -847,6 +855,33 @@ class _CpuPlane:
             return [h(slab.row(r)).digest() for r in rows]
 
 
+_LADDER_FLOOR = 32  # rows: one uint8 tile deep on the chip, see _row_ladder
+
+
+def _row_ladder(batch: int, granule: int = 1) -> tuple[int, ...]:
+    """Ascending row counts a SHA-1 device plane launches at.
+
+    Powers of two from ``_LADDER_FLOOR`` rows up, thinned (every second,
+    third ... power) so that a plane holds at most five shapes, each
+    rounded up to the mesh's ``granule``, and the plane's own ``batch``
+    on top: 256 → (32, 64, 128, 256), 4096 → (32, 128, 512, 2048, 4096),
+    a batch at or under the floor → itself alone. The floor is what the
+    chip read (PERF.md, PR 25): a uint8 batch is tiled 32 rows deep, so
+    a 16-row launch uploads in the same time as a 32-row one and its
+    step is slower (6.3 against 5.8 ms at 256 KiB pieces).
+    """
+    from torrent_tpu.parallel.mesh import round_up_to_multiple
+
+    doublings = max(0, -(-batch // _LADDER_FLOOR) - 1).bit_length()
+    stride = max(1, -(-doublings // 4))
+    rungs = {batch}
+    r = _LADDER_FLOOR
+    while r < batch:
+        rungs.add(round_up_to_multiple(r, granule))
+        r <<= stride
+    return tuple(sorted(x for x in rungs if x <= batch))
+
+
 class _Sha1DevicePlane:
     """SHA-1 device plane: one compiled TPUVerifier per bucket (the
     geometry-grouped compile cache the bulk/verify loops relied on).
@@ -854,6 +889,15 @@ class _Sha1DevicePlane:
     Stages into reusable per-plane :class:`_StagingSlots` instead of
     ``hash_pieces`` (which allocates + zeroes a fresh buffer every
     launch).
+
+    The copying road (``run``) launches at the smallest rung of a fixed
+    row ladder (:func:`_row_ladder`) that holds the chunk: a deadline
+    flush of 14 pieces on a 256-row plane stages, uploads and hashes 32
+    rows, not 256. The top rung is the plane's whole batch; on one
+    device every rung takes the verifier's flat chunked upload
+    (``flat_rows``), on a mesh ``upload_batch``'s sharded handle. Every
+    rung is compiled and run once when the plane is built, so no later
+    launch meets a shape for the first time.
 
     The jitted execution itself is serialized per plane
     (``_device_lock``): two worker threads entering the same compiled
@@ -866,14 +910,52 @@ class _Sha1DevicePlane:
     def __init__(self, bucket: int, batch: int):
         from torrent_tpu.models.verifier import TPUVerifier
 
-        self._verifier = TPUVerifier(piece_length=bucket, batch_size=batch)
-        self.kernel = "pallas" if self._verifier.backend == "pallas" else "scan"
-        self._slots = _StagingSlots(self._verifier.batch_size, bucket)
+        v = self._verifier = TPUVerifier(piece_length=bucket, batch_size=batch)
+        self.kernel = "pallas" if v.backend == "pallas" else "scan"
+        self._slots = _StagingSlots(v.batch_size, bucket)
         self._device_lock = named_lock("sched.sha1_plane._device_lock")
+        self._ladder = _row_ladder(v.batch_size, v.mesh.size)
+        v.flat_rows.update(self._ladder)
+        self._warm()
+
+    def _warm(self) -> None:
+        """Compile and run every rung once on an all-sentinel batch (the
+        plane is built in a worker thread at the lane's first launch).
+        A mesh the explicit upload cannot feed (multi-process) keeps the
+        one fused full-batch launch, compiled at first use as before."""
+        import numpy as np
+
+        v = self._verifier
+        slot = self._slots.checkout()
+        try:
+            padded = slot[0]
+            if not v.upload_supported(padded):
+                self._ladder = self._ladder[-1:]
+                return
+            for rung in self._ladder:
+                handle = v.upload_batch(padded[:rung])
+                np.asarray(v.digest_uploaded(handle, np.zeros(rung, dtype=np.int32)))
+        finally:
+            self._slots.checkin(slot)
+
+    def _rung_for(self, n_rows: int) -> int:
+        """Smallest rung holding ``n_rows`` (at most the plane's batch)."""
+        return self._ladder[bisect.bisect_left(self._ladder, n_rows)]
+
+    def launch_rows(self, n_rows: int) -> int:
+        """Rows ``run`` stages, uploads and hashes for ``n_rows``
+        payloads: whole batches, then the rest's rung. What the lane's
+        pad-row accounting charges for the copying road."""
+        b = self._ladder[-1]
+        full, rest = divmod(n_rows, b)
+        return full * b + (self._rung_for(rest) if rest else 0)
 
     @staticmethod
     def launch_geometry(n_rows: int, bucket: int) -> tuple[int, int]:
-        """Row-exact launches; staging charges the padded row width."""
+        """Any row count is a valid flush target (the ladder follows the
+        chunk a launch holds, not the lane's target), so targets snap to
+        themselves; staging charges the padded row width. The rows a
+        launch really stages are :meth:`launch_rows`."""
         from torrent_tpu.ops.padding import padded_len_for
 
         return n_rows, n_rows * padded_len_for(bucket)
@@ -918,9 +1000,10 @@ class _Sha1DevicePlane:
         for start in range(0, len(payloads), b):
             chunk = payloads[start : start + b]
             nb = sum(len(p) for p in chunk)
-            slot, padded, nblocks = self._slots.stage(chunk)
+            rung = self._rung_for(len(chunk))
+            slot, padded, nblocks = self._slots.stage(chunk, rows=rung)
             try:
-                words = self._launch_padded(padded, nblocks, nb)
+                words = self._launch_padded(padded[:rung], nblocks, nb)
                 out.extend(words_to_digests(words[: len(chunk)]))
             finally:
                 self._slots.checkin(slot)
@@ -1954,22 +2037,6 @@ class HashPlaneScheduler:
                         else:
                             lane.breaker.release_probe()
                         raise
-        # pad-row waste: rows this launch stages beyond the live batch
-        # (tile bucketing on the pallas plane; zero on row-exact planes
-        # and the hashlib degradation path, which stages nothing). The
-        # built plane's own launch_geometry hook is authoritative — a
-        # plane_factory plane (faults seam) may stage differently than
-        # the lane plan assumed; one exposing no hook is taken as
-        # row-exact (FaultyPlane's hook-less default agrees). Charged
-        # per actual attempt (retries and bisection halves each
-        # re-stage), under the counter lock: worker threads run this.
-        hook = getattr(lane.plane, "launch_geometry", None)
-        if hook is not None:
-            pad = hook(len(payloads), lane.bucket)[0] - len(payloads)
-            if pad:
-                with self._counter_lock:
-                    self._counter_cells.write("fault_counters")
-                    lane.pad_rows_total += pad
         # zero-copy launch form: when every ticket is a SlotRow of ONE
         # pre-staged slab and the plane can consume it in place, skip
         # the stage copy entirely (mixed batches — several slabs, or
@@ -1979,6 +2046,33 @@ class HashPlaneScheduler:
         run_staged = (
             getattr(lane.plane, "run_staged", None) if staged else None
         )
+        # launched rows and pad-row waste: the rows this attempt stages,
+        # uploads and hashes, and how many of them hold no piece (the
+        # SHA-1 plane's row ladder, tile bucketing on the pallas plane;
+        # zero pad on the hashlib degradation path, which stages
+        # nothing). The built plane's own word is authoritative — a
+        # plane_factory plane (faults seam) may stage differently than
+        # the lane plan assumed: ``launch_rows`` where the copying road
+        # launches at a shape of its own choosing, else the
+        # ``launch_geometry`` hook the targets snap with (a staged
+        # slab's rows are its reader's: charged as handed over); a plane
+        # exposing neither is taken as row-exact (FaultyPlane's
+        # hook-less default agrees). Charged per actual attempt (retries
+        # and bisection halves each re-stage), under the counter lock:
+        # worker threads run this.
+        n = len(payloads)
+        rows_of = getattr(lane.plane, "launch_rows", None)
+        hook = getattr(lane.plane, "launch_geometry", None)
+        if rows_of is not None and run_staged is None:
+            launched = rows_of(n)
+        elif hook is not None:
+            launched = hook(n, lane.bucket)[0]
+        else:
+            launched = n
+        with self._counter_lock:
+            self._counter_cells.write("fault_counters")
+            lane.launched_rows_total += launched
+            lane.pad_rows_total += launched - n
         if run_staged is not None:
             obs_note["staged"] = True
         try:
@@ -2256,8 +2350,13 @@ class HashPlaneScheduler:
         with self._counter_lock:
             self._counter_cells.read("fault_counters")
             cpu_fallback_launches = self._cpu_fallback_launches
-            pad_rows = {
-                key: lane.pad_rows_total for key, lane in self._lanes.items()
+            # pad share over a window = Δpad ÷ Δlaunched rows
+            lane_rows = {
+                key: {
+                    "pad_rows_total": lane.pad_rows_total,
+                    "launched_rows_total": lane.launched_rows_total,
+                }
+                for key, lane in self._lanes.items()
             }
         # enqueue-to-take seconds and pieces of every launch: the sum
         # and count the queue-wait histograms already keep, over every
@@ -2309,7 +2408,10 @@ class HashPlaneScheduler:
                     "mean_fill": (
                         lane.fill_sum / lane.launches if lane.launches else 0.0
                     ),
-                    "pad_rows_total": pad_rows.get((algo, bucket), 0),
+                    **lane_rows.get(
+                        (algo, bucket),
+                        {"pad_rows_total": 0, "launched_rows_total": 0},
+                    ),
                 }
                 for (algo, bucket), lane in self._lanes.items()
             },

@@ -94,13 +94,24 @@ def render_sched_metrics(sched) -> str:
         )
     lines.append(
         "# HELP torrent_tpu_sched_launch_pad_rows_total Sentinel rows staged "
-        "beyond the live batch (tile-bucketed pallas launches)"
+        "beyond the live batch (SHA-1 row ladder, tile-bucketed pallas launches)"
     )
     lines.append("# TYPE torrent_tpu_sched_launch_pad_rows_total counter")
     for lane, st in sorted(lane_stats.items()):
         lines.append(
             f'torrent_tpu_sched_launch_pad_rows_total{{lane="{_esc(lane)}"}} '
             f"{st.get('pad_rows_total', 0)}"
+        )
+    # the denominator: pad share over a window = pad rows / launched rows
+    lines.append(
+        "# HELP torrent_tpu_sched_launch_rows_total Rows staged, uploaded "
+        "and hashed by launch attempts, pad rows included"
+    )
+    lines.append("# TYPE torrent_tpu_sched_launch_rows_total counter")
+    for lane, st in sorted(lane_stats.items()):
+        lines.append(
+            f'torrent_tpu_sched_launch_rows_total{{lane="{_esc(lane)}"}} '
+            f"{st.get('launched_rows_total', 0)}"
         )
     lines.append(
         "# HELP torrent_tpu_sched_lane_target Pieces per launch this lane aims to fill"

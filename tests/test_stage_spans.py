@@ -174,8 +174,9 @@ class TestRecheckRoad:
             assert snap["stages"][stage]["moved_bytes"] == 0
 
     def test_the_mesh_path_takes_the_same_stages(self, recorder, tmp_path):
-        """Eight devices: ``verify_batch`` a launch, whose sharded step
-        takes host rows — the transfer rides inside ``launch``."""
+        """Eight devices: one synchronous batch a launch, its stages the
+        flat road's three in the flat road's order — the sharded upload
+        is ``h2d`` and counts the moved bytes, ``launch`` moves nothing."""
         from torrent_tpu.ops.padding import padded_len_for
         from torrent_tpu.parallel.verify import verify_pieces
 
@@ -186,13 +187,70 @@ class TestRecheckRoad:
         mine = [s.removeprefix(TRACE_SPAN_PREFIX) for s in recorder.names(main)]
         assert mine == (
             ["pass_setup", "build_verifier", "pass_setup", "alloc_staging", "first_load"]
-            + ["batch", "launch", "digest", "read_wait", "batch", "launch", "digest"]
+            + ["batch", "h2d", "launch", "step_load", "digest"]
+            + ["read_wait", "batch", "h2d", "launch", "digest"]
         ), mine
-        for batch, launch, digest in zip(*(recorder.of(s) for s in ("batch", "launch", "digest"))):
-            assert recorder.inside(launch, batch) and recorder.inside(digest, batch)
-        launch = pipeline_ledger().snapshot()["stages"]["launch"]
-        assert launch["moved_bytes"] == 2 * BATCH * padded_len_for(PLEN)
-        assert launch["bytes"] == info.length
+        _no_metadata(recorder.names())
+        stages = [recorder.of(s) for s in ("batch", "h2d", "launch", "digest")]
+        for batch, h2d, launch, digest in zip(*stages, strict=True):
+            assert all(recorder.inside(s, batch) for s in (h2d, launch, digest))
+            assert h2d[3] <= launch[2] and launch[3] <= digest[2]  # one after the other
+        assert recorder.inside(recorder.of("step_load")[0], recorder.of("launch")[0])
+        snap = pipeline_ledger().snapshot()
+        assert set(snap["stages"]) == RECHECK_STAGES
+        h2d = snap["stages"]["h2d"]
+        assert h2d["ops"] == 2
+        assert h2d["moved_bytes"] == 2 * BATCH * padded_len_for(PLEN)
+        for stage in ("h2d", "launch", "digest"):
+            assert snap["stages"][stage]["bytes"] == info.length, stage
+        assert snap["stages"]["launch"]["moved_bytes"] == 0
+
+    @pytest.mark.parametrize("entry", ["verify_batch", "digest_batch"])
+    def test_batch_entry_points_on_a_mesh_take_the_same_stages(self, recorder, entry):
+        """``verify_batch`` / ``digest_batch`` called directly on the
+        eight-device mesh: the recheck's code, so the recheck's stages."""
+        from torrent_tpu.models.verifier import TPUVerifier
+        from torrent_tpu.ops.padding import digests_to_words, pad_pieces, words_to_digests
+
+        v = TPUVerifier(piece_length=PLEN, batch_size=BATCH)
+        assert v.mesh.size == 8 and not v._use_flat(np.zeros((BATCH, v.padded_len), np.uint8))
+        pieces = [bytes([i + 1]) * PLEN for i in range(BATCH)]
+        want = [hashlib.sha1(p).digest() for p in pieces]
+        padded, nblocks = pad_pieces(pieces)
+        if entry == "verify_batch":
+            want[3] = bytes(20)
+            ok = v.verify_batch(padded, nblocks, digests_to_words(want), nbytes=BATCH * PLEN)
+            assert list(ok) == [i != 3 for i in range(BATCH)]
+        else:
+            assert words_to_digests(v.digest_batch(padded, nblocks, nbytes=BATCH * PLEN)) == want
+        names = [s.removeprefix(TRACE_SPAN_PREFIX) for s in recorder.names(threading.get_ident())]
+        assert names == ["batch", "h2d", "launch", "digest"]  # no step_load: no pass began
+        stages = pipeline_ledger().snapshot()["stages"]
+        assert set(stages) == {"h2d", "launch", "digest"}
+        assert stages["h2d"]["moved_bytes"] == padded.nbytes and stages["h2d"]["bytes"] == BATCH * PLEN
+        assert stages["launch"]["moved_bytes"] == 0 and stages["digest"]["moved_bytes"] == 0
+
+    def test_the_multi_process_road_opens_no_h2d(self, recorder, monkeypatch):
+        """A mesh spanning processes keeps its fused launch: the global
+        arrays are assembled inside the dispatch, so ``launch`` carries
+        the moved bytes and no ``h2d`` entry stands beside it. (One
+        process plays the cluster: ``global_batch`` of all the rows is
+        what a process holding every shard would build.)"""
+        from torrent_tpu.models.verifier import TPUVerifier
+        from torrent_tpu.ops.padding import digests_to_words, pad_pieces
+
+        v = TPUVerifier(piece_length=PLEN, batch_size=BATCH)
+        monkeypatch.setattr(v, "_mesh_processes", 2)
+        assert not v.upload_supported(np.zeros((BATCH, v.padded_len), np.uint8))
+        pieces = [bytes([i + 1]) * PLEN for i in range(BATCH)]
+        padded, nblocks = pad_pieces(pieces)
+        expected = digests_to_words([hashlib.sha1(p).digest() for p in pieces])
+        assert v.verify_batch(padded, nblocks, expected, nbytes=BATCH * PLEN).all()
+        names = [s.removeprefix(TRACE_SPAN_PREFIX) for s in recorder.names(threading.get_ident())]
+        assert names == ["batch", "launch", "digest"]
+        stages = pipeline_ledger().snapshot()["stages"]
+        assert set(stages) == {"launch", "digest"}
+        assert stages["launch"]["moved_bytes"] == padded.nbytes
 
     def test_batch_entry_points_open_each_interval_once(self, recorder, one_device):
         from torrent_tpu.models.verifier import TPUVerifier

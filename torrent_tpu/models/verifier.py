@@ -148,8 +148,9 @@ class TPUVerifier:
         # memcpy. The earlier flatten→concat→reshape design is
         # gone for a reason: XLA's AOT lowering of the big 1-D→2-D
         # reshape materializes a (4,1)-subtiled intermediate padded 32x —
-        # a 16 GiB allocation at 512 KiB pieces. Multi-device meshes keep
-        # the sharded 2-D path (dryrun/tests, upload speed irrelevant).
+        # a 16 GiB allocation at 512 KiB pieces. Multi-device meshes take
+        # one batch-sharded 2-D put instead (_put_sharded: the road a
+        # four-chip host's recheck runs, timed as its own h2d stage).
         # Chunks arrive as host-order u32 (ndarray.view is free and a
         # u8→u32 bitcast on TPU lowers through a 4x-widened convert
         # fusion — the pallas kernel consumes u32 directly). The scan
@@ -265,17 +266,16 @@ class TPUVerifier:
             args.append(global_batch(self._shard, np.asarray(expected_words)))
         return args
 
-    def _put_local_sharded(self, *arrays):
-        """On a multi-process CLUSTER even a fully-addressable local
-        mesh can't take numpy args through a jit with non-trivial
-        in_shardings ("Passing non-trivial shardings for numpy inputs
-        is not allowed") — e.g. each pod host bulk-validating its
-        library shard on its own devices (verify_library_distributed).
-        Put them explicitly with the batch sharding; a no-op wrapper on
-        single-process runs."""
-        if jax.process_count() == 1:
-            return arrays
-        return tuple(jax.device_put(a, self._shard) for a in arrays)
+    def _put_sharded(self, *arrays):
+        """Single-process mesh input path: one explicit ``device_put`` of
+        the rows (and their ``nblocks`` / ``expected``) with the batch
+        sharding, awaited, so the staging buffer may be reused and the
+        caller's ``h2d`` stage times the transfer itself. Explicit also
+        because on a multi-process CLUSTER even a fully-addressable
+        local mesh can't take numpy args through a jit with non-trivial
+        in_shardings (each pod host bulk-validating its library shard
+        on its own devices, verify_library_distributed)."""
+        return jax.block_until_ready(jax.device_put(arrays, self._shard))
 
     def verify_batch_global(
         self, padded: np.ndarray, nblocks: np.ndarray, expected_words: np.ndarray
@@ -316,32 +316,47 @@ class TPUVerifier:
             self._digest_step_flat, self._digest_step, nbytes, padded, nblocks
         )
 
-    def _run_batch(self, step_flat, step, nbytes: int, padded, *rest) -> np.ndarray:
-        """One synchronous batch: the ``batch`` span (and, under
-        ``TORRENT_TPU_PROFILE``, one batch of the capture) around the
-        ledger's stages ``h2d`` (the chunked upload), ``launch`` (the
-        jitted call, an enqueue) and ``digest`` (the blocking fetch).
-        Off the flat path the sharded step takes host rows, so the
-        transfer rides inside its dispatch and ``launch`` carries the
-        moved bytes: no ``h2d`` entry of near-zero length is opened
-        beside it."""
-        led = pipeline_ledger()
-        fetch = np.asarray
-        with maybe_profile_batch(TRACE_SPAN_PREFIX + "batch"):
-            if self._use_flat(padded):
-                with led.track("h2d", nbytes, moved=padded.nbytes):
-                    chunks = self._put_flat(padded)
-                with led.track("launch", nbytes):
-                    out_dev = step_flat(chunks, *rest)
-            else:
-                put = self._put_local_sharded
-                if self._mesh_processes > 1:
-                    from torrent_tpu.parallel.distributed import local_values
+    def _enqueue(self, step_flat, step, nbytes: int, padded, *rest, first: bool = False):
+        """Upload one batch and dispatch its step: the ledger's stages
+        ``h2d`` (the blocking upload, with the padded slab as
+        ``moved_bytes``) and ``launch`` (the jitted call, an enqueue;
+        ``first`` marks a pass's first call, which traces the step and
+        loads its program: the ``step_load`` span). Returns the device
+        result and the function that fetches it.
 
-                    put, fetch = self._put_global, local_values
-                with led.track("launch", nbytes, moved=padded.nbytes):
-                    out_dev = step(*put(padded, *rest))
-            with led.track("digest", nbytes):
+        Where the transfer is counted, by road: one device takes the
+        flat road's chunked concurrent puts, a mesh of several local
+        devices one batch-sharded ``device_put`` (``_put_sharded``) —
+        both under ``h2d``, so ``launch`` moves nothing. Only a mesh
+        spanning processes keeps the fused call: its global arrays are
+        assembled from local rows inside the dispatch, so ``launch``
+        carries the moved bytes there and no ``h2d`` entry of near-zero
+        length is opened beside it."""
+        led = pipeline_ledger()
+        step_load = annotate("step_load") if first else nullcontext()
+        if self._mesh_processes > 1:
+            from torrent_tpu.parallel.distributed import local_values
+
+            with led.track("launch", nbytes, moved=padded.nbytes), step_load:
+                return step(*self._put_global(padded, *rest)), local_values
+        with led.track("h2d", nbytes, moved=padded.nbytes):
+            if self._use_flat(padded):
+                step, args = step_flat, (self._put_flat(padded), *rest)
+            else:
+                args = self._put_sharded(padded, *rest)
+        with led.track("launch", nbytes), step_load:
+            return step(*args), np.asarray
+
+    def _run_batch(
+        self, step_flat, step, nbytes: int, padded, *rest, first: bool = False
+    ) -> np.ndarray:
+        """One synchronous batch: the ``batch`` span (and, under
+        ``TORRENT_TPU_PROFILE``, one batch of the capture) around
+        :meth:`_enqueue`'s ``h2d`` and ``launch`` and the ledger stage
+        ``digest`` (the blocking fetch)."""
+        with maybe_profile_batch(TRACE_SPAN_PREFIX + "batch"):
+            out_dev, fetch = self._enqueue(step_flat, step, nbytes, padded, *rest, first=first)
+            with pipeline_ledger().track("digest", nbytes):
                 return fetch(out_dev)
 
     def upload_supported(self, padded) -> bool:
@@ -374,9 +389,7 @@ class TPUVerifier:
             return None
         if self._use_flat(padded):
             return ("flat", self._put_flat(padded))
-        dev = jax.device_put(padded, self._shard)
-        dev.block_until_ready()
-        return ("sharded", dev)
+        return ("sharded", self._put_sharded(padded)[0])
 
     def digest_uploaded(self, handle, nblocks: np.ndarray):
         """Async digest dispatch on an :meth:`upload_batch` handle.
@@ -437,7 +450,13 @@ class TPUVerifier:
         own) and ``pad``, in this thread the wait ``read_wait``, ``h2d``,
         ``launch`` (an enqueue; the first one of a pass traces the step
         and loads its program, the ``step_load`` span) and ``digest``
-        (the blocking fetch)."""
+        (the blocking fetch). The transfer is counted under ``h2d`` on
+        both roads: one device uploads batch *i+1* while batch *i*'s
+        result is in flight (the branch below, its own chunked puts);
+        a mesh of several local devices takes one synchronous batch at
+        a time (:meth:`_run_batch`: the ``batch`` span around the
+        sharded upload, the dispatch and the fetch), the loader's reads
+        beside it."""
         if info.piece_length != self.piece_length:
             raise ValueError(
                 f"verifier compiled for piece_length={self.piece_length}, "
@@ -545,7 +564,13 @@ class TPUVerifier:
                     while len(inflight) > 1:
                         drain_one()
                 else:
-                    ok = self.verify_batch(padded, nblocks, expected, nbytes)
+                    # a mesh: one synchronous batch at a time, nothing
+                    # in flight beside it; _enqueue puts the sharded
+                    # upload under h2d as the branch above puts its own
+                    ok = self._run_batch(
+                        self._verify_step_flat, self._verify_step, nbytes,
+                        padded, nblocks, expected, first=start == 0,
+                    )
                     bitfield[start : start + k] = ok[:k]
                     if progress_cb:
                         progress_cb(min(next_start, n), n)

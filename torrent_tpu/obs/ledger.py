@@ -42,6 +42,11 @@ Stage boundaries (instrumentation sites):
   ``bytes`` stays payload bytes like every stage's; ``moved_bytes``
   is the padded footprint that physically crossed, so
   ``moved_bytes / busy_s`` over a blocking upload is a transfer rate.
+  The verifier's batch roads count it here whether one device takes
+  the chunked flat puts or a mesh of several local devices one
+  batch-sharded ``device_put`` (``TPUVerifier._enqueue``); only a mesh
+  spanning processes fuses the transfer into its dispatch, and there
+  ``launch`` carries the ``moved_bytes`` and no ``h2d`` entry opens.
 * ``launch``  — the device (or hashlib) hash execution. On a device
   plane this is the jitted call, an ENQUEUE that returns before the
   device finishes (the first call of a pass also loads the program).

@@ -973,10 +973,10 @@ class _Sha1DevicePlane:
         led = pipeline_ledger()
         v = self._verifier
         if not v.upload_supported(padded):
-            # fused fallback (multi-process mesh, odd geometry): the
-            # transfer runs inside digest_batch's sharded dispatch, so
-            # it records the bytes under its own `launch` — never
-            # charged to a zero-length h2d span
+            # fallback (multi-process mesh, odd geometry): digest_batch
+            # opens its own stages — on a multi-process mesh the fused
+            # `launch` that carries the moved bytes, never a zero-length
+            # h2d span
             with self._device_lock:
                 return v.digest_batch(padded, nblocks, nb)
         with led.track("h2d", nb, moved=padded.nbytes):

@@ -353,7 +353,11 @@ class TPUVerifier:
         """One synchronous batch: the ``batch`` span (and, under
         ``TORRENT_TPU_PROFILE``, one batch of the capture) around
         :meth:`_enqueue`'s ``h2d`` and ``launch`` and the ledger stage
-        ``digest`` (the blocking fetch)."""
+        ``digest`` (the blocking fetch). For :meth:`verify_batch` and
+        :meth:`digest_batch` (the bridge, the scheduler's fused
+        fallback, authoring, the library sweep); a recheck pass
+        (:meth:`verify_storage`) calls :meth:`_enqueue` itself and
+        fetches a batch later."""
         with maybe_profile_batch(TRACE_SPAN_PREFIX + "batch"):
             out_dev, fetch = self._enqueue(step_flat, step, nbytes, padded, *rest, first=first)
             with pipeline_ledger().track("digest", nbytes):
@@ -451,12 +455,14 @@ class TPUVerifier:
         ``launch`` (an enqueue; the first one of a pass traces the step
         and loads its program, the ``step_load`` span) and ``digest``
         (the blocking fetch). The transfer is counted under ``h2d`` on
-        both roads: one device uploads batch *i+1* while batch *i*'s
-        result is in flight (the branch below, its own chunked puts);
-        a mesh of several local devices takes one synchronous batch at
-        a time (:meth:`_run_batch`: the ``batch`` span around the
-        sharded upload, the dispatch and the fetch), the loader's reads
-        beside it."""
+        both roads, and both keep ONE batch in flight: batch *i+1* is
+        uploaded and dispatched while batch *i*'s result is unfetched,
+        then batch *i* is fetched. One device uploads by its own
+        chunked puts (the first branch below); a mesh of several local
+        devices by :meth:`_enqueue`'s one batch-sharded ``device_put``,
+        so the chips hash batch *i* while the host uploads batch *i+1*.
+        The loader's reads run beside both. No ``batch`` span opens in
+        a pass: that is :meth:`_run_batch`'s, for its direct callers."""
         if info.piece_length != self.piece_length:
             raise ValueError(
                 f"verifier compiled for piece_length={self.piece_length}, "
@@ -506,16 +512,20 @@ class TPUVerifier:
             return padded, nblocks, expected, k, nbytes
 
         # Three overlapped stages: disk reads (loader thread) ahead of
-        # uploads (chunked concurrent puts) ahead of device compute
-        # (async dispatch). The async window is ONE batch — see the
-        # drain loop below for why it must not be widened.
+        # uploads (chunked concurrent puts, or one sharded put on a
+        # mesh) ahead of device compute (async dispatch). The async
+        # window is ONE batch on both roads — see the drain loop below
+        # for why it must not be widened.
         flat_path = self.mesh.size == 1
         inflight: deque = deque()
+        # what fetches a result: the mesh branch takes the function
+        # _enqueue hands back, which follows the mesh and not the batch
+        fetch = np.asarray
 
         def drain_one():
             start_i, k_i, nbytes_i, ok_dev = inflight.popleft()
             with led.track("digest", nbytes_i):
-                ok = np.asarray(ok_dev)
+                ok = fetch(ok_dev)
             bitfield[start_i : start_i + k_i] = ok[:k_i]
             if progress_cb:
                 progress_cb(min(start_i + b, n), n)
@@ -564,16 +574,23 @@ class TPUVerifier:
                     while len(inflight) > 1:
                         drain_one()
                 else:
-                    # a mesh: one synchronous batch at a time, nothing
-                    # in flight beside it; _enqueue puts the sharded
-                    # upload under h2d as the branch above puts its own
-                    ok = self._run_batch(
+                    # a mesh: the same window of one. _enqueue awaits
+                    # the sharded upload under h2d and dispatches; the
+                    # chips hash this batch while the next is uploaded,
+                    # two shards a device alive at most. The loader
+                    # refills this slab while the batch is in flight,
+                    # and the CPU backend's put aliases an aligned slab
+                    # where a chip's copies it: a copy there, as in
+                    # _put_flat.
+                    if self._upload_must_copy:
+                        padded = padded.copy()
+                    ok_dev, fetch = self._enqueue(
                         self._verify_step_flat, self._verify_step, nbytes,
                         padded, nblocks, expected, first=start == 0,
                     )
-                    bitfield[start : start + k] = ok[:k]
-                    if progress_cb:
-                        progress_cb(min(next_start, n), n)
+                    inflight.append((start, k, nbytes, ok_dev))
+                    while len(inflight) > 1:
+                        drain_one()
                 start = next_start
             while inflight:
                 drain_one()

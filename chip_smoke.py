@@ -173,7 +173,6 @@ def check_rows(words, view, lengths, rows, algo) -> None:
 def phase_device(ctx) -> dict:
     import jax
 
-    import bench
     from torrent_tpu.models.verifier import DEFAULT_TILE_BYTES
 
     stats = jax.devices()[0].memory_stats() or {}
@@ -183,7 +182,6 @@ def phase_device(ctx) -> dict:
         # what the code assumes of HBM, for comparison with the limit
         "assumed": {
             "TORRENT_TPU_TILE_BYTES": DEFAULT_TILE_BYTES,
-            "bench_resident_bytes": bench.RESIDENT_BUDGET_BYTES,
         },
     }
 

@@ -18,8 +18,7 @@ Covers the PR-11 observe→act loop:
 * the fabric rebalance hook: the laggard's offer list, peers adopting
   offered units through the ordinary adoption/trust path
 * surfaces: ``GET /v1/control``, ``torrent_tpu_control_*`` rendering,
-  the ``torrent-tpu top`` decision line, the ``bench controller`` A/B
-  record schema
+  the ``torrent-tpu top`` decision line
 """
 
 from __future__ import annotations
@@ -981,46 +980,3 @@ class TestSurfaces:
                 await svc.wait_closed()
 
         run(go())
-
-    def test_bench_controller_record_schema(self):
-        from torrent_tpu.tools.bench_cli import SCHEMA, _controller_ab
-
-        rec = run(_controller_ab(2, 256, 4), timeout=300)
-        assert rec["schema"] == SCHEMA
-        assert rec["rung"] == "controller"
-        assert rec["value"] is not None
-        assert rec["ab"]["controller_off_pps"] and rec["ab"]["controller_on_pps"]
-        assert rec["ab"]["ratio"] is not None
-        assert rec["fault"] == "latency_ms=25"
-        assert rec["decision"]["bottleneck"] in (None, *(
-            "read", "stage", "h2d", "launch", "digest", "verdict",
-        ))
-        assert "ledger" in rec and rec["ledger"]["stages"]
-
-    def test_trajectory_normalize_preserves_controller_keys(self, tmp_path):
-        import importlib.util
-        import os
-
-        spec = importlib.util.spec_from_file_location(
-            "summarize",
-            os.path.join(
-                os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-                ".bench", "summarize.py",
-            ),
-        )
-        mod = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(mod)
-        rec = {
-            "metric": "sha1_recheck_controller_ab_256KiB_pieces_per_sec",
-            "value": 758.1, "unit": "pieces/s", "rung": "controller",
-            "platform": "cpu", "batch": 8, "piece_kb": 256, "nproc": 8,
-            "bytes": 1 << 25, "fault": "latency_ms=25",
-            "ab": {"controller_off_pps": 500.4, "controller_on_pps": 758.1,
-                   "ratio": 1.515},
-            "decision": {"bottleneck": "h2d"},
-            "measured_at_utc": "2026-08-04T00:00:00Z",
-        }
-        out = mod._normalize(rec, "x.json")
-        for key in ("ab", "decision", "fault", "piece_kb", "bytes", "nproc"):
-            assert out[key] == rec[key], key
-        assert out["non_like_for_like"] is False

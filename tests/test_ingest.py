@@ -9,10 +9,9 @@ Covers the PR-8 refactor end to end:
   happy, shed, poisoned-ticket bisection, breaker CPU-fallback, and a
   mid-batch ``NativeIOError`` (regression: the slot is checked back in)
 * the ISSUE acceptance ledger assertions: no ``stage`` copy bytes on the
-  happy path, read→h2d occupancy overlap (``max_concurrent_stages ≥ 2``)
-  under the CPU-deterministic ``latency_ms`` H2D throttle, and the
-  scheduler-fed recheck bench rung (``torrent-tpu bench e2e``) embedding
-  the breakdown
+  happy path, and read→h2d occupancy overlap
+  (``max_concurrent_stages ≥ 2``) under the CPU-deterministic
+  ``latency_ms`` H2D throttle
 * scheduler semantics preserved under slot-backed submissions:
   admission shed, retry+bisection isolating a poisoned ticket while
   co-batched slot rows still verify, breaker degradation to the hashlib
@@ -464,35 +463,6 @@ class TestLedgerAcceptance:
             assert _staging(sched)["outstanding"] == 0
 
         run(go())
-
-    def test_bench_e2e_rung_embeds_breakdown(self):
-        """`torrent-tpu bench e2e` emits a banked-schema record with the
-        ledger breakdown + overlap + slab accounting embedded."""
-        from torrent_tpu.tools.bench_cli import SCHEMA, _e2e
-
-        rec = run(_e2e(2, 256, 4, "cpu"))
-        assert rec["schema"] == SCHEMA and rec["rung"] == "e2e"
-        assert rec["value"] is not None and rec["valid"] == rec["pieces"]
-        assert rec["staging_outstanding"] == 0
-        assert rec["ledger"]["stages"].get("stage", {}).get("bytes", 0) == 0
-        assert "overlap" in rec["ledger"]
-        # hashlib on the host: no JAX device is claimed
-        assert (rec["platform"], rec["device_kind"], rec["plane"]) == (
-            "cpu", "hashlib", "cpu")
-
-    def test_bench_e2e_names_the_platform_from_jax_not_the_flag(self):
-        """`--hasher tpu` on a CPU-only host runs XLA-CPU; the record
-        must say so (a banked record once wrote the flag there and was
-        read as a chip number)."""
-        import jax
-
-        from torrent_tpu.tools.bench_cli import _e2e
-
-        rec = run(_e2e(2, 256, 4, "tpu"))
-        assert rec["value"] is not None and rec["valid"] == rec["pieces"]
-        assert rec["plane"] == "tpu"
-        assert rec["platform"] == jax.devices()[0].platform == "cpu"
-        assert rec["device_kind"] == jax.devices()[0].device_kind
 
 
 class TestStagedSha256:

@@ -19,8 +19,11 @@ from torrent_tpu.utils.metrics import MetricsServer, render_metrics
 
 from test_session import build_torrent_bytes, fast_config, run, start_tracker
 
+# a label value is quoted and may hold any character but a raw newline,
+# with only \\, \" and \n escaped (so a `}` inside one ends nothing)
+_LABEL = r'[a-zA-Z_][a-zA-Z0-9_]*="(?:[^"\\\n]|\\[\\"n])*"'
 _SAMPLE = re.compile(
-    r"^[a-zA-Z_:][a-zA-Z0-9_:]*(\{[^}]*\})? [0-9.eE+-]+$"
+    rf"^[a-zA-Z_:][a-zA-Z0-9_:]*(\{{(?:{_LABEL}(?:,{_LABEL})*)?\}})? [0-9.eE+-]+$"
 )
 
 

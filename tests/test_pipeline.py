@@ -1,4 +1,4 @@
-"""Pipeline ledger, bottleneck attribution, and the bench harness.
+"""Pipeline ledger and bottleneck attribution.
 
 Covers the PR-7 observability plane end to end:
 
@@ -12,11 +12,8 @@ Covers the PR-7 observability plane end to end:
   injection throttling the H2D stage, a ``verify_library_sched`` run's
   ledger attributes the majority of pipeline wall time to ``h2d`` and
   both ``doctor --bottleneck`` machinery and ``GET /v1/pipeline`` name
-  it as the limiting stage (deterministic, CPU-only); ``torrent-tpu
-  bench --smoke`` emits banked-schema JSON with the ledger breakdown
-  embedded; ``bench --compare`` exits non-zero on a synthetically
-  injected regression vs a fixture record
-* ``torrent-tpu top`` frame rendering and the trajectory aggregator
+  it as the limiting stage (deterministic, CPU-only)
+* ``torrent-tpu top`` frame rendering
 """
 
 from __future__ import annotations
@@ -25,8 +22,6 @@ import asyncio
 import hashlib
 import json
 import os
-import subprocess
-import sys
 import time
 
 import numpy as np
@@ -384,127 +379,6 @@ async def _http(port: int, method: str, path: str, body: bytes):
     return status, resp, ctype
 
 
-class TestBenchHarness:
-    """torrent-tpu bench: banked-schema records with the ledger
-    breakdown embedded, self-banking, and the trajectory comparator."""
-
-    def _smoke_record(self, tmp_path, extra=()):
-        from torrent_tpu.tools import bench_cli
-
-        out = str(tmp_path / "record.json")
-        rc = bench_cli.main(
-            ["--smoke", "--mb", "1", "--piece-kb", "64", "--out", out,
-             *extra]
-        )
-        with open(out) as f:
-            return rc, json.load(f)
-
-    def test_smoke_emits_banked_schema_with_ledger(self, tmp_path, capsys):
-        rc, rec = self._smoke_record(tmp_path)
-        assert rc == 0
-        assert rec["schema"] == "torrent-tpu-bench/1"
-        assert rec["rung"] == "smoke"
-        assert rec["value"] is not None and rec["unit"] == "pieces/s"
-        assert rec["valid"] == rec["pieces"]
-        # the per-stage ledger breakdown is embedded in the record
-        assert rec["ledger"]["bottleneck"] is not None
-        for stage in ("read", "launch", "verdict"):
-            assert stage in rec["ledger"]["stages"]
-        # stdout carries exactly the record as one JSON line
-        line = capsys.readouterr().out.strip().splitlines()[-1]
-        assert json.loads(line)["metric"] == rec["metric"]
-
-    def test_compare_regression_exits_nonzero(self, tmp_path):
-        from torrent_tpu.tools import bench_cli
-
-        banked = {
-            "metric": "sha1_recheck_smoke_64KiB_pieces_per_sec",
-            "value": 1000.0, "unit": "pieces/s", "platform": "cpu",
-            "batch": 32,
-        }
-        traj = str(tmp_path / "traj.json")
-        with open(traj, "w") as f:
-            json.dump({"records": [banked]}, f)
-        # synthetically injected regression: 40% below the banked best
-        cand = dict(banked, value=600.0)
-        cand_path = str(tmp_path / "cand.json")
-        with open(cand_path, "w") as f:
-            json.dump(cand, f)
-        rc = bench_cli.main(
-            ["--record", cand_path, "--compare", "--trajectory", traj]
-        )
-        assert rc == 1
-        # within tolerance → ok
-        with open(cand_path, "w") as f:
-            json.dump(dict(banked, value=950.0), f)
-        assert bench_cli.main(
-            ["--record", cand_path, "--compare", "--trajectory", traj]
-        ) == 0
-        # report-only never fails
-        with open(cand_path, "w") as f:
-            json.dump(cand, f)
-        assert bench_cli.main(
-            ["--record", cand_path, "--compare", "--trajectory", traj,
-             "--report-only"]
-        ) == 0
-
-    def test_compare_unarmed_without_like_for_like(self, tmp_path, capsys):
-        from torrent_tpu.tools import bench_cli
-
-        traj = str(tmp_path / "traj.json")
-        with open(traj, "w") as f:
-            # same metric but a different batch shape AND a caveated
-            # record: neither arms the gate
-            json.dump({"records": [
-                {"metric": "m", "value": 100.0, "platform": "cpu",
-                 "batch": 512},
-                {"metric": "m", "value": 100.0, "platform": "cpu",
-                 "batch": 32, "non_like_for_like": True},
-            ]}, f)
-        cand_path = str(tmp_path / "cand.json")
-        with open(cand_path, "w") as f:
-            json.dump({"metric": "m", "value": 1.0, "platform": "cpu",
-                       "batch": 32}, f)
-        rc = bench_cli.main(
-            ["--record", cand_path, "--compare", "--trajectory", traj]
-        )
-        assert rc == 0
-        assert "unarmed" in capsys.readouterr().err
-
-    def test_bank_then_compare_gates(self, tmp_path):
-        """The self-banking loop: a banked smoke record arms the gate
-        for the next run of the same shape."""
-        from torrent_tpu.tools import bench_cli
-
-        traj = str(tmp_path / "traj.json")
-        rc, rec = self._smoke_record(
-            tmp_path, extra=["--bank", "--trajectory", traj]
-        )
-        assert rc == 0
-        records = bench_cli.load_trajectory(traj)
-        assert len(records) == 1 and records[0]["metric"] == rec["metric"]
-        # a regressed candidate of the same shape now fails the gate
-        cand = dict(records[0], value=records[0]["value"] * 0.1)
-        code, msg = bench_cli.compare_record(cand, records)
-        assert code == 1 and "REGRESSION" in msg
-        # and the genuine record passes against itself
-        code, msg = bench_cli.compare_record(records[0], records)
-        assert code == 0
-
-    def test_null_value_record_fails(self, tmp_path):
-        from torrent_tpu.tools import bench_cli
-
-        cand_path = str(tmp_path / "cand.json")
-        with open(cand_path, "w") as f:
-            json.dump({"metric": "m", "value": None}, f)
-        assert bench_cli.main(["--record", cand_path]) == 1
-
-    def test_usage_errors(self):
-        from torrent_tpu.tools import bench_cli
-
-        assert bench_cli.main([]) == 2  # no rung, no record
-
-
 class TestTopRendering:
     def test_render_frame(self):
         payload = {
@@ -547,85 +421,3 @@ class TestTopRendering:
 
         frame = render_top({"attribution": {"wall_s": 0.0, "stages": {}}})
         assert "idle" in frame
-
-
-class TestTrajectoryAggregation:
-    @staticmethod
-    def _bank(tmp_path):
-        """A record bank under tmp_path with the aggregator beside it
-        (summarize.py's bank is the directory it sits in): two stable
-        live records — one carrying a shape caveat —, a timestamped
-        audit copy, a loose rung artifact and a null that must be
-        filtered."""
-        import shutil
-
-        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-        bank = tmp_path / "bank"
-        (bank / "live").mkdir(parents=True)
-        script = bank / "summarize.py"
-        shutil.copy(os.path.join(repo, ".bench", "summarize.py"), script)
-        metric = "sha1_recheck_256KiB_pieces_per_sec"
-        wide = {"metric": metric, "value": 137804.6, "unit": "pieces/s",
-                "vs_baseline": 24.11, "platform": "tpu", "batch": 8192,
-                "banked_at_utc": "2026-07-30T07:10:51Z"}
-        narrow = {**wide, "value": 246511.0, "batch": 512,
-                  "banked_at_utc": "2026-08-02T15:50:39Z",
-                  "like_for_like": "B=512 x 24 dispatches; not the B=8192 shape"}
-        (bank / "live" / f"{metric}.json").write_text(json.dumps(wide))
-        (bank / "live" / f"{metric}.20260802T155039Z.json").write_text(
-            json.dumps(narrow))
-        (bank / "cfg_author.json").write_text(json.dumps(
-            {"metric": "sha1_author_256KiB_pieces_per_sec", "value": 133480.8,
-             "unit": "pieces/s", "platform": "tpu"}))
-        (bank / "null.json").write_text(json.dumps(
-            {"metric": metric, "value": None, "unit": "pieces/s"}))
-        return str(script)
-
-    def test_summarize_trajectory_marks_shape_caveats(self, tmp_path):
-        """summarize.py --trajectory aggregates a bank into one
-        machine-readable file, preserving like-for-like caveats."""
-        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-        out = str(tmp_path / "traj.json")
-        proc = subprocess.run(
-            [sys.executable, self._bank(tmp_path), "--trajectory", out],
-            capture_output=True, text=True, timeout=60,
-        )
-        assert proc.returncode == 0, proc.stderr
-        with open(out) as f:
-            data = json.load(f)
-        assert data["schema"] == "torrent-tpu-bench-trajectory/1"
-        recs = data["records"]
-        assert len(recs) == 3, recs  # the null record is filtered
-        assert all(r["value"] is not None for r in recs)
-        # the B=512 narrow-batch record carries its shape caveat
-        caveated = [r for r in recs if r["non_like_for_like"]]
-        assert [(r["metric"], r["batch"]) for r in caveated] == [
-            ("sha1_recheck_256KiB_pieces_per_sec", 512)
-        ], recs
-        # the committed trajectory matches the aggregator's schema
-        committed = os.path.join(repo, "BENCH_trajectory.json")
-        with open(committed) as f:
-            assert json.load(f)["schema"] == data["schema"]
-
-    def test_regeneration_preserves_self_banked_records(self, tmp_path):
-        """`bench --bank` records exist only in the trajectory file;
-        regenerating it from a bank must merge them back or the CI
-        comparator they armed is silently disarmed."""
-        from torrent_tpu.tools import bench_cli
-
-        out = str(tmp_path / "traj.json")
-        banked = {"metric": "sha1_recheck_smoke_256KiB_pieces_per_sec",
-                  "value": 3000.0, "unit": "pieces/s", "platform": "cpu",
-                  "batch": 32, "rung": "smoke",
-                  "schema": "torrent-tpu-bench/1"}
-        bench_cli.bank_record(banked, out)
-        proc = subprocess.run(
-            [sys.executable, self._bank(tmp_path), "--trajectory", out],
-            capture_output=True, text=True, timeout=60,
-        )
-        assert proc.returncode == 0, proc.stderr
-        records = bench_cli.load_trajectory(out)
-        kept = [r for r in records if r["metric"] == banked["metric"]]
-        assert kept and kept[0]["value"] == 3000.0, records
-        # and the bank's aggregated records are present alongside it
-        assert any(r.get("artifact") for r in records)

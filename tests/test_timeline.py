@@ -982,38 +982,3 @@ class TestFleetBudgetHealth:
 
         roll = aggregate_fleet({0: {"wall_s": 1.0, "stages": {}, "unit": {}}})
         assert roll["slo"] is None
-
-
-class TestTrajectoryPreservesSchema:
-    def test_summarize_normalize_keeps_timeline_and_slo_keys(self):
-        import importlib.util
-        import pathlib
-
-        spec = importlib.util.spec_from_file_location(
-            "bench_summarize",
-            pathlib.Path(__file__).resolve().parent.parent
-            / ".bench" / "summarize.py",
-        )
-        mod = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(mod)
-        rec = {
-            "metric": "sha1_recheck_smoke_256KiB_pieces_per_sec",
-            "value": 500.0, "unit": "pieces/s", "batch": 32,
-            "platform": "cpu", "piece_kb": 256, "bytes": 1 << 23, "nproc": 4,
-            "timeline": {"samples": 2, "drops": 0, "limiting": "launch"},
-            "slo": {"worst": {"objective": "availability", "burn_rate": 0.0},
-                    "breach_any": False, "objectives": {}},
-        }
-        out = mod._normalize(rec, "live/r.json")
-        assert out["timeline"] == rec["timeline"]
-        assert out["slo"] == rec["slo"]
-        assert out["non_like_for_like"] is False
-
-    def test_bench_smoke_record_embeds_timeline_and_slo(self):
-        from torrent_tpu.tools.bench_cli import _smoke
-
-        rec = run(_smoke(total_mb=1, piece_kb=256, batch_target=8), timeout=120)
-        assert rec["timeline"]["samples"] == 2
-        assert rec["slo"]["breach_any"] is False
-        assert "availability" in rec["slo"]["objectives"]
-        assert rec["value"] is not None

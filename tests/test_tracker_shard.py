@@ -4,8 +4,7 @@ Unit coverage of the store (shard routing, O(numwant) reservoir
 sampling, swap-remove consistency, server-side reply bounds, per-shard
 TTL sweeps, batch processing), service-level coverage over the real
 HTTP/UDP transports (our client against our sharded server), the
-tracker /metrics route, the doctor --announce smoke, and the bench
-announce rung's record schema.
+tracker /metrics route, and the doctor --announce smoke.
 """
 
 import asyncio
@@ -462,93 +461,3 @@ class TestDoctorAnnounceSmoke:
 
         detail = run(_announce_smoke())
         assert "reconcile" in detail
-
-
-class TestBenchAnnounceRung:
-    def test_storm_record_schema_and_occupancy(self):
-        from torrent_tpu.tools.bench_cli import (
-            ANNOUNCE_MIN_SHARDS_HIT,
-            SCHEMA,
-            _announce_storm,
-        )
-
-        rec = run(_announce_storm(
-            clients=4, swarms=16, per_client=120, shards=8, numwant=10))
-        assert rec["schema"] == SCHEMA and rec["rung"] == "announce"
-        assert rec["unit"] == "announces/s"
-        assert rec["value"] is not None and rec["value"] > 0
-        assert rec["contract"] == "median-of-3" and len(rec["rates"]) == 3
-        assert rec["shards_hit"] >= ANNOUNCE_MIN_SHARDS_HIT
-        occ = rec["shard_occupancy"]
-        assert len(occ) == 8 and sum(occ.values()) == rec["store"]["peers"]
-        lat = rec["latency"]
-        assert lat["p50_us"] is not None and lat["p99_us"] >= lat["p50_us"]
-        # the like-for-like shape key fields the comparator gates on
-        for key in ("metric", "platform", "batch", "nproc"):
-            assert rec.get(key) is not None
-
-    def test_bank_then_compare_gates(self, tmp_path):
-        from torrent_tpu.tools.bench_cli import main as bench_main
-
-        traj = str(tmp_path / "traj.json")
-        small = ["announce", "--clients", "2", "--swarms", "16",
-                 "--per-client", "60", "--shards", "8", "--numwant", "5",
-                 "--trajectory", traj]
-        assert bench_main(small + ["--bank"]) == 0
-        # like-for-like record banked → the comparator is ARMED and passes
-        assert bench_main(small + ["--compare", "--tolerance", "0.99"]) == 0
-
-    def test_trajectory_normalize_preserves_announce_keys(self):
-        """`.bench/summarize.py --trajectory` regeneration must keep the
-        announce rung's schema keys (storm shape, occupancy proof,
-        latency summary) — same treatment the controller rung got."""
-        import importlib.util
-        import os
-
-        spec = importlib.util.spec_from_file_location(
-            "summarize",
-            os.path.join(
-                os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-                ".bench", "summarize.py",
-            ),
-        )
-        mod = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(mod)
-        rec = {
-            "metric": "tracker_announce_storm_32sw_announces_per_sec",
-            "value": 50000.0, "unit": "announces/s", "rung": "announce",
-            "platform": "cpu", "batch": 8, "nproc": 8,
-            "contract": "median-of-3", "clients": 8, "swarms": 32,
-            "shards": 8, "shards_hit": 8, "numwant": 30,
-            "announces": 16000, "rates": [49000.0, 50000.0, 51000.0],
-            "latency": {"p50_us": 20.0, "p99_us": 90.0, "max_us": 400.0},
-            "shard_occupancy": {"0": 2000, "1": 2000},
-            "store": {"peers": 16000},
-            "measured_at_utc": "2026-08-04T00:00:00Z",
-        }
-        out = mod._normalize(rec, "x.json")
-        for key in ("contract", "clients", "swarms", "shards", "shards_hit",
-                    "numwant", "announces", "rates", "latency",
-                    "shard_occupancy", "store", "nproc"):
-            assert out[key] == rec[key], key
-        assert out["non_like_for_like"] is False
-
-    def test_sub_floor_config_rejected_upfront(self, capsys):
-        """Review fix: --shards/--swarms below the >=4-shard acceptance
-        floor fail fast with a usage error, not a misleading null-value
-        failure after a full storm."""
-        from torrent_tpu.tools.bench_cli import main as bench_main
-
-        assert bench_main(["announce", "--shards", "2"]) == 2
-        assert ">= 4" in capsys.readouterr().err
-        assert bench_main(["announce", "--swarms", "3"]) == 2
-
-    def test_single_shard_storm_fails_acceptance(self):
-        """The banked rate must come from cross-shard concurrency: a
-        one-shard store cannot satisfy the >= 4 shards-hit floor, so the
-        record's value is null (rung failed)."""
-        from torrent_tpu.tools.bench_cli import _announce_storm
-
-        rec = run(_announce_storm(
-            clients=2, swarms=4, per_client=30, shards=1, numwant=5))
-        assert rec["value"] is None and rec["shards_hit"] == 1

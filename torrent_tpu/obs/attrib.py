@@ -20,8 +20,8 @@ System Accelerators" (PAPERS.md):
 The **limiting stage** is the one with the highest utilization (ties
 broken toward more bytes — the stage doing real pipeline volume).
 Attribution works on a single since-start snapshot or on the delta
-between two (``prev=``) — ``doctor --bottleneck`` and the bench
-harness use deltas so one process can attribute several runs.
+between two (``prev=``) — ``doctor --bottleneck`` uses deltas so one
+process can attribute several runs.
 
 Pure functions over plain dicts: no locks, no globals, trivially
 testable, and safe to call from the bridge's serving loop.
@@ -143,7 +143,7 @@ def format_rate(bps: float | None) -> str:
 
 
 def format_report(report: dict) -> str:
-    """One-paragraph human rendering (doctor --bottleneck, bench logs)."""
+    """One-paragraph human rendering (doctor --bottleneck)."""
     bn = report.get("bottleneck")
     if bn is None:
         return "pipeline idle: no stage activity recorded"

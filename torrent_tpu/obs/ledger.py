@@ -13,8 +13,8 @@ bounded process-global table, and ``obs/attrib.py`` turns any two
 snapshots into a bottleneck verdict ("h2d is 96% of pipeline wall
 time, 24.9 MiB/s achieved vs 2.1 GiB/s demanded"). Surfaced as
 ``GET /v1/pipeline``, ``torrent_tpu_pipeline_*`` Prometheus series on
-both ``/metrics`` endpoints, ``doctor --bottleneck``, ``torrent-tpu
-top``, and embedded in every ``torrent-tpu bench`` record.
+both ``/metrics`` endpoints, ``doctor --bottleneck`` and ``torrent-tpu
+top``.
 
 Stage boundaries (instrumentation sites):
 

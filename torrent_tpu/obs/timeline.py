@@ -530,6 +530,8 @@ def replay_report(timeline_snap: dict, objectives=None) -> dict:
     for prev, cur in zip(samples, samples[1:]):
         rep = attribute(_sample_to_ledger(cur), prev=_sample_to_ledger(prev))
         bn = rep.get("bottleneck")
+        sched = cur.get("sched")
+        sched = sched if isinstance(sched, dict) else {}
         intervals.append(
             {
                 # age of this interval's END relative to the newest
@@ -540,10 +542,8 @@ def replay_report(timeline_snap: dict, objectives=None) -> dict:
                 "utilization": bn.get("utilization") if bn else None,
                 "pipeline_bps": rep.get("pipeline_bps"),
                 "sched": {
-                    "shed": (cur.get("sched") or {}).get("shed", 0),
-                    "failed_pieces": (cur.get("sched") or {}).get(
-                        "failed_pieces", 0
-                    ),
+                    "shed": sched.get("shed", 0),
+                    "failed_pieces": sched.get("failed_pieces", 0),
                 },
             }
         )

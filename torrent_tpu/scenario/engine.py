@@ -298,9 +298,8 @@ def run_scenario(
     ``scenario/verdict.py``), the full timeline ring snapshot (the
     ``torrent-tpu replay`` payload), and the wall-plane latency report.
 
-    ``store`` may be a pre-filled :class:`ShardedSwarmStore` — the
-    bench rung fills one with a million swarms first — but it MUST have
-    been built with a :class:`VirtualClock` and a seeded rng; the
+    ``store`` may be a pre-filled :class:`ShardedSwarmStore`, but it MUST
+    have been built with a :class:`VirtualClock` and a seeded rng; the
     engine adopts them so the virtual timeline stays coherent.
     """
     if store is None:

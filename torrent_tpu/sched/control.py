@@ -59,9 +59,7 @@ backends are the lane plan's. ``decide`` still runs in disabled mode
 
 Surfaces: ``GET /v1/control`` (last decision + inputs + actuator
 values), ``torrent_tpu_control_*`` on both ``/metrics`` endpoints, a
-decision line in ``torrent-tpu top``, ``doctor --control``, and the
-``bench controller`` A/B rung (controller-on vs controller-off under a
-``sched/faults.py`` throttle, banked).
+decision line in ``torrent-tpu top``, and ``doctor --control``.
 """
 
 from __future__ import annotations

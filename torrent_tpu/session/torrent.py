@@ -2814,7 +2814,7 @@ class Torrent:
         self.state = TorrentState.SEEDING
         self._endgame = False
         # the download's tail recv charges must be attributable NOW — a
-        # doctor/bench reading /v1/pipeline right after completion must
+        # doctor reading /v1/pipeline right after completion must
         # not miss the last partial batch
         self._recv_flush()
         if not self._completed_reported:

@@ -15,8 +15,6 @@ One multiplexed entry point over the whole framework::
                          [--cpu-devices K] [--heartbeat-dir DIR] [--hasher cpu|tpu]
                          [--obs-port P] [--fault-plan SPEC]
     torrent-tpu top      [--url URL] [--interval S] [--once] [--fleet]
-    torrent-tpu bench    [smoke|v2|fabric|flagship] [--compare] [--bank]
-                         [--trajectory FILE] [--tolerance F] [--report-only]
 
 ``download`` accepts either a ``.torrent`` file or a ``magnet:?...`` URI
 (BEP 9 metadata fetch). Also runnable as ``python -m torrent_tpu``.
@@ -1041,9 +1039,9 @@ async def _fabric_verify(args) -> int:
         "distrusted": snap["distrusted"],
         "stragglers": snap["stragglers"],
         "seconds": res.seconds,
-        # this process's pipeline-ledger breakdown (bench fabric embeds
-        # these per worker) and its final view of the fleet — which peer
-        # limited the sweep, and which stage inside it
+        # this process's pipeline-ledger breakdown and its final view of
+        # the fleet — which peer limited the sweep, and which stage
+        # inside it
         "ledger": {
             "wall_s": led_rep["wall_s"],
             "stages": led_rep["stages"],
@@ -1340,39 +1338,6 @@ def _cmd_serve(args) -> int:
         if args.slo is not True:
             argv.append(args.slo)
     return serve_main(argv)
-
-
-def _cmd_bench(args) -> int:
-    from torrent_tpu.tools.bench_cli import main as bench_main
-
-    argv: list[str] = []
-    if args.rung:
-        argv.append(args.rung)
-    if args.smoke:
-        argv.append("--smoke")
-    argv += ["--mb", str(args.mb), "--piece-kb", str(args.piece_kb),
-             "--batch-target", str(args.batch_target),
-             "--hasher", args.hasher,
-             "--clients", str(args.clients), "--swarms", str(args.swarms),
-             "--per-client", str(args.per_client),
-             "--shards", str(args.shards), "--numwant", str(args.numwant),
-             "--leechers", str(args.leechers),
-             "--tolerance", str(args.tolerance)]
-    if args.timeout is not None:
-        argv += ["--timeout", str(args.timeout)]
-    if args.out:
-        argv += ["--out", args.out]
-    if args.record:
-        argv += ["--record", args.record]
-    if args.trajectory:
-        argv += ["--trajectory", args.trajectory]
-    if args.compare:
-        argv.append("--compare")
-    if args.report_only:
-        argv.append("--report-only")
-    if args.bank:
-        argv.append("--bank")
-    return bench_main(argv)
 
 
 def _cmd_edit(args) -> int:
@@ -2205,56 +2170,6 @@ def build_parser() -> argparse.ArgumentParser:
                     "= the default availability+integrity contract)")
     sp.add_argument("--timeline-interval", type=float, default=2.0)
     sp.set_defaults(fn=_cmd_serve)
-
-    sp = sub.add_parser(
-        "bench",
-        help="unified bench rungs (smoke/v2/fabric/flagship): banked-"
-        "schema records with the pipeline-ledger stage breakdown "
-        "embedded, plus the trajectory comparator",
-    )
-    sp.add_argument("rung", nargs="?",
-                    choices=("smoke", "e2e", "v2", "fabric", "flagship",
-                             "controller", "announce", "swarm", "seed"))
-    sp.add_argument("--smoke", action="store_true",
-                    help="alias for the smoke rung (the CI spelling)")
-    sp.add_argument("--mb", type=int, default=8,
-                    help="smoke rung payload MiB (default %(default)s)")
-    sp.add_argument("--piece-kb", type=int, default=256,
-                    help="smoke rung piece KiB (default %(default)s)")
-    sp.add_argument("--batch-target", type=int, default=32,
-                    help="smoke rung scheduler launch target")
-    sp.add_argument("--hasher", default="tpu", choices=("tpu", "cpu"),
-                    help="e2e rung hash plane (default %(default)s)")
-    sp.add_argument("--clients", type=int, default=8,
-                    help="announce rung announcer threads")
-    sp.add_argument("--swarms", type=int, default=32,
-                    help="announce rung distinct info-hashes")
-    sp.add_argument("--per-client", type=int, default=2000,
-                    help="announce rung announces per client per rep")
-    sp.add_argument("--shards", type=int, default=8,
-                    help="announce rung store shard count")
-    sp.add_argument("--numwant", type=int, default=30,
-                    help="announce rung peers requested per announce")
-    sp.add_argument("--leechers", type=int, default=64,
-                    help="seed rung concurrent loopback leechers "
-                    "(default %(default)s)")
-    sp.add_argument("--timeout", type=float, default=None,
-                    help="device-rung subprocess timeout seconds")
-    sp.add_argument("--out", default=None, help="also write the record here")
-    sp.add_argument("--record", default=None, metavar="FILE",
-                    help="skip the run; compare/bank this record instead")
-    sp.add_argument("--compare", action="store_true",
-                    help="gate the record against the banked trajectory "
-                    "(unarmed when no like-for-like record is banked)")
-    sp.add_argument("--trajectory", default=None, metavar="FILE",
-                    help="trajectory file (default BENCH_trajectory.json)")
-    sp.add_argument("--tolerance", type=float, default=0.10,
-                    help="allowed fractional regression (default %(default)s)")
-    sp.add_argument("--report-only", action="store_true",
-                    help="comparator reports but never fails the run")
-    sp.add_argument("--bank", action="store_true",
-                    help="append the record to the trajectory (self-banking)")
-    sp.set_defaults(fn=_cmd_bench)
 
     sp = sub.add_parser("tracker", help="run the in-memory tracker server")
     sp.add_argument("--http-port", type=int, default=8080)

@@ -158,8 +158,7 @@ class _LoopbackSwarm:
     """Shared two-client loopback scaffold for the swarm smokes: tmp
     payload file → in-memory tracker → seed + leech clients → download
     to completion. One copy of the port-0/teardown plumbing serves both
-    doctor smokes (the bench swarm rung keeps its own rep-scoped
-    variant — it times each leg and recreates the tracker per rep)."""
+    doctor smokes."""
 
     def __init__(self, tmp: str, payload: bytes, name: str,
                  piece_length: int = 16384, seed_bps: int = 0):
@@ -293,8 +292,7 @@ async def _swarm_wire_smoke(tmp: str) -> str:
             # attribute the ROUTE's served snapshot against this
             # smoke's start (the ledger is process-global and
             # cumulative: another doctor flag's scheduler traffic must
-            # not make a healthy system fail this check — the same
-            # delta discipline bench uses)
+            # not make a healthy system fail this check)
             from torrent_tpu.obs.attrib import attribute
 
             bn = (attribute(pipe["snapshot"], prev=prev) or {}).get(

@@ -78,8 +78,7 @@ def run_sweep(
     if interpret:
         jax.config.update("jax_platforms", "cpu")  # smoke-test mode
     # a sweep compiles every grid config — persist the compiles so a
-    # re-sweep (or the bench run that follows with the winning knobs)
-    # skips straight to execution
+    # re-sweep skips straight to execution
     enable_compile_cache()
     import jax.numpy as jnp
 

@@ -194,7 +194,7 @@ def run_sweep(
         # the winner as ready-to-export env knobs: the scheduler's pallas
         # plane and models/v2's leaf fn read these at import, so a
         # script can `export $(jq ...)` the sweep result straight into
-        # the bench run
+        # the run that uses them
         env = {
             "TORRENT_TPU_SHA256_TILE_SUB": best["tile_sub"],
             "TORRENT_TPU_SHA256_UNROLL": best["unroll"],

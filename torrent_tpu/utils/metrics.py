@@ -17,8 +17,23 @@ from torrent_tpu.utils.log import get_logger
 log = get_logger("utils.metrics")
 
 
+# The text format escapes \\, \" and \n in a label value and nothing
+# else, so a raw control character or any other line boundary that
+# ``str.splitlines()`` (and a scraper's line reader) honours, such as
+# \r, U+0085 or U+2028 from a wire-supplied peer id, would cut the sample
+# in two. Those are written as the literal text \xNN / \uNNNN behind an
+# escaped backslash.
+_LABEL_ESCAPES = {
+    **{c: f"\\\\x{c:02x}" for c in (*range(0x20), *range(0x7F, 0xA0))},
+    **{c: f"\\\\u{c:04x}" for c in (0x2028, 0x2029)},
+    ord("\\"): "\\\\",
+    ord('"'): '\\"',
+    ord("\n"): "\\n",
+}
+
+
 def _esc(value: str) -> str:
-    return value.replace("\\", "\\\\").replace('"', '\\"').replace("\n", "\\n")
+    return value.translate(_LABEL_ESCAPES)
 
 
 def render_sched_metrics(sched) -> str:

@@ -165,6 +165,9 @@ def ladder_plane(request):
     from torrent_tpu.sched.scheduler import _Sha1DevicePlane
 
     mp = pytest.MonkeyPatch()
+    # the jitted steps are the process's (one set a mesh): a set of the
+    # plane's own, so that the programs counted below are the plane's
+    mp.setattr(verifier_mod, "_step_cache", verifier_mod._StepCache(1))
     if request.param == "one_device":
         real = verifier_mod.make_mesh
         mp.setattr(verifier_mod, "make_mesh", lambda devices=None: real(jax.devices()[:1]))

@@ -22,11 +22,13 @@ traffic is output gathering.
 from __future__ import annotations
 
 
-from torrent_tpu.analysis.sanitizer import named_lock
+from torrent_tpu.analysis.sanitizer import guard_attrs, named_lock
 import time
-from collections import deque
+from collections import OrderedDict, deque
+from collections.abc import Callable
 from concurrent.futures import ThreadPoolExecutor
 from contextlib import nullcontext
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -69,6 +71,184 @@ STEP_MODULE_NAMES = frozenset(
 DEFAULT_TILE_BYTES = 1_342_177_280
 
 
+# How many step sets a process keeps. A key is a backend, its Pallas
+# tile and a mesh: a deployment has one or two, the tests a handful.
+STEP_CACHE_CAPACITY = 8
+
+
+class _Steps(NamedTuple):
+    """The five jitted steps of one (backend, tile_sub, mesh)."""
+
+    digest_step: Callable
+    verify_step: Callable
+    verify_step_flat: Callable
+    digest_step_flat: Callable
+    digest_step_donated: Callable
+
+
+def _on_cpu(mesh) -> bool:
+    return next(iter(mesh.devices.flat)).platform == "cpu"
+
+
+def _pallas_tile_sub(padded_len: int) -> int:
+    """Adaptive tiling: one tile row (tile_sub*128 pieces) is the
+    kernel's swizzle/launch granularity, and its temporaries are ~2x
+    the tile slab. Big pieces shrink the sublane count so a tile stays
+    ~1 GiB regardless of piece size (the sweep's measured-best regime;
+    at 4096x1 MiB a whole-batch slab OOMs a 16 GB chip outright)."""
+    from torrent_tpu.ops.sha1_pallas import TILE_SUB
+
+    budget = env_int("TORRENT_TPU_TILE_BYTES", DEFAULT_TILE_BYTES)
+    ts = TILE_SUB
+    # step by 8s, not halving: the env default may be any multiple
+    # of 8 (halving 24 would land on 12 and crash _check_tiling)
+    while ts > 8 and ts * 128 * padded_len > budget:
+        ts -= 8
+    return ts
+
+
+def _build_steps(backend: str, tile_sub: int | None, mesh) -> _Steps:
+    """Wrap the steps of one key in ``jax.jit``. Nothing is traced or
+    compiled here: that happens at a jitted object's first call with a
+    shape, once a process, since every verifier of the key calls the
+    same objects. Piece length and batch size are shapes, and
+    ``jax.jit`` keys on shapes itself."""
+    sha1_fn = make_sha1_fn(backend)
+    pallas = backend == "pallas"
+    if pallas:
+        # A pallas_call has no SPMD partitioning rule, so on a >1-device
+        # mesh we shard it explicitly: each device runs the kernel on its
+        # local piece sub-batch (embarrassingly parallel, no collectives).
+        from jax import shard_map
+        from jax.sharding import PartitionSpec as P
+
+        from torrent_tpu.ops.sha1_pallas import sha1_pieces_pallas
+
+        def sha1_fn(data, nblocks, _ts=tile_sub):
+            return sha1_pieces_pallas(data, nblocks, tile_sub=_ts)
+
+        if mesh.size > 1:
+            spec = P(tuple(mesh.axis_names))
+            sha1_fn = shard_map(
+                sha1_fn,
+                mesh=mesh,
+                in_specs=(spec, spec),
+                out_specs=spec,
+                check_vma=False,
+            )
+    shard = batch_sharding(mesh)
+
+    def _digests(data_u8, nblocks):
+        return sha1_fn(data_u8, nblocks)
+
+    def _verify(data_u8, nblocks, expected):
+        words = sha1_fn(data_u8, nblocks)
+        return jnp.all(words == expected, axis=1)
+
+    # Fast single-device upload path: row-block 2-D chunks put in
+    # parallel, joined with one axis-0 concat on device. padded_len is
+    # 128-byte aligned (ops/padding.py), so a 2-D put is a straight
+    # memcpy. The earlier flatten→concat→reshape design is
+    # gone for a reason: XLA's AOT lowering of the big 1-D→2-D
+    # reshape materializes a (4,1)-subtiled intermediate padded 32x —
+    # a 16 GiB allocation at 512 KiB pieces. Multi-device meshes take
+    # one batch-sharded 2-D put instead (_put_sharded: the road a
+    # four-chip host's recheck runs, timed as its own h2d stage).
+    # Chunks arrive as host-order u32 (ndarray.view is free and a
+    # u8→u32 bitcast on TPU lowers through a 4x-widened convert
+    # fusion — the pallas kernel consumes u32 directly). The scan
+    # backend still wants u8 rows; the bitcast back is cheap there
+    # (CPU/GPU lower it as a real reinterpret).
+    def _join(chunks):
+        data = jnp.concatenate(chunks, axis=0)
+        if not pallas:
+            data = jax.lax.bitcast_convert_type(data, jnp.uint8).reshape(
+                data.shape[0], -1
+            )
+        return data
+
+    def _verify_flat(chunks, nblocks, expected):
+        words = sha1_fn(_join(chunks), nblocks)
+        return jnp.all(words == expected, axis=1)
+
+    def _digests_flat(chunks, nblocks):
+        return sha1_fn(_join(chunks), nblocks)
+
+    # Donate the uploaded chunks on real accelerators: the launch
+    # consumes them exactly once, so freeing the device input buffer
+    # as the kernel runs lets the NEXT batch's H2D reuse that memory
+    # — the double-buffered ingest contract the scheduler's sha1
+    # plane relies on. XLA-CPU refuses donation (it would only emit
+    # a warning per launch), so it stays off there.
+    _donate = () if _on_cpu(mesh) else (0,)
+    return _Steps(
+        digest_step=jax.jit(_digests, in_shardings=(shard, shard), out_shardings=shard),
+        verify_step=jax.jit(
+            _verify, in_shardings=(shard, shard, shard), out_shardings=shard
+        ),
+        verify_step_flat=jax.jit(_verify_flat, donate_argnums=_donate),
+        digest_step_flat=jax.jit(_digests_flat, donate_argnums=_donate),
+        # the sharded twin of the donated digest step, for upload_batch
+        # on a >1-device mesh (compiled only if that path runs)
+        digest_step_donated=jax.jit(
+            _digests, in_shardings=(shard, shard), out_shardings=shard,
+            donate_argnums=_donate,
+        ),
+    )
+
+
+class _StepCache:
+    """The process's jitted steps, one set a (backend, tile_sub, mesh).
+
+    ``jax.jit`` finds a traced and loaded program again by the function
+    object it wraps, so steps wrapped once a verifier would trace the
+    scan and load its program at every verifier's first call, which is
+    every recheck pass's (``step_load``: 0.2 s of a 0.63 s pass on one
+    chip, 0.3 s of 0.74 s on four; PERF.md §6, PR 29). Filled under one
+    lock, so two threads that ask for one key get one set; the jitted
+    objects themselves are safe to call from any thread. Past the
+    capacity the set that was asked for longest ago goes, with the
+    executables it holds."""
+
+    def __init__(self, capacity: int):
+        self._capacity = capacity
+        self._steps_lock = named_lock("models.verifier._steps_lock")
+        self._cells = guard_attrs("models.verifier.steps", "entries")
+        self._entries: OrderedDict = OrderedDict()  # bounded-by: _capacity
+        self._builds = 0
+        self._reuses = 0
+
+    def get(self, backend: str, tile_sub: int | None, mesh) -> _Steps:
+        key = (backend, tile_sub, mesh)
+        with self._steps_lock:
+            self._cells.write("entries")
+            steps = self._entries.get(key)
+            if steps is not None:
+                self._entries.move_to_end(key)
+                self._reuses += 1
+                return steps
+            steps = self._entries[key] = _build_steps(backend, tile_sub, mesh)
+            self._builds += 1
+            if len(self._entries) > self._capacity:
+                self._entries.popitem(last=False)
+            return steps
+
+    def stats(self) -> dict[str, int]:
+        with self._steps_lock:
+            self._cells.read("entries")
+            return {"step_builds": self._builds, "step_reuses": self._reuses}
+
+
+_step_cache = _StepCache(STEP_CACHE_CAPACITY)
+
+
+def step_cache_stats() -> dict[str, int]:
+    """``step_builds``: step sets this process has wrapped in
+    ``jax.jit``; ``step_reuses``: verifiers built on a set that was
+    there. Rendered by ``/metrics`` (utils/metrics.py)."""
+    return _step_cache.stats()
+
+
 class TPUVerifier:
     def __init__(
         self,
@@ -85,110 +265,20 @@ class TPUVerifier:
         self.batch_size = round_up_to_multiple(max(batch_size, self.mesh.size), self.mesh.size)
         self.padded_len = padded_len_for(piece_length)
         self.backend = backend
-        sha1_fn = make_sha1_fn(backend)
         self.tile_sub = None
         if backend == "pallas":
-            # A pallas_call has no SPMD partitioning rule, so on a >1-device
-            # mesh we shard it explicitly: each device runs the kernel on its
-            # local piece sub-batch (embarrassingly parallel, no collectives).
+            self.tile_sub = _pallas_tile_sub(self.padded_len)
             # Per-device sub-batches must be tile-aligned or every
             # launch pads with wasted sentinel rows.
-            from jax import shard_map
-            from jax.sharding import PartitionSpec as P
-
-            from torrent_tpu.ops.sha1_pallas import TILE_SUB, sha1_pieces_pallas
-
-            # Adaptive tiling: one tile row (tile_sub*128 pieces) is the
-            # kernel's swizzle/launch granularity, and its temporaries are
-            # ~2x the tile slab. Big pieces shrink the sublane count so a
-            # tile stays ~1 GiB regardless of piece size (the sweep's
-            # measured-best regime; at 4096x1 MiB a whole-batch slab OOMs
-            # a 16 GB chip outright).
-            budget = env_int("TORRENT_TPU_TILE_BYTES", DEFAULT_TILE_BYTES)
-            ts = TILE_SUB
-            # step by 8s, not halving: the env default may be any multiple
-            # of 8 (halving 24 would land on 12 and crash _check_tiling)
-            while ts > 8 and ts * 128 * self.padded_len > budget:
-                ts -= 8
-            self.tile_sub = ts
-            tile = ts * 128
-
-            def sha1_fn(data, nblocks, _ts=ts):
-                return sha1_pieces_pallas(data, nblocks, tile_sub=_ts)
-
-            if self.mesh.size > 1:
-                spec = P(tuple(self.mesh.axis_names))
-                sha1_fn = shard_map(
-                    sha1_fn,
-                    mesh=self.mesh,
-                    in_specs=(spec, spec),
-                    out_specs=spec,
-                    check_vma=False,
-                )
-            self.batch_size = round_up_to_multiple(self.batch_size, tile * self.mesh.size)
-        shard = batch_sharding(self.mesh)
-
-        def _digests(data_u8, nblocks):
-            return sha1_fn(data_u8, nblocks)
-
-        def _verify(data_u8, nblocks, expected):
-            words = sha1_fn(data_u8, nblocks)
-            return jnp.all(words == expected, axis=1)
-
-        self._digest_step = jax.jit(
-            _digests, in_shardings=(shard, shard), out_shardings=shard
-        )
-        self._verify_step = jax.jit(
-            _verify, in_shardings=(shard, shard, shard), out_shardings=shard
-        )
-
-        # Fast single-device upload path: row-block 2-D chunks put in
-        # parallel, joined with one axis-0 concat on device. padded_len is
-        # 128-byte aligned (ops/padding.py), so a 2-D put is a straight
-        # memcpy. The earlier flatten→concat→reshape design is
-        # gone for a reason: XLA's AOT lowering of the big 1-D→2-D
-        # reshape materializes a (4,1)-subtiled intermediate padded 32x —
-        # a 16 GiB allocation at 512 KiB pieces. Multi-device meshes take
-        # one batch-sharded 2-D put instead (_put_sharded: the road a
-        # four-chip host's recheck runs, timed as its own h2d stage).
-        # Chunks arrive as host-order u32 (ndarray.view is free and a
-        # u8→u32 bitcast on TPU lowers through a 4x-widened convert
-        # fusion — the pallas kernel consumes u32 directly). The scan
-        # backend still wants u8 rows; the bitcast back is cheap there
-        # (CPU/GPU lower it as a real reinterpret).
-        pallas = backend == "pallas"
-
-        def _join(chunks):
-            data = jnp.concatenate(chunks, axis=0)
-            if not pallas:
-                data = jax.lax.bitcast_convert_type(data, jnp.uint8).reshape(
-                    data.shape[0], -1
-                )
-            return data
-
-        def _verify_flat(chunks, nblocks, expected):
-            words = sha1_fn(_join(chunks), nblocks)
-            return jnp.all(words == expected, axis=1)
-
-        def _digests_flat(chunks, nblocks):
-            return sha1_fn(_join(chunks), nblocks)
-
-        # Donate the uploaded chunks on real accelerators: the launch
-        # consumes them exactly once, so freeing the device input buffer
-        # as the kernel runs lets the NEXT batch's H2D reuse that memory
-        # — the double-buffered ingest contract the scheduler's sha1
-        # plane relies on. XLA-CPU refuses donation (it would only emit
-        # a warning per launch), so it stays off there.
-        _platform_cpu = next(iter(self.mesh.devices.flat)).platform == "cpu"
-        _donate = () if _platform_cpu else (0,)
-        self._verify_step_flat = jax.jit(_verify_flat, donate_argnums=_donate)
-        self._digest_step_flat = jax.jit(_digests_flat, donate_argnums=_donate)
-        # the sharded twin of the donated digest step, for upload_batch
-        # on a >1-device mesh (compiled only if that path runs)
-        self._digest_step_donated = jax.jit(
-            _digests, in_shardings=(shard, shard), out_shardings=shard,
-            donate_argnums=_donate,
-        )
+            self.batch_size = round_up_to_multiple(
+                self.batch_size, self.tile_sub * 128 * self.mesh.size
+            )
+        steps = _step_cache.get(backend, self.tile_sub, self.mesh)
+        self._digest_step = steps.digest_step
+        self._verify_step = steps.verify_step
+        self._verify_step_flat = steps.verify_step_flat
+        self._digest_step_flat = steps.digest_step_flat
+        self._digest_step_donated = steps.digest_step_donated
         # 4 concurrent upload streams: chosen on a retired setup, not
         # measured on this one.
         self._upload_chunks = env_int("TORRENT_TPU_UPLOAD_CHUNKS", 4)
@@ -208,8 +298,8 @@ class TPUVerifier:
         # reusing the buffer while a batch is still in flight would
         # corrupt it. Force a real copy there (still done in the upload
         # worker threads, so it's parallel).
-        self._upload_must_copy = _platform_cpu
-        self._shard = shard
+        self._upload_must_copy = _on_cpu(self.mesh)
+        self._shard = batch_sharding(self.mesh)
         # A mesh spanning >1 process (parallel/distributed.py) cannot be
         # fed global numpy arrays — each process only holds its
         # addressable shard. verify/digest then take this process's
@@ -320,9 +410,11 @@ class TPUVerifier:
         """Upload one batch and dispatch its step: the ledger's stages
         ``h2d`` (the blocking upload, with the padded slab as
         ``moved_bytes``) and ``launch`` (the jitted call, an enqueue;
-        ``first`` marks a pass's first call, which traces the step and
-        loads its program: the ``step_load`` span). Returns the device
-        result and the function that fetches it.
+        ``first`` marks a pass's first call, the ``step_load`` span: in
+        a process's first pass of a shape it traces the step and loads
+        its program, in every later one it finds both in the process's
+        jitted steps). Returns the device result and the function that
+        fetches it.
 
         Where the transfer is counted, by road: one device takes the
         flat road's chunked concurrent puts, a mesh of several local
@@ -452,9 +544,10 @@ class TPUVerifier:
         load; ``verify_pieces_tpu`` opens the entry before it, around
         the verifier's build), in the loader thread ``read`` (storage's
         own) and ``pad``, in this thread the wait ``read_wait``, ``h2d``,
-        ``launch`` (an enqueue; the first one of a pass traces the step
-        and loads its program, the ``step_load`` span) and ``digest``
-        (the blocking fetch). The transfer is counted under ``h2d`` on
+        ``launch`` (an enqueue; the first one of a pass is the
+        ``step_load`` span, which traces the step and loads its program
+        in a process's first pass of a shape and in no later one) and
+        ``digest`` (the blocking fetch). The transfer is counted under ``h2d`` on
         both roads, and both keep ONE batch in flight: batch *i+1* is
         uploaded and dispatched while batch *i*'s result is unfetched,
         then batch *i* is fetched. One device uploads by its own

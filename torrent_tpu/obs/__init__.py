@@ -37,6 +37,8 @@ are leaves of the lock-order graph) and keeps exchanged/dumped bytes
 deterministic: monotonic-only timestamps, sorted keys.
 """
 
+import sys
+
 from torrent_tpu.obs.attrib import attribute, format_report
 from torrent_tpu.obs.fleet import (
     DIGEST_MAX_BYTES,
@@ -129,18 +131,23 @@ def render_obs_metrics() -> str:
     the swarm wire-plane families (``torrent_tpu_swarm_*`` + bounded
     ``torrent_tpu_peer_*``), the seeder plane's ``torrent_tpu_serve_*``
     (only once this process has actually served — tracker-only scrapes
-    stay lean), and the flight-recorder dump counters. Appended by both
-    the bridge's ``/metrics`` and the session ``MetricsServer``."""
+    stay lean), the jitted SHA-1 steps' build and reuse counters (only
+    once this process has imported the verifier, and so JAX), and the
+    flight-recorder dump counters. Appended by both the bridge's
+    ``/metrics`` and the session ``MetricsServer``."""
     from torrent_tpu.serve_plane.telemetry import serve_telemetry
     from torrent_tpu.utils.metrics import (
         render_serve_metrics,
+        render_step_metrics,
         render_swarm_metrics,
     )
 
     serve_obs = serve_telemetry()
+    verifier = sys.modules.get("torrent_tpu.models.verifier")
     return (
         histograms().render()
         + render_pipeline_metrics()
+        + (render_step_metrics(verifier.step_cache_stats()) if verifier else "")
         + render_swarm_metrics(swarm_telemetry().snapshot())
         + (
             render_serve_metrics(serve_obs.snapshot())

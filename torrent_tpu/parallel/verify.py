@@ -126,9 +126,11 @@ def verify_pieces_tpu(
     from torrent_tpu.obs.ledger import pipeline_ledger
     from torrent_tpu.obs.profiler import annotate
 
-    # the launch-free start of a pass, first entry: every pass builds
-    # its verifier anew (verify_storage opens the second, up to its
-    # first upload)
+    # the launch-free start of a pass, first entry: a pass builds its
+    # verifier, which takes the process's jitted steps (models/verifier:
+    # only a process's first pass of a shape traces and loads them), so
+    # the build is about a millisecond (verify_storage opens the second
+    # entry, up to its first upload)
     with pipeline_ledger().track("pass_setup"), annotate("build_verifier"):
         from torrent_tpu.models.verifier import TPUVerifier
 

@@ -178,6 +178,24 @@ def render_sched_metrics(sched) -> str:
     return "\n".join(lines) + "\n"
 
 
+def render_step_metrics(stats: dict) -> str:
+    """Prometheus rendering of the process's jitted SHA-1 steps.
+
+    ``stats`` is ``torrent_tpu.models.verifier.step_cache_stats()``. A
+    long-lived process that rechecks a torrent a call should show
+    builds at the number of (backend, mesh) pairs it uses and reuses
+    rising by one a call; builds rising with the calls means every
+    call traces the scan and loads its program again."""
+    return (
+        "# HELP torrent_tpu_verifier_step_builds_total Sets of jitted SHA-1 steps this process has built\n"
+        "# TYPE torrent_tpu_verifier_step_builds_total counter\n"
+        f"torrent_tpu_verifier_step_builds_total {stats['step_builds']}\n"
+        "# HELP torrent_tpu_verifier_step_reuses_total Verifiers constructed on a set of jitted steps already built\n"
+        "# TYPE torrent_tpu_verifier_step_reuses_total counter\n"
+        f"torrent_tpu_verifier_step_reuses_total {stats['step_reuses']}\n"
+    )
+
+
 def render_tsan_metrics(snapshot: dict) -> str:
     """Prometheus rendering of the concurrency sanitizer's counters.
 

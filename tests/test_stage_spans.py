@@ -490,12 +490,17 @@ class TestStepModuleNames:
         names = {re.search(r"module @(\S+)", lo.as_text()).group(1) for lo in lowered}
         assert names == STEP_MODULE_NAMES
 
-    def test_every_configuration_names_a_step_the_program_has(self):
+    @pytest.mark.parametrize(
+        "path", sorted(glob.glob(os.path.join(ROOT, "benchmark", "configs", "*.json"))), ids=os.path.basename
+    )
+    def test_every_configuration_names_a_step_the_program_has(self, path):
+        """Each configuration is held to the steps of its ``algo``: the
+        SHA-1 verifier's, or the v2 leaf plane's."""
+        from torrent_tpu.models.v2 import LEAF_STEP_MODULE_NAMES
         from torrent_tpu.models.verifier import STEP_MODULE_NAMES
 
-        files = sorted(glob.glob(os.path.join(ROOT, "benchmark", "configs", "*.json")))
-        assert files
-        for path in files:
-            with open(path) as f:
-                modules = json.load(f)["step_modules"]
-            assert modules and set(modules) <= STEP_MODULE_NAMES, (path, modules)
+        with open(path) as f:
+            config = json.load(f)
+        pinned = {"sha1": STEP_MODULE_NAMES, "sha256": LEAF_STEP_MODULE_NAMES}[config["algo"]]
+        modules = config["step_modules"]
+        assert modules and set(modules) <= pinned, (path, modules)

@@ -26,6 +26,12 @@ import numpy as np
 
 from torrent_tpu.ops.sha256_jax import _IV256, _compress256
 
+# The XLA module names of the merkle reduce (the fused chain on an
+# accelerator, one pair level a dispatch on the CPU backend): apart from
+# the leaf steps' (``models.v2.LEAF_STEP_MODULE_NAMES``), so that a
+# reader of the leaf step's device time never counts the fold.
+MERKLE_MODULE_NAMES = frozenset({"jit__merkle_reduce_fused", "jit_sha256_pairs"})
+
 
 @jax.jit
 def sha256_pairs(words: jax.Array) -> jax.Array:

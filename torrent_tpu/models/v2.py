@@ -36,6 +36,9 @@ from torrent_tpu.models.merkle import (
     words32_to_digests,
     zero_chain,
 )
+from torrent_tpu.analysis.sanitizer import guard_attrs, named_lock
+from torrent_tpu.obs.ledger import pipeline_ledger
+from torrent_tpu.obs.profiler import annotate
 from torrent_tpu.ops.padding import alloc_padded, pad_in_place
 from torrent_tpu.ops.sha256_jax import make_sha256_fn
 from torrent_tpu.utils.env import env_int
@@ -65,6 +68,7 @@ def _iter_source(source, chunk_bytes: int):
     (striped parallel reads per chunk — the same engine behind
     ``Storage.read_batch``); plain buffered reads otherwise.
     """
+    ledger = pipeline_ledger()
     if isinstance(source, (bytes, bytearray, memoryview)):
         mv = memoryview(source)
         for off in range(0, len(mv), chunk_bytes):
@@ -84,12 +88,19 @@ def _iter_source(source, chunk_bytes: int):
             segs = [
                 (0, off + s, s, min(step, n - s)) for s in range(0, n, step)
             ]
+            # the engine charges the ledger's ``read`` stage itself
             engine.read_segments([path], segs, buf[:n])
-            yield buf[:n].tobytes()
+            # the chunk's copy is staging time; its bytes are counted
+            # once, where the chunk becomes a padded batch
+            with ledger.track("stage"):
+                chunk = buf[:n].tobytes()
+            yield chunk
         return
     with open(source, "rb") as f:
         while True:
-            chunk = f.read(chunk_bytes)
+            with ledger.track("read") as t:
+                chunk = f.read(chunk_bytes)
+                t.add(len(chunk))
             if not chunk:
                 return
             yield chunk
@@ -124,6 +135,15 @@ def _make_leaf_fn(b: int, backend: str):
     return make_sha256_fn(backend), "pallas" if backend == "pallas" else "scan"
 
 
+# The XLA module names of the leaf steps (``jit_`` + the traced
+# function's name): what ``sha256_pieces_pallas`` and ``sha256_pieces_jax``
+# lower to. The benchmark finds the leaf step's device time by these
+# names (``step_modules`` of a ``sha256`` configuration), as it finds the
+# SHA-1 steps by ``models.verifier.STEP_MODULE_NAMES``; the merkle
+# reduce's modules are apart (``models.merkle.MERKLE_MODULE_NAMES``).
+# tests/test_v2_stage_spans.py holds the steps to this set.
+LEAF_STEP_MODULE_NAMES = frozenset({"jit__sha256_pallas_aligned", "jit_sha256_pieces_jax"})
+
 # Per-launch wall time of the v2 leaf path by kernel. Its per-kernel
 # counts are how a drop from Mosaic to the scan backend (a leaf batch
 # that is not a 1024-row multiple, a non-TPU platform) stays visible.
@@ -133,65 +153,128 @@ LEAF_LAUNCH_HIST = (
 )
 
 
-def _launch_leaves(leaf_fn, padded, nblocks) -> np.ndarray:
+class _LeafCounters:
+    """Leaf launches by kernel, and the rows each staged and had live.
+    Batch rows are pow-2 bucketed, so a launch stages more rows than it
+    hashes (28,673 leaves go out as 32,768 rows, 4 as 16): launched less
+    live is that padding, and the ``scan`` entries are the small files'
+    drop off the Pallas kernel."""
+
+    def __init__(self):
+        self._lock = named_lock("models.v2._leaf_lock")
+        self._cells = guard_attrs("models.v2.leaf_counters", "by_kernel")
+        self._by_kernel: dict[str, list[int]] = {}  # one entry a kernel name: pallas, scan
+
+    def add(self, kernel: str, launched: int, live: int) -> None:
+        with self._lock:
+            self._cells.write("by_kernel")
+            c = self._by_kernel.setdefault(kernel, [0, 0, 0])
+            c[0] += 1
+            c[1] += launched
+            c[2] += live
+
+    def stats(self) -> dict[str, dict[str, int]]:
+        with self._lock:
+            self._cells.read("by_kernel")
+            return {
+                k: {"launches": c[0], "rows_launched": c[1], "rows_live": c[2]}
+                for k, c in self._by_kernel.items()
+            }
+
+
+_leaf_counters = _LeafCounters()
+
+
+def leaf_launch_stats() -> dict[str, dict[str, int]]:
+    """``{kernel: {launches, rows_launched, rows_live}}`` of this
+    process's leaf launches, ``kernel`` being ``pallas`` or ``scan``.
+    Rendered by ``/metrics`` (utils/metrics.py)."""
+    return _leaf_counters.stats()
+
+
+def _launch_leaves(leaf_fn, padded, nblocks, nbytes: int = 0) -> np.ndarray:
     """One counted launch of a :func:`_make_leaf_fn` pair → host
-    ``u32[b, 8]``."""
+    ``u32[b, 8]``. Synchronous, and three ledger stages: ``h2d`` blocks
+    until the batch is on the device, ``launch`` enqueues the leaf
+    function, ``digest`` fetches. ``nbytes`` is the live payload of the
+    batch; the upload moves the whole padded slab."""
+    import jax
     import jax.numpy as jnp
 
     from torrent_tpu.obs.hist import histograms
 
     fn, kernel = leaf_fn
+    ledger = pipeline_ledger()
     t0 = time.monotonic()
-    words = np.asarray(fn(jnp.asarray(padded), jnp.asarray(nblocks)))
+    with ledger.track("h2d", nbytes, moved=padded.nbytes + nblocks.nbytes):
+        on_device = jax.block_until_ready((jnp.asarray(padded), jnp.asarray(nblocks)))
+    with ledger.track("launch", nbytes):
+        out = fn(*on_device)
+    with ledger.track("digest", nbytes):
+        words = np.asarray(out)
     histograms().get(*LEAF_LAUNCH_HIST, kernel=kernel).observe(
         time.monotonic() - t0
     )
+    _leaf_counters.add(kernel, padded.shape[0], int(np.count_nonzero(nblocks)))
     return words
 
 
-def _leaf_words_from_chunks(chunks, total: int, backend: str) -> np.ndarray:
+def _leaf_words_from_chunks(
+    chunks, total: int, backend: str, on_launch=None
+) -> np.ndarray:
     """SHA-256 leaf hashes from an iterator of block-aligned chunks
     → ``u32[n_blocks, 8]``.
 
     Batch rows are pow-2 bucketed (floor 16, cap LEAF_BATCH) so arbitrary
     file sizes share a handful of compiled executables instead of one per
     block count; sentinel rows carry ``nblocks=0`` and never run.
+    ``on_launch(leaves_done)`` is called after every launch.
     """
+    ledger = pipeline_ledger()
     n = max(1, -(-total // BLOCK))
     b = min(LEAF_BATCH, max(16, 1 << (n - 1).bit_length()))
-    leaf_fn = _make_leaf_fn(b, backend)
-    out = np.zeros((n, 8), dtype=np.uint32)
-    padded, view = alloc_padded(b, BLOCK)
+    with ledger.track("pass_setup"):
+        with annotate("make_leaf_fn"):
+            leaf_fn = _make_leaf_fn(b, backend)
+        out = np.zeros((n, 8), dtype=np.uint32)
+        with annotate("alloc_padded"):
+            padded, view = alloc_padded(b, BLOCK)
     start = 0
     for chunk in chunks:
         k = -(-len(chunk) // BLOCK)
-        lengths = np.zeros(b, dtype=np.int64)
-        padded[:] = 0
-        flat = np.frombuffer(chunk, dtype=np.uint8)
-        full, rem = divmod(len(chunk), BLOCK)
-        view[:full] = flat[: full * BLOCK].reshape(full, BLOCK)
-        lengths[:full] = BLOCK
-        if rem:
-            view[full, :rem] = flat[full * BLOCK :]
-            lengths[full] = rem
-        nblocks = pad_in_place(padded, lengths)
-        nblocks[k:] = 0
-        out[start : start + k] = _launch_leaves(leaf_fn, padded, nblocks)[:k]
+        with ledger.track("stage", len(chunk)):
+            lengths = np.zeros(b, dtype=np.int64)
+            padded[:] = 0
+            flat = np.frombuffer(chunk, dtype=np.uint8)
+            full, rem = divmod(len(chunk), BLOCK)
+            view[:full] = flat[: full * BLOCK].reshape(full, BLOCK)
+            lengths[:full] = BLOCK
+            if rem:
+                view[full, :rem] = flat[full * BLOCK :]
+                lengths[full] = rem
+            nblocks = pad_in_place(padded, lengths)
+            nblocks[k:] = 0
+        out[start : start + k] = _launch_leaves(leaf_fn, padded, nblocks, len(chunk))[:k]
         start += k
+        if on_launch is not None:
+            on_launch(start)
     if total == 0:  # empty source: single zero-length leaf
-        lengths = np.zeros(b, dtype=np.int64)
-        padded[:] = 0
-        nblocks = pad_in_place(padded, lengths)
-        nblocks[1:] = 0
+        with ledger.track("stage"):
+            lengths = np.zeros(b, dtype=np.int64)
+            padded[:] = 0
+            nblocks = pad_in_place(padded, lengths)
+            nblocks[1:] = 0
         out[0] = _launch_leaves(leaf_fn, padded, nblocks)[0]
     return out
 
 
-def _leaf_words_device(source, backend: str) -> np.ndarray:
+def _leaf_words_device(source, backend: str, on_launch=None) -> np.ndarray:
     total = source_len(source)
     n = max(1, -(-total // BLOCK))
     b = min(LEAF_BATCH, max(16, 1 << (n - 1).bit_length()))
-    return _leaf_words_from_chunks(_iter_source(source, b * BLOCK), total, backend)
+    return _leaf_words_from_chunks(
+        _iter_source(source, b * BLOCK), total, backend, on_launch
+    )
 
 
 def _leaf_words_cpu_from_chunks(chunks) -> np.ndarray:
@@ -336,14 +419,21 @@ def roots_batched_windowed(
     out: list[tuple[bytes, tuple[bytes, ...]]] = []
     buf: list[tuple[int, np.ndarray]] = []
     acc = 0
+
+    def flush():
+        # the fold is a ledger stage of its own, off the canonical
+        # chain: bytes are the 32-byte leaf hashes it folds
+        with pipeline_ledger().track("merkle", 32 * acc):
+            out.extend(roots_batched(buf, piece_length, device=device))
+
     for entry in entry_iter:
         buf.append(entry)
         acc += entry[1].shape[0]
         if acc >= window:
-            out.extend(roots_batched(buf, piece_length, device=device))
+            flush()
             buf, acc = [], 0
     if buf:
-        out.extend(roots_batched(buf, piece_length, device=device))
+        flush()
     return out
 
 
@@ -580,13 +670,17 @@ def verify_v2(
     read_file,
     meta: MetainfoV2,
     hasher: str = "tpu",
+    progress_cb=None,
 ) -> dict[tuple[str, ...], np.ndarray]:
     """Recheck every file against its pieces_root / piece layer.
 
     ``read_file(path_tuple) -> bytes | path-str | None`` supplies each
     file's source (None = missing; a path source streams in bounded
     chunks). Returns ``{path: bool[n_pieces]}`` — the v2 analogue of the
-    v1 resume-recheck bitfield, per file.
+    v1 resume-recheck bitfield, per file. ``progress_cb(done_pieces,
+    total_pieces)`` is called once a leaf launch on the device road
+    (once a file with ``hasher="cpu"``): the pieces whose leaves are
+    hashed, files without a source counted from the start.
     """
     plen = meta.info.piece_length
     lpp = plen // BLOCK
@@ -612,13 +706,31 @@ def verify_v2(
             continue
         todo.append((f, source))
 
+    total_pieces = sum(f.num_pieces(plen) for f in meta.info.files)
+    done = total_pieces - sum(f.num_pieces(plen) for f, _ in todo)
+
     def leaf_entries():
+        nonlocal done
         for f, source in todo:
+            base = done
+            done += f.num_pieces(plen)
+
+            on_launch = None
+            if progress_cb is not None:
+
+                def on_launch(leaves_done, f=f, base=base):
+                    # a file's last launch ends inside its (short) last piece
+                    at_end = leaves_done * BLOCK >= f.length
+                    progress_cb(done if at_end else base + leaves_done // lpp, total_pieces)
+
             try:
                 if hasher == "cpu":
-                    yield f.length, _leaf_words_cpu(source)
+                    leaves = _leaf_words_cpu(source)
+                    if on_launch is not None:
+                        on_launch(len(leaves))
+                    yield f.length, leaves
                 else:
-                    yield f.length, _leaf_words_device(source, "auto")
+                    yield f.length, _leaf_words_device(source, "auto", on_launch)
             except OSError:
                 # a path source deleted between phases: zero leaf words
                 # can't match any real root, so every piece of this file
@@ -645,15 +757,16 @@ def verify_v2(
         if len(layer) != n_pieces:
             results[f.path] = ok
             continue
-        if hasher == "cpu":
-            height = lpp.bit_length() - 1
-            padded_n = 1 << max(0, (n_pieces - 1).bit_length())
-            layer_root = _root_cpu(
-                digests_to_words32(layer), padded_n,
-                pad_digest=zero_chain(height)[height],
-            )
-        else:
-            layer_root = file_root_from_piece_roots(digests_to_words32(layer), lpp)
+        with pipeline_ledger().track("merkle", 32 * n_pieces):
+            if hasher == "cpu":
+                height = lpp.bit_length() - 1
+                padded_n = 1 << max(0, (n_pieces - 1).bit_length())
+                layer_root = _root_cpu(
+                    digests_to_words32(layer), padded_n,
+                    pad_digest=zero_chain(height)[height],
+                )
+            else:
+                layer_root = file_root_from_piece_roots(digests_to_words32(layer), lpp)
         if layer_root != f.pieces_root:
             results[f.path] = ok
             continue

@@ -58,8 +58,14 @@ Stages off the canonical chain, recorded where they happen:
 ``pass_setup`` (the launch-free start of a ``verify_pieces_tpu`` pass:
 verifier build, then staging and the first load, two entries a pass),
 ``pad`` (host staging in ``verify_storage``'s loader: tail clear,
-``pad_in_place``, expected words) and ``assemble`` (the scheduler's
-``_drr_take``).
+``pad_in_place``, expected words), ``assemble`` (the scheduler's
+``_drr_take``) and ``merkle`` (the BEP 52 fold above the leaves in
+``models/v2.py``: each flush of ``roots_batched_windowed`` and each
+file's piece-layer check in ``verify_v2``; bytes are the 32-byte hashes
+folded). The v2 recheck (``verify_v2``) charges the chain by file:
+``pass_setup`` (leaf function, padded slab), ``read``, ``stage`` (the
+chunk's copy, the slab's memset, the copy into it, ``pad_in_place``),
+then ``h2d``, ``launch``, ``digest`` a leaf launch, synchronously.
 
 Every stage entry is also a host span in the profiler's trace
 (``obs/profiler.open_span``: ``sched_<stage>``, on the device trace's

@@ -551,7 +551,11 @@ def _verify_v2(v2, args) -> int:
             return None
         return fp  # path source: verify_v2 streams it
 
-    res = verify_v2(read_file, v2, hasher=args.hasher)
+    def progress(done, total):
+        print(f"\rverified {done}/{total} pieces", end="", file=sys.stderr, flush=True)
+
+    res = verify_v2(read_file, v2, hasher=args.hasher, progress_cb=progress)
+    print("", file=sys.stderr)
     total = sum(len(ok) for ok in res.values())
     valid = sum(int(ok.sum()) for ok in res.values())
     for path, ok in res.items():

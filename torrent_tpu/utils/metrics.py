@@ -196,6 +196,26 @@ def render_step_metrics(stats: dict) -> str:
     )
 
 
+def render_leaf_metrics(stats: dict) -> str:
+    """Prometheus rendering of the v2 leaf plane's launch counters.
+
+    ``stats`` is ``torrent_tpu.models.v2.leaf_launch_stats()``. Leaf
+    batches are pow-2 bucketed, so ``rows_launched`` less ``rows_live``
+    is the padding a recheck staged and uploaded, and launches under
+    ``kernel="scan"`` on a TPU are the batches too small for the Pallas
+    kernel."""
+    families = (
+        ("launches", "torrent_tpu_v2_leaf_launches_total", "v2 leaf launches by kernel"),
+        ("rows_launched", "torrent_tpu_v2_leaf_rows_launched_total", "Leaf rows staged and uploaded, padding included, by kernel"),
+        ("rows_live", "torrent_tpu_v2_leaf_rows_live_total", "Leaf rows that held a 16 KiB block to hash, by kernel"),
+    )
+    lines = []
+    for key, name, text in families:
+        lines += [f"# HELP {name} {text}", f"# TYPE {name} counter"]
+        lines += [f'{name}{{kernel="{_esc(k)}"}} {st[key]}' for k, st in sorted(stats.items())]
+    return "\n".join(lines) + "\n"
+
+
 def render_tsan_metrics(snapshot: dict) -> str:
     """Prometheus rendering of the concurrency sanitizer's counters.
 

@@ -109,6 +109,80 @@ class TestEngineRaw:
             eng.close()
 
 
+class TestRunsOfSmallSegments:
+    """Segments that follow one another in a file share one preadv (up to
+    256 KiB a run): the same bytes in the same places, the same statuses."""
+
+    ROW = 4096
+
+    def _file(self, tmp_path, nbytes, name="rows.bin", seed=8):
+        blob = np.random.default_rng(seed).integers(0, 256, size=nbytes, dtype=np.uint8)
+        f = tmp_path / name
+        f.write_bytes(blob.tobytes())
+        return str(f), blob
+
+    def _row_quads(self, k, stride, last, file_index=0, off=0):
+        q = np.zeros((k, 4), dtype=np.int64)
+        rows = np.arange(k, dtype=np.int64)
+        q[:, 0] = file_index
+        q[:, 1] = off + rows * self.ROW
+        q[:, 2] = rows * stride
+        q[:, 3] = self.ROW
+        q[-1, 3] = last
+        return q
+
+    @pytest.mark.parametrize("rows,last", [(1, 4096), (2, 1), (64, 4096), (65, 777), (300, 4095)])
+    def test_rows_land_in_a_strided_slab(self, tmp_path, rows, last):
+        path, blob = self._file(tmp_path, (rows - 1) * self.ROW + last)
+        slab = np.full((rows + 1, self.ROW + 128), 0xEE, dtype=np.uint8)
+        eng = NativeIOEngine(4)
+        try:
+            eng.read_into([path], self._row_quads(rows, slab.strides[0], last), slab.ctypes.data, slab.nbytes, keepalive=slab)
+        finally:
+            eng.close()
+        assert (slab[: rows - 1, : self.ROW].reshape(-1) == blob[: (rows - 1) * self.ROW]).all()
+        assert (slab[rows - 1, :last] == blob[(rows - 1) * self.ROW :]).all()
+        # nothing but the rows was written: pad columns, the short row's tail, the row past the last
+        assert (slab[:, self.ROW :] == 0xEE).all() and (slab[rows - 1, last : self.ROW] == 0xEE).all()
+        assert (slab[rows] == 0xEE).all()
+
+    def test_a_run_across_the_end_of_file_gives_each_segment_its_status(self, tmp_path):
+        path, blob = self._file(tmp_path, 5 * self.ROW + 100)
+        slab = np.zeros((8, self.ROW + 128), dtype=np.uint8)
+        statuses = np.full(8, 99, dtype=np.int32)
+        eng = NativeIOEngine(2)
+        try:
+            rc = eng.read_into([path], self._row_quads(8, slab.strides[0], self.ROW), slab.ctypes.data, slab.nbytes,
+                               keepalive=slab, statuses=statuses)
+            assert rc != 0
+            assert statuses[:5].tolist() == [0] * 5 and (statuses[5:] != 0).all()
+            assert (slab[:5, : self.ROW].reshape(-1) == blob[: 5 * self.ROW]).all()
+            with pytest.raises(NativeIOError):
+                eng.read_into([path], self._row_quads(8, slab.strides[0], self.ROW), slab.ctypes.data, slab.nbytes, keepalive=slab)
+        finally:
+            eng.close()
+
+    def test_runs_break_at_gaps_files_and_empty_segments(self, tmp_path):
+        a, blob_a = self._file(tmp_path, 40 * self.ROW, "a.bin", seed=1)
+        b, blob_b = self._file(tmp_path, 40 * self.ROW, "b.bin", seed=2)
+        R = self.ROW
+        segs = [
+            (0, 0, 0, R), (0, R, R, R), (0, 2 * R, 2 * R, 0), (0, 2 * R, 2 * R, R),  # a run with an empty segment
+            (1, 0, 3 * R, R), (1, R, 4 * R, R),  # another file: another run
+            (0, 10 * R, 5 * R, R), (0, 12 * R, 6 * R, R),  # a gap in the file
+            (0, 13 * R, 8 * R, R), (0, 14 * R, 7 * R, R),  # contiguous in the file, out of order in memory
+        ]
+        out = np.zeros(9 * R, dtype=np.uint8)
+        eng = NativeIOEngine(3)
+        try:
+            eng.read_segments([a, b], segs, out)
+        finally:
+            eng.close()
+        for f, foff, ooff, n in segs:
+            blob = blob_a if f == 0 else blob_b
+            assert (out[ooff : ooff + n] == blob[foff : foff + n]).all(), (f, foff)
+
+
 class TestStorageNativePath:
     def test_differential_multifile(self, tmp_path):
         root, m, payload = make_multifile(tmp_path, [40_000, 1_000, 25_000], 16384)

@@ -22,6 +22,7 @@ from __future__ import annotations
 import functools
 import hashlib
 import time
+from typing import NamedTuple
 
 import numpy as np
 
@@ -51,10 +52,11 @@ LEAF_BATCH = env_int("TORRENT_TPU_LEAF_BATCH", 32768)
 # A "source" is either resident bytes or a filesystem path (str) that is
 # streamed in LEAF_BATCH-block chunks — a 60 GiB file never holds more
 # than one chunk (LEAF_BATCH x 16 KiB) in memory.
+_RESIDENT = (bytes, bytearray, memoryview)
 
 
 def source_len(source) -> int:
-    if isinstance(source, (bytes, bytearray, memoryview)):
+    if isinstance(source, _RESIDENT):
         return len(source)
     import os
 
@@ -62,17 +64,23 @@ def source_len(source) -> int:
 
 
 def _iter_source(source, chunk_bytes: int):
-    """Yield ``chunk_bytes``-sized slices of the source (last may be short).
+    """Yield ``chunk_bytes``-sized bytes-like slices of the source (last
+    may be short), for the callers that hash them on the CPU or tee them
+    (``_leaf_words_cpu``, ``_hybrid_hash_file``); the device road reads a
+    path by row into the leaf slab (:class:`_PathChunk`) and comes
+    here only without the native engine.
 
-    Path sources go through the native C++ pread pool when it's built
-    (striped parallel reads per chunk — the same engine behind
-    ``Storage.read_batch``); plain buffered reads otherwise.
+    A resident source is sliced, not copied. Path sources go through the
+    native C++ pread pool when it's built (striped parallel reads per
+    chunk, straight into the buffer whose ``memoryview`` is yielded — the
+    same engine behind ``Storage.read_batch``); plain buffered reads
+    otherwise.
     """
     ledger = pipeline_ledger()
-    if isinstance(source, (bytes, bytearray, memoryview)):
+    if isinstance(source, _RESIDENT):
         mv = memoryview(source)
         for off in range(0, len(mv), chunk_bytes):
-            yield bytes(mv[off : off + chunk_bytes])
+            yield mv[off : off + chunk_bytes]
         return
     from torrent_tpu.native.io_engine import get_engine
 
@@ -80,7 +88,6 @@ def _iter_source(source, chunk_bytes: int):
     total = source_len(source)
     if engine is not None and total > 0:
         path = str(source)
-        buf = np.empty(chunk_bytes, dtype=np.uint8)
         stripes = 4
         for off in range(0, total, chunk_bytes):
             n = min(chunk_bytes, total - off)
@@ -88,13 +95,10 @@ def _iter_source(source, chunk_bytes: int):
             segs = [
                 (0, off + s, s, min(step, n - s)) for s in range(0, n, step)
             ]
+            chunk = np.empty(n, dtype=np.uint8)
             # the engine charges the ledger's ``read`` stage itself
-            engine.read_segments([path], segs, buf[:n])
-            # the chunk's copy is staging time; its bytes are counted
-            # once, where the chunk becomes a padded batch
-            with ledger.track("stage"):
-                chunk = buf[:n].tobytes()
-            yield chunk
+            engine.read_segments([path], segs, chunk)
+            yield chunk.data
         return
     with open(source, "rb") as f:
         while True:
@@ -104,6 +108,36 @@ def _iter_source(source, chunk_bytes: int):
             if not chunk:
                 return
             yield chunk
+
+
+class _PathChunk(NamedTuple):
+    """``nbytes`` of the file at ``path`` from ``off``: a chunk that the
+    native engine reads by row straight into the leaf slab."""
+
+    engine: object
+    path: str
+    off: int
+    nbytes: int
+
+    def read_rows(self, padded: np.ndarray) -> None:
+        """Row ``i`` of the launch is the file's 16 KiB block at ``off +
+        i * BLOCK`` (the last one may be short), so a segment a row lands
+        each where the kernel reads it: no read buffer, no copy. The
+        engine serves rows that follow one another sixteen a ``preadv``,
+        charges the ledger's ``read`` stage itself, and raises
+        ``NativeIOError`` (an ``OSError``) when the file ends early."""
+        full, rem = divmod(self.nbytes, BLOCK)
+        k = full + (1 if rem else 0)
+        rows = np.arange(k, dtype=np.int64)
+        quads = np.zeros((k, 4), dtype=np.int64)
+        quads[:, 1] = self.off + rows * BLOCK
+        quads[:, 2] = rows * padded.strides[0]
+        quads[:, 3] = BLOCK
+        if rem:
+            quads[-1, 3] = rem
+        self.engine.read_into(
+            [self.path], quads, padded.ctypes.data, padded.nbytes, keepalive=padded
+        )
 
 
 def _make_leaf_fn(b: int, backend: str):
@@ -192,6 +226,101 @@ def leaf_launch_stats() -> dict[str, dict[str, int]]:
     return _leaf_counters.stats()
 
 
+class _LeafSlab:
+    """The process's padded leaf slab, and the counters of its use.
+
+    One kept ``uint8[rows, padded_len_for(BLOCK)]`` buffer, grown to the
+    largest row bucket a caller has asked for and never beyond
+    ``LEAF_BATCH`` rows (541 MB), as ``models.verifier._step_cache`` keeps
+    the jitted steps: a slab allocated anew a file is paid in page faults
+    when it is filled and again when it is freed (1.9 s and 0.5 s of a
+    9 s recheck pass on the chip's machine; PERF.md §6, PR 30). A smaller
+    bucket takes the prefix ``slab[:b]``, which is C-contiguous, so every
+    launch shape uses the same pages. Nothing in it is ever zeroed whole:
+    :func:`_pad_rows` makes the live rows of a launch sound, and the rows
+    past them keep whatever they held.
+
+    Checked out for one :func:`_leaf_words_from_chunks` call and back in
+    at its end, under one lock. A caller that finds it out (two threads
+    of a session) gets a transient slab and never waits."""
+
+    def __init__(self):
+        self._lock = named_lock("models.v2._slab_lock")
+        self._cells = guard_attrs("models.v2.leaf_slab", "slab")
+        self._slab: np.ndarray | None = None  # bounded-by: LEAF_BATCH rows
+        self._out = False
+        self._counts = {
+            "leaf_slab_allocs": 0, "leaf_slab_reuses": 0, "leaf_slab_transient": 0,
+            "direct": 0, "copied": 0,
+        }
+
+    def checkout(self, b: int) -> tuple[np.ndarray, bool]:
+        """``(uint8[b, padded_len], kept)``: rows for the caller alone,
+        holding whatever the last launch left. ``kept`` says they are the
+        kept slab's, and the caller then owes a :meth:`checkin`."""
+        with self._lock:
+            self._cells.write("slab")
+            if self._out:
+                self._counts["leaf_slab_transient"] += 1
+                return alloc_padded(b, BLOCK)[0], False
+            if self._slab is not None and self._slab.shape[0] >= b:
+                self._counts["leaf_slab_reuses"] += 1
+            else:
+                # calloc'd: a page is first touched by the read that fills it
+                self._slab = alloc_padded(b, BLOCK)[0]
+                self._counts["leaf_slab_allocs"] += 1
+            self._out = True
+            return self._slab[:b], True
+
+    def checkin(self) -> None:
+        with self._lock:
+            self._cells.write("slab")
+            self._out = False
+
+    def count_launch(self, direct: bool) -> None:
+        with self._lock:
+            self._cells.write("slab")
+            self._counts["direct" if direct else "copied"] += 1
+
+    def stats(self) -> dict[str, int]:
+        with self._lock:
+            self._cells.read("slab")
+            return dict(self._counts)
+
+
+_leaf_slab = _LeafSlab()
+
+
+def leaf_slab_stats() -> dict[str, int]:
+    """``leaf_slab_allocs`` / ``_reuses`` / ``_transient``: check-outs
+    of the process's leaf slab that allocated or grew it, found it large
+    enough, or found it out and took a transient one; ``direct`` /
+    ``copied``: leaf launches whose rows the native engine read into the
+    slab, or that were copied in from a resident chunk. Rendered by
+    ``/metrics`` (utils/metrics.py)."""
+    return _leaf_slab.stats()
+
+
+def _pad_rows(padded: np.ndarray, nbytes: int) -> np.ndarray:
+    """SHA-256 padding for a launch whose first ``nbytes`` of data
+    columns are live, on rows that may hold an earlier launch's bytes →
+    ``int32[b]`` block counts. ``pad_in_place`` needs zeros after each
+    live message: the pad columns of the live rows (128 B a row) and the
+    tail of the one short last row are zeroed, never the slab. Rows past
+    the live ones carry ``nblocks = 0`` and never run. ``nbytes == 0`` is
+    the empty source's single zero-length leaf."""
+    full, rem = divmod(nbytes, BLOCK)
+    k = max(1, full + (1 if rem else 0))
+    lengths = np.full(k, BLOCK, dtype=np.int64)
+    padded[:k, BLOCK:] = 0
+    if full < k:
+        padded[full, rem:BLOCK] = 0
+        lengths[full] = rem
+    nblocks = np.zeros(padded.shape[0], dtype=np.int32)
+    nblocks[:k] = pad_in_place(padded[:k], lengths)
+    return nblocks
+
+
 def _launch_leaves(leaf_fn, padded, nblocks, nbytes: int = 0) -> np.ndarray:
     """One counted launch of a :func:`_make_leaf_fn` pair → host
     ``u32[b, 8]``. Synchronous, and three ledger stages: ``h2d`` blocks
@@ -219,62 +348,92 @@ def _launch_leaves(leaf_fn, padded, nblocks, nbytes: int = 0) -> np.ndarray:
     return words
 
 
+def _leaf_bucket(total: int) -> int:
+    """Rows of a leaf launch for a source of ``total`` bytes: pow-2
+    bucketed (floor 16, cap LEAF_BATCH), so arbitrary file sizes share a
+    handful of compiled executables instead of one per block count."""
+    n = max(1, -(-total // BLOCK))
+    return min(LEAF_BATCH, max(16, 1 << (n - 1).bit_length()))
+
+
 def _leaf_words_from_chunks(
     chunks, total: int, backend: str, on_launch=None
 ) -> np.ndarray:
     """SHA-256 leaf hashes from an iterator of block-aligned chunks
     → ``u32[n_blocks, 8]``.
 
-    Batch rows are pow-2 bucketed (floor 16, cap LEAF_BATCH) so arbitrary
-    file sizes share a handful of compiled executables instead of one per
-    block count; sentinel rows carry ``nblocks=0`` and never run.
-    ``on_launch(leaves_done)`` is called after every launch.
+    A chunk is bytes-like (copied into the slab's rows) or a
+    :class:`_PathChunk` (read into them); either way the rows are those
+    of the process's one kept slab (:class:`_LeafSlab`). Sentinel rows
+    carry ``nblocks=0`` and never run. ``on_launch(leaves_done)`` is
+    called after every launch.
     """
     ledger = pipeline_ledger()
     n = max(1, -(-total // BLOCK))
-    b = min(LEAF_BATCH, max(16, 1 << (n - 1).bit_length()))
+    b = _leaf_bucket(total)
     with ledger.track("pass_setup"):
         with annotate("make_leaf_fn"):
             leaf_fn = _make_leaf_fn(b, backend)
         out = np.zeros((n, 8), dtype=np.uint32)
         with annotate("alloc_padded"):
-            padded, view = alloc_padded(b, BLOCK)
-    start = 0
-    for chunk in chunks:
-        k = -(-len(chunk) // BLOCK)
-        with ledger.track("stage", len(chunk)):
-            lengths = np.zeros(b, dtype=np.int64)
-            padded[:] = 0
-            flat = np.frombuffer(chunk, dtype=np.uint8)
-            full, rem = divmod(len(chunk), BLOCK)
-            view[:full] = flat[: full * BLOCK].reshape(full, BLOCK)
-            lengths[:full] = BLOCK
-            if rem:
-                view[full, :rem] = flat[full * BLOCK :]
-                lengths[full] = rem
-            nblocks = pad_in_place(padded, lengths)
-            nblocks[k:] = 0
-        out[start : start + k] = _launch_leaves(leaf_fn, padded, nblocks, len(chunk))[:k]
-        start += k
-        if on_launch is not None:
-            on_launch(start)
-    if total == 0:  # empty source: single zero-length leaf
-        with ledger.track("stage"):
-            lengths = np.zeros(b, dtype=np.int64)
-            padded[:] = 0
-            nblocks = pad_in_place(padded, lengths)
-            nblocks[1:] = 0
-        out[0] = _launch_leaves(leaf_fn, padded, nblocks)[0]
+            # The road is synchronous: _launch_leaves blocks in ``h2d``
+            # before ``launch`` and fetches the digest before it returns,
+            # so the slab is free to refill when it does. On the CPU
+            # backend jnp.asarray may alias a 64-byte-aligned host buffer
+            # (models/verifier.py, _upload_must_copy): it is this
+            # ordering, not luck, that keeps a reused slab safe there. A
+            # batch in flight would have to copy on the CPU, as _put_flat
+            # does.
+            padded, kept = _leaf_slab.checkout(b)
+    try:
+        view = padded[:, :BLOCK]
+        start = 0
+        for chunk in chunks:
+            direct = isinstance(chunk, _PathChunk)
+            nbytes = chunk.nbytes if direct else len(chunk)
+            k = -(-nbytes // BLOCK)
+            if direct:
+                chunk.read_rows(padded)
+            with ledger.track("stage", nbytes):
+                if not direct:
+                    flat = np.frombuffer(chunk, dtype=np.uint8)
+                    full, rem = divmod(nbytes, BLOCK)
+                    view[:full] = flat[: full * BLOCK].reshape(full, BLOCK)
+                    if rem:
+                        view[full, :rem] = flat[full * BLOCK :]
+                nblocks = _pad_rows(padded, nbytes)
+            _leaf_slab.count_launch(direct)
+            out[start : start + k] = _launch_leaves(leaf_fn, padded, nblocks, nbytes)[:k]
+            start += k
+            if on_launch is not None:
+                on_launch(start)
+        if total == 0:  # empty source: single zero-length leaf
+            with ledger.track("stage"):
+                nblocks = _pad_rows(padded, 0)
+            _leaf_slab.count_launch(False)
+            out[0] = _launch_leaves(leaf_fn, padded, nblocks)[0]
+    finally:
+        if kept:
+            _leaf_slab.checkin()
     return out
 
 
 def _leaf_words_device(source, backend: str, on_launch=None) -> np.ndarray:
     total = source_len(source)
-    n = max(1, -(-total // BLOCK))
-    b = min(LEAF_BATCH, max(16, 1 << (n - 1).bit_length()))
-    return _leaf_words_from_chunks(
-        _iter_source(source, b * BLOCK), total, backend, on_launch
-    )
+    chunk_bytes = _leaf_bucket(total) * BLOCK
+    engine = None
+    if total > 0 and not isinstance(source, _RESIDENT):
+        from torrent_tpu.native.io_engine import get_engine
+
+        engine = get_engine()
+    if engine is not None:
+        chunks = (
+            _PathChunk(engine, str(source), off, min(chunk_bytes, total - off))
+            for off in range(0, total, chunk_bytes)
+        )
+    else:
+        chunks = _iter_source(source, chunk_bytes)
+    return _leaf_words_from_chunks(chunks, total, backend, on_launch)
 
 
 def _leaf_words_cpu_from_chunks(chunks) -> np.ndarray:
@@ -552,9 +711,7 @@ def _hybrid_hash_file(
     total = source_len(source)
     if total == 0:
         return b"\x00" * 32, (), []
-    n = max(1, -(-total // BLOCK))
-    bkt = min(LEAF_BATCH, max(16, 1 << (n - 1).bit_length()))
-    chunk_bytes = bkt * BLOCK
+    chunk_bytes = _leaf_bucket(total) * BLOCK
 
     if hasher == "cpu":
         import hashlib as _hl

@@ -94,7 +94,10 @@ class NativeIOEngine:
         The strided entry point: ``Storage.read_batch`` computes absolute
         byte offsets into a row-strided staging view, so out_offsets here
         are *memory* offsets, not logical array indices. ``keepalive``
-        pins the owning buffer for the duration of the call.
+        pins the owning buffer for the duration of the call. Segments
+        that follow one another in a file are read up to 256 KiB a
+        ``preadv`` (the v2 leaf road's 16 KiB rows); statuses stay one a
+        segment.
 
         ``statuses``: optional caller-owned ``int32[n_segments]`` array.
         When given, per-segment errnos land there and a failed segment

@@ -133,12 +133,13 @@ def render_obs_metrics() -> str:
     (only once this process has actually served — tracker-only scrapes
     stay lean), the jitted SHA-1 steps' build and reuse counters (only
     once this process has imported the verifier, and so JAX), the v2
-    leaf plane's launch and row counters (once ``models/v2`` is in), and the
+    leaf plane's launch, row and slab counters (once ``models/v2`` is in), and the
     flight-recorder dump counters. Appended by both the bridge's
     ``/metrics`` and the session ``MetricsServer``."""
     from torrent_tpu.serve_plane.telemetry import serve_telemetry
     from torrent_tpu.utils.metrics import (
         render_leaf_metrics,
+        render_leaf_slab_metrics,
         render_serve_metrics,
         render_step_metrics,
         render_swarm_metrics,
@@ -152,6 +153,7 @@ def render_obs_metrics() -> str:
         + render_pipeline_metrics()
         + (render_step_metrics(verifier.step_cache_stats()) if verifier else "")
         + (render_leaf_metrics(v2.leaf_launch_stats()) if v2 else "")
+        + (render_leaf_slab_metrics(v2.leaf_slab_stats()) if v2 else "")
         + render_swarm_metrics(swarm_telemetry().snapshot())
         + (
             render_serve_metrics(serve_obs.snapshot())

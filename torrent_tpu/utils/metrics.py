@@ -216,6 +216,31 @@ def render_leaf_metrics(stats: dict) -> str:
     return "\n".join(lines) + "\n"
 
 
+def render_leaf_slab_metrics(stats: dict) -> str:
+    """Prometheus rendering of the v2 leaf slab's counters.
+
+    ``stats`` is ``torrent_tpu.models.v2.leaf_slab_stats()``: check-outs
+    of the process's one kept slab by what they found, and leaf launches
+    by how their rows were staged. A recheck that keeps allocating, or
+    whose path sources go out ``copied``, has lost the kept slab or the
+    native engine."""
+    lines = []
+    for key, text in (
+        ("leaf_slab_allocs", "Check-outs that allocated or grew the process's kept leaf slab"),
+        ("leaf_slab_reuses", "Check-outs that found the kept leaf slab large enough"),
+        ("leaf_slab_transient", "Check-outs that found the kept leaf slab out and took a transient one"),
+    ):
+        name = f"torrent_tpu_v2_{key}_total"
+        lines += [f"# HELP {name} {text}", f"# TYPE {name} counter", f"{name} {stats[key]}"]
+    name = "torrent_tpu_v2_leaf_launches_staged_total"
+    lines += [
+        f"# HELP {name} v2 leaf launches by how their rows reached the slab: read into it (direct) or copied from a resident chunk",
+        f"# TYPE {name} counter",
+    ]
+    lines += [f'{name}{{staged="{how}"}} {stats[how]}' for how in ("copied", "direct")]
+    return "\n".join(lines) + "\n"
+
+
 def render_tsan_metrics(snapshot: dict) -> str:
     """Prometheus rendering of the concurrency sanitizer's counters.
 

@@ -198,6 +198,9 @@ class TestSha1RowLadder:
             (512, 1, (32, 64, 128, 256, 512)),
             (4096, 1, (32, 128, 512, 2048, 4096)),
             (100, 1, (32, 64, 100)),
+            # the 1 MiB and 512 KiB lanes under the default staging budget (below)
+            (127, 1, (32, 64, 127)),
+            (255, 1, (32, 64, 128, 255)),
             (96, 6, (36, 66, 96)),
             (32, 1, (32,)),
             (8, 8, (8,)),
@@ -210,6 +213,17 @@ class TestSha1RowLadder:
         assert got == want
         assert len(got) <= 5 and got[-1] == batch
         assert all(r % granule == 0 for r in got)
+
+    @pytest.mark.parametrize(
+        "piece_length,target",
+        [(32 << 10, 256), (64 << 10, 256), (128 << 10, 256), (256 << 10, 256), (512 << 10, 255), (1 << 20, 127)],
+    )
+    def test_lane_targets_of_the_authoring_rules_piece_lengths(self, piece_length, target):
+        """What the default 128 MiB staging budget leaves a lane at each
+        piece length `choose_piece_length` can give: a staged slab, and a
+        64 MiB fabric unit's chunk, has this many rows."""
+        sched = HashPlaneScheduler(SchedulerConfig(batch_target=256), hasher="tpu")
+        assert sched.chunk_for(piece_length) == target
 
     @pytest.mark.parametrize(
         "n,rows",
@@ -357,6 +371,62 @@ class TestSha1RowLadder:
                 pad = stats["pad_rows_total"] - before["pad_rows_total"]
                 assert launched % 32 == 0 and launched >= 64 + 32 + 32
                 assert launched - pad >= 40 + 39  # live rows of every attempt
+            finally:
+                await sched.close()
+
+        run(go())
+
+
+class TestStagedRowCounters:
+    """The zero-copy road launches its slab whole; its fill has counters
+    of its own, and ``pad_rows_total`` keeps its row-exact meaning."""
+
+    @pytest.mark.parametrize("road", ["staged", "copying"])
+    def test_only_a_staged_launch_moves_the_staged_rows(self, road):
+        async def go():
+            from torrent_tpu.utils.metrics import render_sched_metrics
+
+            sched = HashPlaneScheduler(
+                SchedulerConfig(batch_target=256, flush_deadline=0.01), hasher="tpu"
+            )
+            await sched.start()
+            try:
+                warm = _pieces(2, 64)
+                assert await sched.submit("t", warm, piece_length=64) == _sha1s(warm)
+                before = sched.metrics_snapshot()["lane_stats"]["sha1/64"]
+                pieces = _pieces(5, 64, salt=3)
+                if road == "staged":
+                    slab = sched.checkout_staging(64, 5)
+                    assert slab.rows_total == 256
+                    slab.prepare([64] * 5)
+                    for i, p in enumerate(pieces):
+                        slab.view[i, :64] = np.frombuffer(p, dtype=np.uint8)
+                    slab.finalize([True] * 5)
+                    try:
+                        fut = await sched.enqueue_staged("t", slab, list(range(5)))
+                    finally:
+                        slab.release()
+                    assert await fut == _sha1s(pieces)
+                else:
+                    assert await sched.submit("t", pieces, piece_length=64) == _sha1s(pieces)
+                snap = sched.metrics_snapshot()
+                after = snap["lane_stats"]["sha1/64"]
+                moved = {k: after[k] - before[k] for k in
+                         ("staged_launches", "staged_rows_total", "staged_live_rows_total", "pad_rows_total")}
+                if road == "staged":
+                    # 256 rows uploaded for 5 live; charged row-exact as before
+                    assert moved == {"staged_launches": 1, "staged_rows_total": 256,
+                                     "staged_live_rows_total": 5, "pad_rows_total": 0}
+                    assert snap["staging"]["outstanding"] == 0
+                else:  # the 32-row rung of the ladder: pad rows, and no staged row
+                    assert moved == {"staged_launches": 0, "staged_rows_total": 0,
+                                     "staged_live_rows_total": 0, "pad_rows_total": 32 - 5}
+                text = render_sched_metrics(sched)
+                for name, key in (("staged_launches_total", "staged_launches"),
+                                  ("staged_rows_total", "staged_rows_total"),
+                                  ("staged_live_rows_total", "staged_live_rows_total")):
+                    assert f"# TYPE torrent_tpu_sched_{name} counter" in text
+                    assert f'torrent_tpu_sched_{name}{{lane="sha1/64"}} {after[key]}' in text
             finally:
                 await sched.close()
 

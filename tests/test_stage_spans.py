@@ -394,6 +394,47 @@ class TestSchedulerRoad:
         assert 0.03 < mean < 0.2  # four of five waited 40 ms of the deadline, one all 50
 
 
+class TestFabricRoad:
+    def test_a_solo_sweep_names_its_drain_and_its_ends(self, recorder, one_device, tmp_path):
+        """``verify_library_fabric`` with no transport: the executor's
+        park on its oldest launch is the wait ``unit_drain``, the plan
+        and the bitfields' assembly the stage ``pass_setup``, each a host
+        span too; the reads land in a staging slab and launch in place."""
+        from torrent_tpu.parallel.bulk import verify_library_fabric
+        from torrent_tpu.sched import HashPlaneScheduler, SchedulerConfig
+
+        storage, info = _torrent(tmp_path, 11)
+        out: dict = {}
+
+        async def go():
+            sched = await HashPlaneScheduler(
+                SchedulerConfig(batch_target=BATCH, flush_deadline=0.01), hasher="tpu"
+            ).start()
+            try:
+                out["res"] = await verify_library_fabric(
+                    [(storage, info)], sched, nproc=1, pid=0, unit_bytes=BATCH * PLEN
+                )
+                out["snap"] = sched.metrics_snapshot()
+            finally:
+                await sched.close()
+
+        asyncio.run(go())
+        assert out["res"].bitfields[0].all() and out["res"].n_pieces == 11
+        _no_metadata(recorder.names())
+        snap = pipeline_ledger().snapshot()
+        # two units (8 and 3 pieces), one chunk each: one drain a chunk
+        assert snap["waits"]["unit_drain"]["ops"] == len(recorder.of("unit_drain")) == 2
+        assert snap["waits"]["unit_drain"]["busy_s"] > 0 and "unit_drain" not in snap["stages"]
+        assert snap["stages"]["pass_setup"]["ops"] == len(recorder.of("pass_setup")) == 2
+        assert snap["stages"].get("stage", {"bytes": 0})["bytes"] == 0  # no copy into a slot
+        # a full slab, then a ragged one launched whole: 16 rows for 11 live
+        lane = out["snap"]["lane_stats"][f"sha1/{PLEN}"]
+        assert (lane["staged_launches"], lane["staged_rows_total"], lane["staged_live_rows_total"]) == (2, 16, 11)
+        assert lane["pad_rows_total"] == 0 and out["snap"]["staging"]["outstanding"] == 0
+        # the drain is a wait: the attributor never names it
+        assert "unit_drain" not in attribute(snap)["stages"]
+
+
 class TestLedgerContract:
     def test_waits_stay_out_of_stages_overlap_and_wall(self):
         led = PipelineLedger()
@@ -421,7 +462,7 @@ class TestLedgerContract:
         for stage in new + list(PIPELINE_STAGES):
             with led.track(stage, 64, moved=128):
                 pass
-        for wait in sorted(SCHED_WAITS | {"read_wait"}):
+        for wait in sorted(SCHED_WAITS | {"read_wait", "unit_drain"}):
             with led.track(wait, wait=True):
                 pass
         snap = led.snapshot()
@@ -431,7 +472,7 @@ class TestLedgerContract:
         prom_lint(text)
         for stage in new:
             assert f'torrent_tpu_pipeline_stage_busy_seconds_total{{stage="{stage}"}}' in text
-        assert "deadline_wait" not in text and "read_wait" not in text
+        assert "deadline_wait" not in text and "read_wait" not in text and "unit_drain" not in text
 
     def test_track_without_jax_imports_nothing(self):
         """``import torrent_tpu`` itself pulls JAX in today (``parallel/

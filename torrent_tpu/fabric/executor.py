@@ -706,7 +706,10 @@ class FabricExecutor:
             nonlocal n_ok
             fut, keep, nb = futs.popleft()
             try:
-                ok = await fut
+                # the executor parked on its oldest launch: no chunk of
+                # this unit (or of the next) is read meanwhile
+                with pipeline_ledger().track("unit_drain", wait=True):
+                    ok = await fut
             except SchedLaunchError as e:
                 log.warning(
                     "fabric unit %d: %d pieces unverified (launch failed: %s)",

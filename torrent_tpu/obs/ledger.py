@@ -56,7 +56,10 @@ Stage boundaries (instrumentation sites):
 
 Stages off the canonical chain, recorded where they happen:
 ``pass_setup`` (the launch-free start of a ``verify_pieces_tpu`` pass:
-verifier build, then staging and the first load, two entries a pass),
+verifier build, then staging and the first load, two entries a pass;
+the launch-free ends of a ``verify_library_fabric`` sweep: the shard
+plan and executor before the first read, the bitfields' assembly after
+the last verdict),
 ``pad`` (host staging in ``verify_storage``'s loader: tail clear,
 ``pad_in_place``, expected words), ``assemble`` (the scheduler's
 ``_drr_take``) and ``merkle`` (the BEP 52 fold above the leaves in
@@ -71,7 +74,9 @@ Every stage entry is also a host span in the profiler's trace
 (``obs/profiler.open_span``: ``sched_<stage>``, on the device trace's
 clock), so there is no second call at any site. ``track(wait=True)``
 marks time work spent *waiting for* a layer — ``read_wait``,
-``lane_idle``, ``deadline_wait``, ``sem_wait`` — and lands in a table
+``lane_idle``, ``deadline_wait``, ``sem_wait``, ``unit_drain`` (the
+fabric executor parked on its oldest launch's future: it reads nothing
+meanwhile) — and lands in a table
 of its own, ``snapshot()["waits"]``: everything that iterates
 ``stages`` (the attributor, ``doctor --bottleneck``, ``top``, the
 Prometheus stage series, the autopilot) never sees a parked lane as a

@@ -275,24 +275,31 @@ async def verify_library_fabric(
     pieces against the library-wide total.
     """
     from torrent_tpu.fabric import DEFAULT_UNIT_BYTES, build_fabric_executor
+    from torrent_tpu.obs.ledger import pipeline_ledger
 
     t0 = time.perf_counter()
-    ex = build_fabric_executor(
-        items,
-        scheduler,
-        nproc=nproc,
-        pid=pid,
-        heartbeat_dir=heartbeat_dir,
-        transport=transport,
-        config=fabric_config,
-        unit_bytes=unit_bytes or DEFAULT_UNIT_BYTES,
-        progress_cb=progress_cb,
-    )
+    # the launch-free ends of a sweep, as the recheck's pass_setup: the
+    # shard plan and executor before the first read, the bitfields'
+    # assembly after the last verdict
+    with pipeline_ledger().track("pass_setup"):
+        ex = build_fabric_executor(
+            items,
+            scheduler,
+            nproc=nproc,
+            pid=pid,
+            heartbeat_dir=heartbeat_dir,
+            transport=transport,
+            config=fabric_config,
+            unit_bytes=unit_bytes or DEFAULT_UNIT_BYTES,
+            progress_cb=progress_cb,
+        )
     if executor_out is not None:
         executor_out.append(ex)
     await ex.run()
+    with pipeline_ledger().track("pass_setup"):
+        bitfields = ex.bitfields()
     total_pieces = sum(info.num_pieces for _, info in items)
     total_bytes = sum(info.length for _, info in items)
     return LibraryResult(
-        ex.bitfields(), total_pieces, total_bytes, time.perf_counter() - t0
+        bitfields, total_pieces, total_bytes, time.perf_counter() - t0
     )

@@ -347,6 +347,13 @@ class _Ticket:
         self.ts = ts
 
 
+# per-lane row counters bumped by worker threads under _counter_lock
+_LANE_ROW_COUNTERS = (
+    "pad_rows_total", "launched_rows_total",
+    "staged_launches", "staged_rows_total", "staged_live_rows_total",
+)
+
+
 class _Lane:
     """Assembler state for one (algo, piece-length bucket) geometry."""
 
@@ -355,6 +362,7 @@ class _Lane:
         "event", "task", "plane", "build_lock", "sem", "inflight",
         "breaker", "cpu_plane", "backend", "deadline",
         "launches", "fill_sum", "pad_rows_total", "launched_rows_total",
+        "staged_launches", "staged_rows_total", "staged_live_rows_total",
     )
 
     def __init__(
@@ -392,6 +400,14 @@ class _Lane:
         self.fill_sum = 0.0
         self.pad_rows_total = 0
         self.launched_rows_total = 0
+        # the zero-copy road's own fill: launch attempts that took
+        # run_staged, the rows of the slabs they handed over (what a
+        # device plane uploads: the whole slab, whatever is live) and
+        # the ticket rows among them. pad_rows_total stays row-exact
+        # for this road (a staged slab's rows are its reader's)
+        self.staged_launches = 0
+        self.staged_rows_total = 0
+        self.staged_live_rows_total = 0
 
     def oldest_ts(self) -> float:
         return min(q[0].ts for q in self.queues.values() if q)
@@ -2073,6 +2089,10 @@ class HashPlaneScheduler:
             self._counter_cells.write("fault_counters")
             lane.launched_rows_total += launched
             lane.pad_rows_total += launched - n
+            if run_staged is not None:
+                lane.staged_launches += 1
+                lane.staged_rows_total += staged[0].rows_total
+                lane.staged_live_rows_total += n
         if run_staged is not None:
             obs_note["staged"] = True
         try:
@@ -2350,12 +2370,10 @@ class HashPlaneScheduler:
         with self._counter_lock:
             self._counter_cells.read("fault_counters")
             cpu_fallback_launches = self._cpu_fallback_launches
-            # pad share over a window = Δpad ÷ Δlaunched rows
+            # pad share over a window = Δpad ÷ Δlaunched rows; the staged
+            # road's fill = Δstaged live ÷ Δstaged rows
             lane_rows = {
-                key: {
-                    "pad_rows_total": lane.pad_rows_total,
-                    "launched_rows_total": lane.launched_rows_total,
-                }
+                key: {name: getattr(lane, name) for name in _LANE_ROW_COUNTERS}
                 for key, lane in self._lanes.items()
             }
         # enqueue-to-take seconds and pieces of every launch: the sum
@@ -2408,10 +2426,8 @@ class HashPlaneScheduler:
                     "mean_fill": (
                         lane.fill_sum / lane.launches if lane.launches else 0.0
                     ),
-                    **lane_rows.get(
-                        (algo, bucket),
-                        {"pad_rows_total": 0, "launched_rows_total": 0},
-                    ),
+                    # a lane born since the locked copy above reads 0
+                    **lane_rows.get((algo, bucket), dict.fromkeys(_LANE_ROW_COUNTERS, 0)),
                 }
                 for (algo, bucket), lane in self._lanes.items()
             },

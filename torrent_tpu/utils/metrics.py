@@ -128,6 +128,23 @@ def render_sched_metrics(sched) -> str:
             f'torrent_tpu_sched_launch_rows_total{{lane="{_esc(lane)}"}} '
             f"{st.get('launched_rows_total', 0)}"
         )
+    # the zero-copy road: a staged launch uploads its whole slab, so its
+    # fill over a window = live rows / staged rows (pad_rows_total stays
+    # row-exact there)
+    for name, key, text in (
+        ("staged_launches_total", "staged_launches",
+         "Launch attempts that took a pre-staged slab in place (run_staged)"),
+        ("staged_rows_total", "staged_rows_total",
+         "Rows of the slabs handed to staged launches, live or not"),
+        ("staged_live_rows_total", "staged_live_rows_total",
+         "Ticket rows among the rows of staged launches"),
+    ):
+        lines.append(f"# HELP torrent_tpu_sched_{name} {text}")
+        lines.append(f"# TYPE torrent_tpu_sched_{name} counter")
+        for lane, st in sorted(lane_stats.items()):
+            lines.append(
+                f'torrent_tpu_sched_{name}{{lane="{_esc(lane)}"}} {st.get(key, 0)}'
+            )
     lines.append(
         "# HELP torrent_tpu_sched_lane_target Pieces per launch this lane aims to fill"
     )

@@ -48,32 +48,33 @@ def run(coro, timeout=120):
     return asyncio.run(asyncio.wait_for(coro, timeout))
 
 
-def make_library(tmp_path, sizes_pieces, seed=7, corrupt=None):
+def make_library(tmp_path, sizes_pieces, seed=7, corrupt=None, plens=None):
     """Build an on-disk library: one single-file torrent per entry of
-    ``sizes_pieces`` (ragged last piece), optionally corrupting
-    ``corrupt=(torrent, piece)`` on disk. Returns (items, torrent_dir,
+    ``sizes_pieces`` (ragged last piece; piece length ``PLEN``, or
+    ``plens[t]``), optionally corrupting ``corrupt=(torrent, piece)``,
+    or a list of such pairs, on disk. Returns (items, torrent_dir,
     data_dir)."""
     rng = np.random.default_rng(seed)
     tdir = tmp_path / "torrents"
     ddir = tmp_path / "data"
     tdir.mkdir()
+    plens = plens or [PLEN] * len(sizes_pieces)
     items = []
     for t, npieces in enumerate(sizes_pieces):
         root = ddir / f"lib{t}"
         root.mkdir(parents=True)
-        size = (npieces - 1) * PLEN + PLEN // 2
+        size = (npieces - 1) * plens[t] + plens[t] // 2
         payload = root / "payload.bin"
         payload.write_bytes(rng.integers(0, 256, size, dtype=np.uint8).tobytes())
         tf = tdir / f"lib{t}.torrent"
         tf.write_bytes(
-            make_torrent(str(payload), "http://t.invalid/announce", piece_length=PLEN)
+            make_torrent(str(payload), "http://t.invalid/announce", piece_length=plens[t])
         )
         items.append(tf)
-    if corrupt is not None:
-        ct, cp = corrupt
+    for ct, cp in [corrupt] if isinstance(corrupt, tuple) else corrupt or []:
         f = ddir / f"lib{ct}" / "payload.bin"
         buf = bytearray(f.read_bytes())
-        buf[cp * PLEN + 11] ^= 0xFF
+        buf[cp * plens[ct] + 11] ^= 0xFF
         f.write_bytes(bytes(buf))
     out = []
     for t, tf in enumerate(items):
@@ -191,6 +192,75 @@ class TestSoloExecutor:
             assert (a == b).all()
         assert not res.bitfields[1][5]  # the corrupted piece
         assert int(sum(b.sum() for b in res.bitfields)) == res.n_pieces - 1
+
+
+class TestLastChunkFlushHint:
+    """After a unit's last chunk the executor only drains, so it
+    enqueues that chunk with ``flush=True`` and the lane launches at
+    once: a sweep whose every ragged chunk would have sat out a 2 s
+    flush deadline ends inside one."""
+
+    DEADLINE = 2.0
+
+    @pytest.mark.parametrize("transport", ["solo", "file_heartbeat"])
+    def test_no_ragged_chunk_sits_out_the_deadline(self, tmp_path, transport):
+        from torrent_tpu.parallel.bulk import verify_library_fabric
+
+        plens = [16384, 32768, 65536]  # three lanes
+        planted = [(0, 3), (0, 19), (1, 16), (2, 7)]
+        items, _, _ = make_library(
+            tmp_path, [20, 20, 11], corrupt=planted, plens=plens
+        )
+        want = []  # the CPU reference: hashlib over the bytes on disk
+        for storage, info in items:
+            data = open(os.path.join(storage.method.root, "payload.bin"), "rb").read()
+            pl = info.piece_length
+            want.append(np.array([
+                hashlib.sha1(data[i * pl : (i + 1) * pl]).digest() == info.pieces[i]
+                for i in range(info.num_pieces)
+            ]))
+        assert sum(int((~w).sum()) for w in want) == len(planted)
+        executors: list = []
+
+        async def go():
+            sched = await HashPlaneScheduler(
+                SchedulerConfig(batch_target=16, flush_deadline=self.DEADLINE),
+                hasher="cpu",
+            ).start()
+            try:
+                t0 = time.monotonic()
+                res = await verify_library_fabric(
+                    items, sched, nproc=1, pid=0, unit_bytes=8 * 65536,
+                    heartbeat_dir=(
+                        str(tmp_path / "hb") if transport == "file_heartbeat" else None
+                    ),
+                    fabric_config=FabricConfig(heartbeat_interval=0.05),
+                    executor_out=executors,
+                )
+                return res, time.monotonic() - t0, sched.metrics_snapshot()
+            finally:
+                await sched.close()
+
+        res, elapsed, snap = run(go())
+        (ex,) = executors
+        assert (ex.transport is not None) == (transport == "file_heartbeat")
+        for got, ref in zip(res.bitfields, want):
+            assert (got == ref).all()
+        # a unit's last chunk is ragged where it is under its lane's
+        # target (16 rows in every lane of a CPU-hasher scheduler)
+        chunks = [
+            min(16, u.stop - start)
+            for u in ex.plan.units
+            for start in range(u.start, u.stop, 16)
+        ]
+        ragged = sum(1 for n in chunks if n < 16)
+        assert (len(chunks), ragged) == (6, 4)
+        assert snap["launches"] == len(chunks) and snap["lanes"] == 3
+        assert snap["flush_reasons"] == {
+            "full": len(chunks) - ragged, "deadline": 0, "hint": ragged, "shutdown": 0,
+        }
+        assert snap["staging"]["outstanding"] == 0
+        assert elapsed < self.DEADLINE, elapsed
 
 
 class TestInflightBudget:

@@ -154,6 +154,20 @@ def _sha1s(payloads) -> list[bytes]:
     return [hashlib.sha1(p).digest() for p in payloads]
 
 
+async def _enqueue_staged(sched, tenant, pieces, piece_length, **kw):
+    """The zero-copy road by hand: a slab checked out, filled with
+    ``pieces`` and handed over; the caller's reference is released."""
+    slab = sched.checkout_staging(piece_length, len(pieces))
+    slab.prepare([len(p) for p in pieces])
+    for i, p in enumerate(pieces):
+        slab.view[i, : len(p)] = np.frombuffer(p, dtype=np.uint8)
+    slab.finalize([True] * len(pieces))
+    try:
+        return await sched.enqueue_staged(tenant, slab, list(range(len(pieces))), **kw)
+    finally:
+        slab.release()
+
+
 @pytest.fixture(scope="module", params=["mesh", "one_device"])
 def ladder_plane(request):
     """A built 256-row SHA-1 device plane at 192-byte pieces: over the
@@ -396,16 +410,7 @@ class TestStagedRowCounters:
                 before = sched.metrics_snapshot()["lane_stats"]["sha1/64"]
                 pieces = _pieces(5, 64, salt=3)
                 if road == "staged":
-                    slab = sched.checkout_staging(64, 5)
-                    assert slab.rows_total == 256
-                    slab.prepare([64] * 5)
-                    for i, p in enumerate(pieces):
-                        slab.view[i, :64] = np.frombuffer(p, dtype=np.uint8)
-                    slab.finalize([True] * 5)
-                    try:
-                        fut = await sched.enqueue_staged("t", slab, list(range(5)))
-                    finally:
-                        slab.release()
+                    fut = await _enqueue_staged(sched, "t", pieces, 64)
                     assert await fut == _sha1s(pieces)
                 else:
                     assert await sched.submit("t", pieces, piece_length=64) == _sha1s(pieces)
@@ -583,6 +588,104 @@ class TestAssembler:
                 assert sched.metrics_snapshot()["lanes"] == 2
             finally:
                 await sched.close()
+
+        run(go())
+
+
+class TestFlushHint:
+    """``flush=True``: the submitter sends nothing more until this
+    submission resolves, so its lane takes at once. The deadline here is
+    5 s and every await is cut at 2 s: a lane that sat the hint out
+    fails by time-out, not by luck."""
+
+    TARGET, PLEN, DEADLINE, SOON = 8, 64, 5.0, 2.0
+
+    async def _enqueue(self, sched, road, tenant, pieces, **kw):
+        if road == "bytes":
+            return await sched.enqueue(tenant, pieces, piece_length=self.PLEN, **kw)
+        return await _enqueue_staged(sched, tenant, pieces, self.PLEN, **kw)
+
+    @pytest.mark.parametrize("road", ["bytes", "staged"])
+    @pytest.mark.parametrize(
+        "case", ["under_target", "rides_along", "fills_target", "not_sticky", "shed", "shutdown"]
+    )
+    def test_a_hinted_submission_is_launched_at_once(self, case, road):
+        tight = case == "shed"  # a queue that holds four pieces
+
+        async def go():
+            sched = HashPlaneScheduler(
+                SchedulerConfig(
+                    batch_target=self.TARGET, flush_deadline=self.DEADLINE,
+                    max_queue_bytes=4 * self.PLEN if tight else 256 << 20,
+                ),
+                hasher="cpu",
+            )
+            reasons = lambda: sched.metrics_snapshot()["flush_reasons"]  # noqa: E731
+            lane = lambda: sched._lanes[("sha1", self.PLEN)]  # noqa: E731
+            idle = {"full": 0, "deadline": 0, "hint": 0, "shutdown": 0}
+            hinted = _pieces(3, self.PLEN, salt=1)
+            plain = _pieces(2, self.PLEN, salt=9)
+            t0 = time.monotonic()
+            closed = False
+
+            async def stays_queued(seen):
+                """Unhinted traffic after it waits for fill or its deadline."""
+                later = await self._enqueue(sched, road, "other", plain)
+                _, waiting = await asyncio.wait({later}, timeout=0.3)
+                assert waiting == {later} and reasons() == seen
+                assert lane().flush_pending == 0 and lane().pending_pieces == 2
+                return later
+
+            try:
+                if case == "under_target":
+                    fut = await self._enqueue(sched, road, "fabric", hinted, flush=True)
+                    assert await asyncio.wait_for(fut, self.SOON) == _sha1s(hinted)
+                    assert reasons() == {**idle, "hint": 1}
+                    from torrent_tpu.utils.metrics import render_sched_metrics
+
+                    assert 'torrent_tpu_sched_flush_total{reason="hint"} 1' in render_sched_metrics(sched)
+                elif case == "rides_along":
+                    # another tenant's unhinted tickets, queued in the lane
+                    # before: they ride the launch the hint sends
+                    other = await self._enqueue(sched, road, "other", plain)
+                    fut = await self._enqueue(sched, road, "fabric", hinted, flush=True)
+                    assert await asyncio.wait_for(fut, self.SOON) == _sha1s(hinted)
+                    assert await asyncio.wait_for(other, self.SOON) == _sha1s(plain)
+                    assert reasons() == {**idle, "hint": 1}
+                    assert sched.metrics_snapshot()["launches"] == 1
+                elif case == "fills_target":
+                    full = _pieces(self.TARGET, self.PLEN, salt=4)
+                    fut = await self._enqueue(sched, road, "fabric", full, flush=True)
+                    assert await asyncio.wait_for(fut, self.SOON) == _sha1s(full)
+                    assert reasons() == {**idle, "full": 1}
+                elif case == "not_sticky":
+                    fut = await self._enqueue(sched, road, "fabric", hinted, flush=True)
+                    assert await asyncio.wait_for(fut, self.SOON) == _sha1s(hinted)
+                    later = await stays_queued({**idle, "hint": 1})
+                    await sched.close()
+                    closed = True
+                    assert await asyncio.wait_for(later, self.SOON) == _sha1s(plain)
+                    assert reasons() == {**idle, "hint": 1, "shutdown": 1}
+                elif case == "shed":
+                    with pytest.raises(SchedRejected):
+                        await self._enqueue(
+                            sched, road, "fabric", _pieces(6, self.PLEN), flush=True
+                        )
+                    # nothing of the shed submission is queued or remembered
+                    await stays_queued(idle)
+                else:  # shutdown: closing before the lane's loop has run
+                    fut = await self._enqueue(sched, road, "fabric", hinted, flush=True)
+                    assert lane().flush_pending == 3
+                    await sched.close()
+                    closed = True
+                    assert await asyncio.wait_for(fut, self.SOON) == _sha1s(hinted)
+                    assert reasons() == {**idle, "shutdown": 1}
+            finally:
+                if not closed:
+                    await sched.close()
+            assert lane().flush_pending == 0 and lane().pending_pieces == 0
+            assert sched.metrics_snapshot()["staging"]["outstanding"] == 0
+            assert time.monotonic() - t0 < self.DEADLINE  # no take sat a deadline out
 
         run(go())
 

@@ -431,6 +431,11 @@ class TestFabricRoad:
         lane = out["snap"]["lane_stats"][f"sha1/{PLEN}"]
         assert (lane["staged_launches"], lane["staged_rows_total"], lane["staged_live_rows_total"]) == (2, 16, 11)
         assert lane["pad_rows_total"] == 0 and out["snap"]["staging"]["outstanding"] == 0
+        # the ragged chunk is its unit's last and says so: no deadline is
+        # sat out, and the wait nobody entered stands in the ledger at zero
+        assert out["snap"]["flush_reasons"] == {"full": 1, "deadline": 0, "hint": 1, "shutdown": 0}
+        assert snap["waits"]["deadline_wait"]["ops"] == 0 and snap["waits"]["deadline_wait"]["busy_s"] == 0.0
+        assert not recorder.of("deadline_wait")
         # the drain is a wait: the attributor never names it
         assert "unit_drain" not in attribute(snap)["stages"]
 
@@ -453,6 +458,17 @@ class TestLedgerContract:
         assert attribute(snap)["bottleneck"]["stage"] == "read"
         led.clear()
         assert led.snapshot()["waits"] == {}
+
+    def test_a_declared_wait_reads_zero_until_it_is_entered(self):
+        led = PipelineLedger()
+        led.declare_wait("deadline_wait")
+        zero = led.snapshot()
+        assert zero["waits"] == {"deadline_wait": {k: 0 for k in zero["waits"]["deadline_wait"]}}
+        assert zero["stages"] == {} and zero["t_first"] is None
+        with led.track("deadline_wait", wait=True):
+            pass
+        led.declare_wait("deadline_wait")  # again: what was counted stays
+        assert led.snapshot()["waits"]["deadline_wait"]["ops"] == 1
 
     def test_new_stages_render_lintable_and_fold_nothing(self):
         led = PipelineLedger()

@@ -82,7 +82,8 @@ SINK_CALLS: dict[str, tuple[str, tuple[int, ...] | None]] = {
     "read_into": ("read offset/length", None),
     "readexactly": ("read length", (0,)),
     "checkout_staging": ("staging slab geometry", (0, 1)),
-    "enqueue_staged": ("staged submit geometry", None),
+    # slab, rows: its tenant, expected, wait and flush are no geometry
+    "enqueue_staged": ("staged submit geometry", (1, 2)),
     "seek": ("file offset", (0,)),
     "joinpath": ("file-path construction", None),
     "truncate": ("file size", (0,)),

@@ -707,7 +707,8 @@ class FabricExecutor:
             fut, keep, nb = futs.popleft()
             try:
                 # the executor parked on its oldest launch: no chunk of
-                # this unit (or of the next) is read meanwhile
+                # this unit (or of the next) is read meanwhile, which is
+                # why the unit's last chunk is enqueued with flush=True
                 with pipeline_ledger().track("unit_drain", wait=True):
                     ok = await fut
             except SchedLaunchError as e:
@@ -748,9 +749,14 @@ class FabricExecutor:
             await self._acquire_bytes(nb)
             try:
                 # wait=True: backpressure pauses the read loop; the
-                # chunk releases its slab hold on every path itself
+                # chunk releases its slab hold on every path itself.
+                # After a unit's last chunk this coroutine only drains,
+                # so nothing it does could fill that chunk's lane: it
+                # says so, and the lane launches without sitting out
+                # its flush deadline
                 fut = await ck.enqueue(
-                    self.scheduler, self.config.tenant, wait=True
+                    self.scheduler, self.config.tenant, wait=True,
+                    flush=start + chunk >= unit.stop,
                 )
             except BaseException:
                 await self._release_bytes(nb)

@@ -210,6 +210,15 @@ class PipelineLedger:
         the stage table, the overlap integrator and the activity wall."""
         return _Tracked(self, stage, nbytes, moved, wait)
 
+    def declare_wait(self, wait: str) -> None:
+        """Enter ``wait`` in the wait table at zero, so that a reader
+        tells "never waited" (0 s) from a program that keeps no such
+        wait (absent): a lane whose every take is full or hinted never
+        enters ``deadline_wait`` at all."""
+        with self._lock:
+            self._cells.write("stages")
+            self._stage_locked(wait, wait=True)
+
     def record(self, stage: str, nbytes: int, seconds: float) -> None:
         """Post-hoc accounting for a stage whose duration was measured
         by the caller (no occupancy window)."""

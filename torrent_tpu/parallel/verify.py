@@ -354,15 +354,22 @@ class _SchedChunk:
             return int(self.slab.lengths[list(self.rows)].sum())
         return sum(len(p) for p in self.payloads)
 
-    async def enqueue(self, scheduler, tenant: str, wait: bool = True):
+    async def enqueue(
+        self, scheduler, tenant: str, wait: bool = True, flush: bool = False
+    ):
         """Submit and hand ownership over: the creator's slab reference
         is released on EVERY path (tickets keep the slab alive through
-        demux; a shed releases everything)."""
+        demux; a shed releases everything). ``flush=True`` is the
+        scheduler's: the caller sends nothing more until this chunk
+        resolves, so its lane launches at once. It is passed on only
+        where it is true: a scheduler stand-in need not know the word."""
+        hint = {"flush": True} if flush else {}
         if self.slab is not None:
             slab, self.slab = self.slab, None
             try:
                 return await scheduler.enqueue_staged(
-                    tenant, slab, self.rows, expected=self.expected, wait=wait
+                    tenant, slab, self.rows, expected=self.expected, wait=wait,
+                    **hint,
                 )
             finally:
                 slab.release()
@@ -373,6 +380,7 @@ class _SchedChunk:
             algo="sha1",
             piece_length=self.piece_length,
             wait=wait,
+            **hint,
         )
 
     def discard(self) -> None:

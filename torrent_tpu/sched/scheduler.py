@@ -22,7 +22,11 @@ bucket)`` — the same pow-2 bucketing the bridge used, so a handful of
 compiled executables serve any geometry and the compile cache survives
 across callers. Each lane runs one assembler task: it flushes a launch
 when the batch fills to the lane target **or** when the oldest queued
-item's deadline expires (flush reasons: full / deadline / shutdown).
+item's deadline expires (flush reasons: full / deadline / hint /
+shutdown). A submitter that sends nothing more until its submission
+resolves says so (``enqueue(..., flush=True)``): nothing it does can
+fill the lane further, so the lane takes at once — the take the deadline
+would have made, without the wait (reason ``hint`` when under target).
 
 Fairness is deficit round-robin over queued *bytes*: each tenant's
 deficit grows by ``drr_quantum × weight`` per assembly pass, so a greedy
@@ -301,10 +305,17 @@ class _Submission:
     tasks and worker threads never inherit a request's contextvars.
     """
 
-    __slots__ = ("mode", "results", "remaining", "future", "trace", "traced_done")
+    __slots__ = ("mode", "results", "remaining", "future", "trace",
+                 "traced_done", "flush")
 
-    def __init__(self, n: int, mode: str, loop: asyncio.AbstractEventLoop):
+    def __init__(
+        self, n: int, mode: str, loop: asyncio.AbstractEventLoop,
+        flush: bool = False,
+    ):
         self.mode = mode  # 'digest' | 'verify'
+        # the submitter sends nothing more until this resolves: a lane
+        # holding any of its tickets takes at once (_Lane.flush_pending)
+        self.flush = flush
         self.results: list = [None] * n
         self.remaining = n
         self.future: asyncio.Future = loop.create_future()
@@ -359,7 +370,7 @@ class _Lane:
 
     __slots__ = (
         "algo", "bucket", "target", "queues", "rotation", "pending_pieces",
-        "event", "task", "plane", "build_lock", "sem", "inflight",
+        "flush_pending", "event", "task", "plane", "build_lock", "sem", "inflight",
         "breaker", "cpu_plane", "backend", "deadline",
         "launches", "fill_sum", "pad_rows_total", "launched_rows_total",
         "staged_launches", "staged_rows_total", "staged_live_rows_total",
@@ -380,6 +391,10 @@ class _Lane:
         self.queues: dict[str, deque] = {}
         self.rotation: list[str] = []
         self.pending_pieces = 0
+        # queued tickets of flush=True submissions: counted where tickets
+        # enter (enqueue) and leave (_drr_take) the queues, their only
+        # two doors, so it is never stale; > 0 ends the fill wait
+        self.flush_pending = 0
         self.event = asyncio.Event()
         self.task: asyncio.Task | None = None
         self.plane = None  # built lazily off the event loop
@@ -1305,7 +1320,7 @@ class HashPlaneScheduler:
         # metrics
         self._launches = 0
         self._fill_sum = 0.0
-        self._flush_reasons = {"full": 0, "deadline": 0, "shutdown": 0}
+        self._flush_reasons = {"full": 0, "deadline": 0, "hint": 0, "shutdown": 0}
         self._shed_total = 0
         # fault-tolerance counters (satellite observability: exported
         # via metrics_snapshot -> render_sched_metrics -> /metrics)
@@ -1707,6 +1722,7 @@ class HashPlaneScheduler:
         algo: str = "sha1",
         piece_length: int | None = None,
         wait: bool = False,
+        flush: bool = False,
     ) -> asyncio.Future:
         """Queue one submission; returns a future resolving to its
         results (digest list, or ok-bytes when ``expected`` is given).
@@ -1714,6 +1730,16 @@ class HashPlaneScheduler:
         ``wait=False`` sheds with :class:`SchedRejected` when admission
         control is over budget (the bridge's 429); ``wait=True`` blocks
         until space frees — the backpressure path for streaming ingest.
+
+        ``flush=True`` is the submitter's word that it sends nothing
+        more until this submission resolves (the fabric executor's last
+        chunk of a unit, after which it only drains): its lane stops
+        waiting for fill and takes at once, whatever else is queued
+        there riding along — the take the flush deadline would have
+        made, counted under the flush reason ``hint`` when it is under
+        target (``full`` otherwise). It is a statement about the
+        caller's own next step, not a setting: everyone else's
+        submissions still wait for fill or ``flush_deadline``.
         """
         if algo not in DIGEST_LEN:
             raise ValueError(f"unknown algo {algo!r}")
@@ -1721,7 +1747,7 @@ class HashPlaneScheduler:
         if expected is not None and len(expected) != len(pieces):
             raise ValueError("expected list must match pieces")
         loop = asyncio.get_running_loop()
-        sub = _Submission(len(pieces), mode, loop)
+        sub = _Submission(len(pieces), mode, loop, flush)
         if not pieces:
             sub.future.set_result(b"" if mode == "verify" else [])
             return sub.future
@@ -1772,6 +1798,8 @@ class HashPlaneScheduler:
                 )
             )
         lane.pending_pieces += len(pieces)
+        if flush:
+            lane.flush_pending += len(pieces)
         ts.queued_bytes += charged
         self._queued_bytes += charged
         lane.event.set()
@@ -1799,9 +1827,11 @@ class HashPlaneScheduler:
         rows: list[int],
         expected: list[bytes] | None = None,
         wait: bool = False,
+        flush: bool = False,
     ) -> asyncio.Future:
         """Slot-carrying submission: queue the pre-staged ``rows`` of a
-        :class:`StagedSlab` (from :meth:`checkout_staging`).
+        :class:`StagedSlab` (from :meth:`checkout_staging`); ``wait``
+        and ``flush`` as for :meth:`enqueue`.
 
         Tickets carry :class:`SlotRow` payloads — zero-copy views into
         the slab — and each holds one slab reference that the demux
@@ -1823,6 +1853,7 @@ class HashPlaneScheduler:
                 algo=slab.algo,
                 piece_length=slab.piece_length,
                 wait=wait,
+                flush=flush,
             )
         except BaseException:
             slab.release(len(payloads))
@@ -1897,6 +1928,8 @@ class HashPlaneScheduler:
         span held across its ``await``."""
         cfg = self.config
         led = pipeline_ledger()
+        # a lane that never had to wait for fill reads 0 s of it
+        led.declare_wait("deadline_wait")
         while True:
             if lane.pending_pieces == 0:
                 if self._closing:
@@ -1908,33 +1941,45 @@ class HashPlaneScheduler:
                 continue
             # oldest queued item bounds the wait: flush at target fill
             # or when its deadline expires, whichever comes first (the
-            # autopilot may have set a per-lane deadline override)
+            # autopilot may have set a per-lane deadline override); a
+            # queued flush=True ticket ends the wait before it starts:
+            # its submitter is parked on it and fills nothing
             flush_after = (
                 lane.deadline if lane.deadline is not None else cfg.flush_deadline
             )
             deadline = lane.oldest_ts() + flush_after
-            while lane.pending_pieces < lane.target and not self._closing:
+            while (
+                lane.pending_pieces < lane.target
+                and not lane.flush_pending
+                and not self._closing
+            ):
                 remaining = deadline - time.monotonic()
                 if remaining <= 0:
                     break
                 lane.event.clear()
-                if lane.pending_pieces >= lane.target or self._closing:
+                if (
+                    lane.pending_pieces >= lane.target
+                    or lane.flush_pending
+                    or self._closing
+                ):
                     break
                 try:
                     with led.track("deadline_wait", wait=True):
                         await asyncio.wait_for(lane.event.wait(), remaining)
                 except asyncio.TimeoutError:
                     break
+            hinted = lane.flush_pending > 0  # before the take spends it
             with led.track("assemble") as assembling:
                 tickets = self._drr_take(lane)
                 assembling.add(sum(t.nbytes for t in tickets))
             if not tickets:
                 continue
-            reason = (
-                "full"
-                if len(tickets) >= lane.target
-                else ("shutdown" if self._closing else "deadline")
-            )
+            if len(tickets) >= lane.target:
+                reason = "full"
+            elif self._closing:
+                reason = "shutdown"
+            else:
+                reason = "hint" if hinted else "deadline"
             # pipelined launch: the semaphore bounds in-flight launches
             # (depth 2 = double-buffer) while this loop keeps assembling
             # the next batch during the device run — the host/device
@@ -1970,6 +2015,8 @@ class HashPlaneScheduler:
                     tkt = q.popleft()
                     t.deficit -= tkt.nbytes
                     lane.pending_pieces -= 1
+                    if tkt.sub.flush:
+                        lane.flush_pending -= 1
                     taken.append(tkt)
                 if not q:
                     t.deficit = 0  # classic DRR: no credit hoarding

@@ -11,6 +11,7 @@ from __future__ import annotations
 
 import asyncio
 import hashlib
+import json
 
 import pytest
 
@@ -383,3 +384,167 @@ class TestStreamingBridge:
                 await server.wait_closed()
 
         run(go())
+
+
+# ---------------------------------------------------------------- phases
+
+_PHASE_WAITS = ("http_head", "http_body", "verdict_wake", "http_request")
+_PHASE_STAGES = ("decode", "reply")
+
+
+def _phase_counts() -> dict:
+    """``(busy_s, ops, active, bytes)`` of every entry a request's phases
+    touch in the process's ledger."""
+    from torrent_tpu.obs import pipeline_ledger
+
+    snap = pipeline_ledger().snapshot()
+    out = {}
+    for table, names in (("waits", _PHASE_WAITS), ("stages", _PHASE_STAGES)):
+        for name in names:
+            e = snap[table].get(name, {})
+            out[name] = (e.get("busy_s", 0.0), e.get("ops", 0), e.get("active", 0), e.get("bytes", 0))
+    return out
+
+
+def bencode_req(pieces, expected=None) -> bytes:
+    from torrent_tpu.codec.bencode import bencode
+
+    req = {b"pieces": pieces}
+    if expected is not None:
+        req[b"expected"] = expected
+    return bencode(req)
+
+
+async def _raw(port: int, data: bytes) -> bytes:
+    reader, writer = await asyncio.open_connection("127.0.0.1", port)
+    writer.write(data)
+    await writer.drain()
+    out = await reader.read()
+    writer.close()
+    return out
+
+
+class TestRequestPhases:
+    """A buffered hash request's milliseconds by phase, from inside the
+    bridge: head, body and wake are ledger waits recorded after the fact,
+    decode and reply are stages (work on the loop thread), the whole is
+    the wait ``http_request``; the scheduler exports the e2e family's
+    sum and count; the request's trace tree shows the same intervals."""
+
+    def test_one_verify_moves_every_phase_by_one(self):
+        async def go():
+            server = await _start("cpu")
+            try:
+                pieces = _mk_pieces(3, 4096)
+                body = bencode_req(pieces, [hashlib.sha1(p).digest() for p in pieces])
+                c0, s0 = _phase_counts(), server.sched.metrics_snapshot()
+                status, resp = await _post_raw(server.port, "/v1/verify", {}, body)
+                assert status == 200 and bdecode(resp)[b"ok"] == b"\x01\x01\x01"
+                c1, s1 = _phase_counts(), server.sched.metrics_snapshot()
+                return c0, c1, s0, s1, len(body), sum(len(p) for p in pieces)
+            finally:
+                server.close()
+                await server.wait_closed()
+
+        c0, c1, s0, s1, body_len, payload_len = run(go())
+        d = {k: (c1[k][0] - c0[k][0], c1[k][1] - c0[k][1]) for k in c0}
+        assert {k: ops for k, (_, ops) in d.items()} == dict.fromkeys(_PHASE_WAITS + _PHASE_STAGES, 1)
+        assert all(c1[k][2] == 0 for k in c1)  # nothing left active
+        assert s1["e2e_pieces"] - s0["e2e_pieces"] == 3
+        # a piece's enqueue → verdict, a mean of three here; the request's is the same interval
+        e2e = (s1["e2e_s_sum"] - s0["e2e_s_sum"]) / 3
+        assert e2e > 0
+        parts = sum(d[k][0] for k in ("http_head", "http_body", "decode", "verdict_wake", "reply")) + e2e
+        assert parts <= d["http_request"][0]
+        # bytes: the body's on the way in, the payload's on the way out
+        assert c1["http_body"][3] - c0["http_body"][3] == body_len
+        assert c1["decode"][3] - c0["decode"][3] == body_len
+        assert c1["reply"][3] - c0["reply"][3] == payload_len
+
+    @pytest.mark.parametrize(
+        "case,status,moved",
+        [
+            ("refused", 429, ("http_head", "http_body", "decode", "reply", "http_request")),
+            ("malformed", 400, ("http_head", "http_body", "decode", "reply", "http_request")),
+            ("launch_failed", 500, _PHASE_WAITS + _PHASE_STAGES),
+            # the two replies before the root span opens: counted too
+            ("bad_request_line", 400, ("http_head", "reply", "http_request")),
+            ("headers_too_large", 431, ("http_head", "reply", "http_request")),
+            # a route without a body or a submit
+            ("get", 200, ("http_head", "reply", "http_request")),
+        ],
+    )
+    def test_every_reply_records_the_whole_and_leaves_nothing_active(self, case, status, moved):
+        from torrent_tpu.bridge.service import BridgeServer
+
+        async def go():
+            kwargs = {}
+            if case == "refused":
+                kwargs["tenant_max_mb"] = 0  # every submission is over its tenant's budget
+            if case == "launch_failed":
+                kwargs["fault_plan"] = "payload=bdbdbdbd"
+            server = await BridgeServer(port=0, hasher="cpu", **kwargs).start()
+            try:
+                c0 = _phase_counts()
+                if case == "bad_request_line":
+                    raw = await _raw(server.port, b"nonsense\r\n\r\n")
+                elif case == "headers_too_large":
+                    raw = await _raw(
+                        server.port,
+                        b"GET /v1/info HTTP/1.1\r\n" + b"".join(b"X-Pad-%d: %s\r\n" % (i, b"p" * 1000) for i in range(20)) + b"\r\n",
+                    )
+                elif case == "get":
+                    raw = await _raw(server.port, b"GET /v1/info HTTP/1.1\r\nHost: x\r\n\r\n")
+                else:
+                    piece = (b"\xbd\xbd\xbd\xbd" if case == "launch_failed" else b"good") + b"x" * 60
+                    body = b"not bencode" if case == "malformed" else bencode_req([piece])
+                    got, _ = await _post_raw(server.port, "/v1/digests", {}, body)
+                    raw = b"HTTP/1.1 %d X" % got
+                return int(raw.split()[1]), c0, _phase_counts()
+            finally:
+                server.close()
+                await server.wait_closed()
+
+        got, c0, c1 = run(go())
+        assert got == status
+        assert {k for k in c0 if c1[k][1] - c0[k][1] == 1} == set(moved)
+        assert all(c1[k][1] - c0[k][1] in (0, 1) for k in c0)
+        assert all(c1[k][2] == 0 for k in c1)
+        # only a verdict answers for payload bytes
+        assert c1["reply"][3] == c0["reply"][3]
+
+    def test_the_trace_tree_shows_the_phases_inside_their_parents(self):
+        async def go():
+            server = await _start("cpu")
+            try:
+                pieces = _mk_pieces(2, 4096)
+                body = bencode_req(pieces, [hashlib.sha1(p).digest() for p in pieces])
+                status, _ = await _post_raw(server.port, "/v1/verify", {"X-Trace-Id": "phases-0001"}, body)
+                assert status == 200
+                raw = await _raw(server.port, b"GET /v1/trace?id=phases-0001 HTTP/1.1\r\nHost: x\r\n\r\n")
+                return json.loads(raw.split(b"\r\n\r\n", 1)[1]), len(body)
+            finally:
+                server.close()
+                await server.wait_closed()
+
+        tree, body_len = run(go())
+        (root,) = tree["spans"]
+        assert root["name"] == "bridge.request" and root["attrs"]["head_ms"] >= 0
+        kids = {c["name"]: c for c in root["children"]}
+        assert {"bridge.body", "bridge.decode", "sched.enqueue", "bridge.reply"} <= set(kids)
+        order = ["bridge.body", "bridge.decode", "sched.enqueue", "bridge.reply"]
+        starts = [kids[n]["start_ms"] for n in order]
+        assert starts == sorted(starts)
+        for name in ("bridge.body", "bridge.decode", "bridge.reply"):
+            c = kids[name]
+            assert c["start_ms"] >= root["start_ms"]
+            # the tree rounds to the microsecond
+            assert c["start_ms"] + c["duration_ms"] <= root["start_ms"] + root["duration_ms"] + 0.002, name
+        assert kids["bridge.body"]["attrs"]["bytes"] == kids["bridge.decode"]["attrs"]["bytes"] == body_len
+        assert kids["bridge.reply"]["attrs"]["status_code"] == 200
+        sched = {c["name"]: c for c in kids["sched.enqueue"]["children"]}
+        assert {"sched.lane_wait", "sched.launch", "sched.digest", "sched.wake"} <= set(sched)
+        # the wake begins where the demux resolved the submission and
+        # ends before the reply does
+        assert sched["sched.wake"]["start_ms"] >= sched["sched.digest"]["start_ms"]
+        assert sched["sched.wake"]["start_ms"] + sched["sched.wake"]["duration_ms"] <= kids["bridge.reply"]["start_ms"] + 0.002

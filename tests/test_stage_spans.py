@@ -44,7 +44,10 @@ BATCH = 8
 # the stages of the two roads, as the ledger's docstring names them
 RECHECK_STAGES = {"pass_setup", "read", "pad", "h2d", "launch", "digest"}
 SCHED_STAGES = {"assemble", "stage", "h2d", "launch", "digest", "verdict"}
-SCHED_WAITS = {"lane_idle", "deadline_wait", "sem_wait"}
+SCHED_WAITS = {"lane_idle", "deadline_wait", "sem_wait", "verdict_wake"}
+# the bridge's two stages and its four waits (a request's phases, PR 34)
+BRIDGE_STAGES = {"decode", "reply"}
+BRIDGE_WAITS = {"http_head", "http_body", "verdict_wake", "http_request"}
 
 
 class _Recorder:
@@ -375,6 +378,12 @@ class TestSchedulerRoad:
         assert h2d["moved_bytes"] == 8 * padded_len_for(PLEN)  # the whole slab, whatever its fill
         assert h2d["bytes"] == 3 * PLEN
         assert snap["stages"]["assemble"]["bytes"] == 3 * PLEN
+        # submit() says how late the loop woke it, after the fact: an op
+        # a call in the wait table, and no span (many callers park here
+        # at once: a span each would name every gap it covers)
+        assert snap["waits"]["verdict_wake"]["ops"] == 2 and snap["waits"]["verdict_wake"]["active"] == 0
+        assert 0 < snap["waits"]["verdict_wake"]["busy_s"] < 0.05
+        assert not recorder.of("verdict_wake")
         # a parked lane is no busy stage: the attributor never sees it
         rep = attribute(snap)
         assert not set(rep["stages"]) & SCHED_WAITS
@@ -392,6 +401,54 @@ class TestSchedulerRoad:
         assert waited == pytest.approx(total, rel=1e-9)
         mean = waited / pieces
         assert 0.03 < mean < 0.2  # four of five waited 40 ms of the deadline, one all 50
+        # enqueue → verdict likewise, the e2e family's own sum and count:
+        # the same five pieces, each at least its queue wait
+        e2e_pieces = snap["e2e_pieces"] - out["snap0"]["e2e_pieces"]
+        e2e = snap["e2e_s_sum"] - out["snap0"]["e2e_s_sum"]
+        assert e2e_pieces == 5 and waited < e2e < waited + 5 * 0.5
+
+
+class TestBridgeRoad:
+    def test_a_request_writes_its_phases_at_its_reply_and_opens_no_span(self, recorder):
+        """A buffered hash request: ``decode`` and ``reply`` are the loop
+        thread's work, stages; head, body, wake and the whole are waits.
+        All of them are recorded after the fact, so none opens a span for
+        an idle gap of the device to take its name from (a ``track()``
+        each for the two stages was measured and cost the live cell's
+        median 3.5 %: PERF.md, PR 34)."""
+        from torrent_tpu.bridge.service import BridgeServer
+        from torrent_tpu.codec.bencode import bencode
+
+        pieces = [bytes([i + 1]) * 1024 for i in range(2)]
+        body = bencode({b"pieces": pieces, b"expected": [hashlib.sha1(p).digest() for p in pieces]})
+        out: dict = {}
+
+        async def go():
+            server = await BridgeServer(port=0, hasher="cpu", flush_deadline_ms=5).start()
+            try:
+                reader, writer = await asyncio.open_connection("127.0.0.1", server.port)
+                writer.write(b"POST /v1/verify HTTP/1.1\r\nContent-Length: %d\r\n\r\n" % len(body) + body)
+                await writer.drain()
+                out["reply"] = await reader.read()
+                writer.close()
+                out["loop"] = threading.get_ident()
+            finally:
+                server.close()
+                await server.wait_closed()
+
+        asyncio.run(go())
+        assert out["reply"].startswith(b"HTTP/1.1 200")
+        _no_metadata(recorder.names())
+        assert not any(recorder.of(name) for name in BRIDGE_WAITS | BRIDGE_STAGES)
+        # the lane's own spans are there as before, on the loop's thread
+        assert {s[1] for s in recorder.of("verdict")} == {out["loop"]}
+        snap = pipeline_ledger().snapshot()
+        assert BRIDGE_STAGES <= set(snap["stages"]) and BRIDGE_WAITS <= set(snap["waits"])
+        assert all(snap["waits"][w]["ops"] == 1 and snap["waits"][w]["max_active"] == 0 for w in BRIDGE_WAITS)
+        assert all(snap["stages"][s]["ops"] == 1 and snap["stages"][s]["max_active"] == 0 for s in BRIDGE_STAGES)
+        # the two stages enter the attribution, the waits never do
+        assert BRIDGE_STAGES <= set(attribute(snap)["stages"])
+        assert not set(attribute(snap)["stages"]) & BRIDGE_WAITS
 
 
 class TestFabricRoad:
@@ -459,6 +516,57 @@ class TestLedgerContract:
         led.clear()
         assert led.snapshot()["waits"] == {}
 
+    def test_a_wait_recorded_after_the_fact_touches_the_wait_table_alone(self):
+        led = PipelineLedger()
+        with led.track("read", 10):
+            pass
+        mid = led.snapshot()
+        led.record("http_body", 4096, 0.25, wait=True)
+        led.record("http_body", 4096, 0.5, wait=True)
+        led.record("http_request", 0, -1.0, wait=True)  # a clock that stepped back counts an op and no time
+        snap = led.snapshot()
+        assert snap["waits"]["http_body"] == {
+            "busy_s": 0.75, "bytes": 8192, "moved_bytes": 0, "ops": 2, "active": 0, "max_active": 0
+        }
+        assert snap["waits"]["http_request"]["busy_s"] == 0.0 and snap["waits"]["http_request"]["ops"] == 1
+        # neither the stage table, nor the overlap, nor the activity wall
+        assert snap["stages"] == mid["stages"] and snap["overlap"] == mid["overlap"]
+        assert (snap["t_first"], snap["t_last"]) == (mid["t_first"], mid["t_last"])
+        assert attribute(snap)["bottleneck"]["stage"] == "read"
+        # without the keyword it is a stage, as before, and moves the wall
+        led.record("decode", 7, 0.125)
+        after = led.snapshot()
+        assert after["stages"]["decode"]["ops"] == 1 and "decode" not in after["waits"]
+        assert after["t_last"] > mid["t_last"]
+
+    def test_many_entries_go_in_under_one_lock_and_read_like_single_ones(self):
+        one, many = PipelineLedger(), PipelineLedger()
+        entries = [
+            ("http_head", 0, 0.5, True), ("http_body", 4096, 0.25, True),
+            ("decode", 4100, 0.125, False), ("reply", 4096, 0.0625, False), ("http_request", 0, 1.0, True),
+        ]
+        for e in entries:
+            one.record(*e)
+        takes = []
+        lock = many._lock
+
+        class Counting:
+            def __enter__(self):
+                takes.append(1)
+                return lock.__enter__()
+
+            def __exit__(self, *exc):
+                return lock.__exit__(*exc)
+
+        many._lock = Counting()
+        many.record_many(entries)
+        many._lock = lock
+        assert len(takes) == 1
+        a, b = one.snapshot(), many.snapshot()
+        assert a["stages"] == b["stages"] and a["waits"] == b["waits"]
+        assert set(b["stages"]) == {"decode", "reply"} and b["stages"]["decode"]["max_active"] == 0
+        assert b["t_first"] is not None and b["overlap"]["busy_s"] == 0.0
+
     def test_a_declared_wait_reads_zero_until_it_is_entered(self):
         led = PipelineLedger()
         led.declare_wait("deadline_wait")
@@ -472,13 +580,17 @@ class TestLedgerContract:
 
     def test_new_stages_render_lintable_and_fold_nothing(self):
         led = PipelineLedger()
-        new = sorted((RECHECK_STAGES | SCHED_STAGES) - set(PIPELINE_STAGES))
-        assert new == ["assemble", "pad", "pass_setup"]
-        assert len(PIPELINE_STAGES) + len(new) <= MAX_STAGES
+        # one process can hold them all: a bridge that also runs a
+        # fabric job and a v2 stream (``merkle``, tests/test_v2_stage_spans.py)
+        new = sorted((RECHECK_STAGES | SCHED_STAGES | BRIDGE_STAGES | {"merkle"}) - set(PIPELINE_STAGES))
+        assert new == ["assemble", "decode", "merkle", "pad", "pass_setup", "reply"]
+        assert len(PIPELINE_STAGES) + len(new) == 14 <= MAX_STAGES
         for stage in new + list(PIPELINE_STAGES):
             with led.track(stage, 64, moved=128):
                 pass
-        for wait in sorted(SCHED_WAITS | {"read_wait", "unit_drain"}):
+        waits = sorted(SCHED_WAITS | BRIDGE_WAITS | {"read_wait", "unit_drain"})
+        assert len(waits) <= MAX_STAGES  # the wait table folds past the same bound
+        for wait in waits:
             with led.track(wait, wait=True):
                 pass
         snap = led.snapshot()
@@ -489,6 +601,7 @@ class TestLedgerContract:
         for stage in new:
             assert f'torrent_tpu_pipeline_stage_busy_seconds_total{{stage="{stage}"}}' in text
         assert "deadline_wait" not in text and "read_wait" not in text and "unit_drain" not in text
+        assert not any(w in text for w in BRIDGE_WAITS)
 
     def test_track_without_jax_imports_nothing(self):
         """``import torrent_tpu`` itself pulls JAX in today (``parallel/

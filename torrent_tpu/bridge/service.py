@@ -55,6 +55,30 @@ ticket lifecycle (enqueue → admission/shed → lane wait → launch/retry/
 bisect → digest → verdict) so ``/v1/trace?id=…`` shows where a request
 spent its time.
 
+A request's milliseconds by phase, always on, each clock read written
+to the pipeline ledger and to that tree both: **head** (the accept
+callback's task starts → headers parsed; ledger wait ``http_head``,
+the root span's ``head_ms``), **body** (→ ``readexactly`` returned;
+wait ``http_body``, span ``bridge.body``), **decode** (``bdecode`` and
+the checks up to the submit; ledger *stage* ``decode``, span
+``bridge.decode``), enqueue → verdict (the scheduler's own spans and
+its e2e histogram), **wake** (the submission resolved → ``submit()``
+running again; wait ``verdict_wake``, span ``sched.wake``), **reply**
+(``_reply`` entered → ``writer.close()``; stage ``reply``, span
+``bridge.reply``) and **the whole** (accept → the reply written; wait
+``http_request``). All of it is written after the fact, at the reply,
+in one ledger call, and opens no profiler span: many requests sit in a
+wait at once, and a ``track()`` each for the two stages (the loop
+thread's work) cost the live cell's median 3.5 % (``PERF.md``, PR 34:
+what this loop spends a request comes back ≈ 45 × in its latency).
+Every reply records ``http_head``, ``reply``
+and ``http_request``, the 400 / 431 sent before the headers are parsed
+too; ``http_body`` where a body was read; ``decode`` and
+``verdict_wake`` on the buffered hash routes. ``GET /v1/pipeline``
+shows them (``snapshot.waits``, ``snapshot.stages``); the histogram
+``torrent_tpu_bridge_request_seconds`` keeps its meaning (headers
+parsed → reply). The stream routes have no per-frame phases.
+
   POST /v1/fabric/verify  body {items: [{torrent, root}, ...]}
                           → 202; starts a scheduler-fed library recheck
                             (torrent_tpu/fabric) of sidecar-local paths
@@ -129,6 +153,7 @@ Hand-rolled asyncio HTTP — no web framework needed for six routes.
 from __future__ import annotations
 
 import asyncio
+import contextvars
 import json
 import time
 
@@ -136,6 +161,7 @@ from torrent_tpu.codec.bencode import BencodeError, bdecode, bencode
 from torrent_tpu.obs import (
     flight_recorder,
     histograms,
+    pipeline_ledger,
     render_obs_metrics,
     tracer,
     valid_trace_id,
@@ -165,6 +191,14 @@ _KNOWN_ROUTES = frozenset(
 _H_REQUEST = (
     "torrent_tpu_bridge_request_seconds",
     "Bridge HTTP request duration by route",
+)
+# One request's stamps (``time.monotonic()``), from ``_handle`` to
+# ``_route`` and ``_reply`` in the connection's own task: ``accept``,
+# then as they pass ``head``, ``body`` + ``body_bytes``, and
+# ``decode`` (bytes, seconds) and ``payload_bytes`` once a verdict is
+# about to be answered. ``_reply`` writes them to the ledger and clears it.
+_request_clock: contextvars.ContextVar[dict | None] = contextvars.ContextVar(
+    "bridge_request_clock", default=None
 )
 
 MAX_BODY = 1 << 30  # 1 GiB of piece data per buffered (non-stream) request
@@ -598,6 +632,8 @@ class BridgeServer:
     # --------------------------------------------------------------- http
 
     async def _handle(self, reader: asyncio.StreamReader, writer: asyncio.StreamWriter):
+        clock = {"accept": time.monotonic()}
+        _request_clock.set(clock)
         try:
             request_line = (await asyncio.wait_for(reader.readline(), 60)).split()
             if len(request_line) < 2:
@@ -626,10 +662,14 @@ class BridgeServer:
             route = path if path in _KNOWN_ROUTES else "other"
             t0 = time.monotonic()
             try:
+                # the root keeps its start (a child may not begin
+                # before its parent); what came before it is head_ms
                 with tracer().span(
                     "bridge.request", trace_id=trace_id, method=method,
                     target=path, tenant=self._tenant_of(headers),
-                ):
+                    head_ms=round((t0 - clock["accept"]) * 1e3, 3),
+                ) as root_id:
+                    clock["head"] = time.monotonic()
                     if method == "POST" and target.startswith("/v1/stream/"):
                         body_reader = _BodyReader(reader, headers)
                         return await self._route_stream(
@@ -641,11 +681,16 @@ class BridgeServer:
                         return await self._reply(writer, 400, b"bad content-length")
                     if content_length > MAX_BODY:
                         return await self._reply(writer, 413, b"body too large")
-                    body = (
-                        await reader.readexactly(content_length)
-                        if content_length
-                        else b""
-                    )
+                    body = b""
+                    if content_length:
+                        body = await reader.readexactly(content_length)
+                        clock["body"] = time.monotonic()
+                        clock["body_bytes"] = content_length
+                        tracer().add_span(
+                            trace_id, "bridge.body", parent_id=root_id,
+                            t0=clock["head"], t1=clock["body"],
+                            bytes=content_length,
+                        )
                     await self._route(writer, method, target, body, headers)
             finally:
                 histograms().get(*_H_REQUEST, route=route).observe(
@@ -749,55 +794,75 @@ class BridgeServer:
             return await self._reply(writer, 405, b"method not allowed")
         if target == "/v1/fabric/verify":
             return await self._fabric_verify(writer, body)
+        # work on the loop thread: a stage, written to the ledger with
+        # the request's other entries at its reply
+        t_dec0 = time.monotonic()
+        pieces, expected, refused = self._decode_hash(target, body, headers)
+        t_dec1 = time.monotonic()
+        clock = _request_clock.get()
+        if clock is not None:
+            clock["decode"] = (len(body), t_dec1 - t_dec0)
+        ctx = tracer().current_context()
+        if ctx is not None:
+            tracer().add_span(
+                ctx[0], "bridge.decode", parent_id=ctx[1], t0=t_dec0, t1=t_dec1,
+                status="ok" if refused is None else "error", bytes=len(body),
+            )
+        if refused is not None:
+            return await self._reply(writer, *refused)
+        try:
+            answer = await self.sched.submit(
+                self._tenant_of(headers), pieces, expected=expected, algo="sha1"
+            )
+        except SchedRejected as e:
+            return await self._reply(writer, 429, str(e).encode())
+        except SchedLaunchError as e:
+            return await self._reply_launch_failed(writer, e)
+        if clock is not None:
+            clock["payload_bytes"] = sum(len(p) for p in pieces)
+        key = b"digests" if expected is None else b"ok"
+        await self._reply(writer, 200, bencode({key: answer}))
+
+    @staticmethod
+    def _decode_hash(target: str, body: bytes, headers):
+        """``bdecode`` and every check between a buffered hash request's
+        body and its ``submit``: ``(pieces, expected, None)``, with
+        ``expected`` ``None`` on ``/v1/digests``, or ``(None, None,
+        (status, message))``. It never awaits: ``_route`` times it
+        whole as the ledger stage ``decode``."""
         # the buffered hash routes are sha1-only; a sha256 request must
         # fail closed, not silently return v1 digests with a 200 (the
         # algorithm-agnostic /v1/info above is exempt)
         algo = (headers or {}).get(b"x-hash-algo", b"sha1").decode("latin-1").lower()
         if algo != "sha1":
-            return await self._reply(
-                writer, 400, b"buffered routes are sha1-only; use /v1/stream/* for sha256"
+            return None, None, (
+                400, b"buffered routes are sha1-only; use /v1/stream/* for sha256"
             )
         try:
             req = bdecode(body)
         except BencodeError as e:
-            return await self._reply(writer, 400, f"bad bencode: {e}".encode())
+            return None, None, (400, f"bad bencode: {e}".encode())
         if not isinstance(req, dict) or not isinstance(req.get(b"pieces"), list):
-            return await self._reply(writer, 400, b"missing pieces list")
+            return None, None, (400, b"missing pieces list")
         pieces = req[b"pieces"]
         if not all(isinstance(p, bytes) for p in pieces):
-            return await self._reply(writer, 400, b"pieces must be bytestrings")
+            return None, None, (400, b"pieces must be bytestrings")
         if any(len(p) > MAX_PIECE for p in pieces):
             # same cap as the stream routes: an oversized piece would open
             # (and cache) a scheduler lane far beyond the staging budget
-            return await self._reply(writer, 413, b"piece exceeds 16MiB cap")
-        tenant = self._tenant_of(headers)
-
+            return None, None, (413, b"piece exceeds 16MiB cap")
         if target == "/v1/digests":
-            try:
-                digests = await self.sched.submit(tenant, pieces, algo="sha1")
-            except SchedRejected as e:
-                return await self._reply(writer, 429, str(e).encode())
-            except SchedLaunchError as e:
-                return await self._reply_launch_failed(writer, e)
-            return await self._reply(writer, 200, bencode({b"digests": digests}))
-        if target == "/v1/verify":
-            expected = req.get(b"expected")
-            if (
-                not isinstance(expected, list)
-                or len(expected) != len(pieces)
-                or not all(isinstance(e, bytes) and len(e) == 20 for e in expected)
-            ):
-                return await self._reply(writer, 400, b"expected must be 20-byte hashes")
-            try:
-                ok = await self.sched.submit(
-                    tenant, pieces, expected=expected, algo="sha1"
-                )
-            except SchedRejected as e:
-                return await self._reply(writer, 429, str(e).encode())
-            except SchedLaunchError as e:
-                return await self._reply_launch_failed(writer, e)
-            return await self._reply(writer, 200, bencode({b"ok": ok}))
-        await self._reply(writer, 404, b"not found")
+            return pieces, None, None
+        if target != "/v1/verify":
+            return None, None, (404, b"not found")
+        expected = req.get(b"expected")
+        if (
+            not isinstance(expected, list)
+            or len(expected) != len(pieces)
+            or not all(isinstance(e, bytes) and len(e) == 20 for e in expected)
+        ):
+            return None, None, (400, b"expected must be 20-byte hashes")
+        return pieces, expected, None
 
     # ------------------------------------------------------------- fabric
 
@@ -945,7 +1010,6 @@ class BridgeServer:
         keys, same operator-surface conventions as ``/v1/trace``; pure
         in-memory reads, safe on the serving loop."""
         from torrent_tpu.obs.attrib import attribute
-        from torrent_tpu.obs.ledger import pipeline_ledger
 
         snap = pipeline_ledger().snapshot()
         sched_snap = self.sched.metrics_snapshot() if self.sched else {}
@@ -1170,6 +1234,9 @@ class BridgeServer:
         headers=None,
         content_type: str = "application/octet-stream",
     ):
+        clock = _request_clock.get()
+        ctx = tracer().current_context()
+        t_rep0 = time.monotonic()
         try:
             head = (
                 f"HTTP/1.1 {status} X\r\nContent-Type: {content_type}\r\n"
@@ -1177,7 +1244,6 @@ class BridgeServer:
             )
             # every traced request echoes its trace id, honored or
             # minted, so the client can fetch GET /v1/trace?id=…
-            ctx = tracer().current_context()
             if ctx is not None:
                 head += f"X-Trace-Id: {ctx[0]}\r\n"
             for k, v in (headers or {}).items():
@@ -1189,6 +1255,32 @@ class BridgeServer:
             pass
         finally:
             writer.close()
+        t_rep1 = time.monotonic()
+        if ctx is not None:
+            tracer().add_span(
+                ctx[0], "bridge.reply", parent_id=ctx[1], t0=t_rep0, t1=t_rep1,
+                status_code=status, bytes=len(body),
+            )
+        # The request's phases, after the fact and under one acquisition
+        # of the ledger's lock: what this loop spends a request comes back
+        # many times over in the request's latency. Parked phases are
+        # waits (some fifteen requests sit in one at once: no span);
+        # decode and reply are the loop's work, stages (bytes of reply:
+        # the payload a verdict is answered for, as ``verdict`` counts
+        # them, not the reply's few). A reply before the headers were
+        # parsed (400, 431) ends its head.
+        if clock is None:
+            return
+        _request_clock.set(None)
+        accept, head_at = clock["accept"], clock.get("head", t_rep0)
+        entries = [("http_head", 0, head_at - accept, True)]
+        if "body" in clock:
+            entries.append(("http_body", clock["body_bytes"], clock["body"] - head_at, True))
+        if "decode" in clock:
+            entries.append(("decode", *clock["decode"], False))
+        entries.append(("reply", clock.get("payload_bytes", 0), t_rep1 - t_rep0, False))
+        entries.append(("http_request", 0, t_rep1 - accept, True))
+        pipeline_ledger().record_many(entries)
 
 
 async def serve_bridge(

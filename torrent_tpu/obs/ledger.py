@@ -69,6 +69,14 @@ folded). The v2 recheck (``verify_v2``) charges the chain by file:
 ``pass_setup`` (leaf function, padded slab), ``read``, ``stage`` (the
 chunk's copy, the slab's memset, the copy into it, ``pad_in_place``),
 then ``h2d``, ``launch``, ``digest`` a leaf launch, synchronously.
+The bridge (``bridge/service.py``) charges a buffered hash request's
+work on the loop thread: ``decode`` (``bdecode`` and the checks up to
+the submit; bytes are the body's) and ``reply`` (``_reply`` entered →
+``writer.close()``; bytes are the payload bytes answered for, as
+``verdict`` counts them). Both go through ``record_many`` at the reply,
+beside the request's waits: stages without a host span, the one
+exception to the next paragraph (with ``track()``'s two spans a request
+the live cell's median verdict came 3.5 % later, ``PERF.md`` PR 34).
 
 Every stage entry is also a host span in the profiler's trace
 (``obs/profiler.open_span``: ``sched_<stage>``, on the device trace's
@@ -80,7 +88,13 @@ meanwhile) — and lands in a table
 of its own, ``snapshot()["waits"]``: everything that iterates
 ``stages`` (the attributor, ``doctor --bottleneck``, ``top``, the
 Prometheus stage series, the autopilot) never sees a parked lane as a
-busy stage.
+busy stage. A request's parked phases go there after the fact
+(``record(..., wait=True)`` / ``record_many``: seconds and an op, no
+span, because some fifteen requests sit in one at once): ``http_head``
+(accept → headers parsed), ``http_body`` (→ the body read),
+``verdict_wake`` (a submission resolved → ``submit()`` running again)
+and the whole, ``http_request`` (accept → the reply written and the
+socket closed).
 
 The ledger also integrates cross-stage occupancy overlap — wall
 seconds with ≥2 distinct stages simultaneously busy and the
@@ -117,7 +131,10 @@ __all__ = [
 PIPELINE_STAGES = ("recv", "read", "stage", "h2d", "launch", "digest", "verdict", "egress")
 
 # unknown stage names fold into "other" past this bound — the ledger's
-# cardinality must stay fixed no matter what a plane_factory plane does
+# cardinality must stay fixed no matter what a plane_factory plane does.
+# One process can hold 14 today: the eight above, ``pass_setup``,
+# ``pad``, ``assemble``, ``merkle``, and the bridge's ``decode`` and
+# ``reply`` (tests/test_stage_spans.py keeps the budget)
 MAX_STAGES = 16
 
 
@@ -219,18 +236,34 @@ class PipelineLedger:
             self._cells.write("stages")
             self._stage_locked(wait, wait=True)
 
-    def record(self, stage: str, nbytes: int, seconds: float) -> None:
+    def record(self, stage: str, nbytes: int, seconds: float, wait: bool = False) -> None:
         """Post-hoc accounting for a stage whose duration was measured
-        by the caller (no occupancy window)."""
+        by the caller (no occupancy window). ``wait=True`` puts the
+        seconds and one op into ``waits`` and nowhere else: no activity
+        wall, no overlap, and (as for every ``record``) no host span —
+        the form for a wait many callers sit in at once (the bridge's
+        per-request phases), where a span a caller would lend its name
+        to every idle gap of the device that it happens to cover."""
+        self.record_many(((stage, nbytes, seconds, wait),))
+
+    def record_many(self, entries) -> None:
+        """:meth:`record` for several entries, ``(stage, nbytes,
+        seconds, wait)`` each, under one acquisition of the lock: the
+        bridge writes a request's phases at its reply, and what the
+        serving loop spends a request comes back many times over in the
+        request's latency (``PERF.md``, PR 34)."""
         now = time.monotonic()
         with self._lock:
             self._cells.write("stages")
-            s = self._stage_locked(stage)
-            s.busy_s += max(0.0, seconds)
-            s.bytes += nbytes
-            s.ops += 1
-            self._touch_locked(now - max(0.0, seconds))
-            self._touch_locked(now)
+            for stage, nbytes, seconds, wait in entries:
+                seconds = max(0.0, seconds)
+                s = self._stage_locked(stage, wait)
+                s.busy_s += seconds
+                s.bytes += nbytes
+                s.ops += 1
+                if not wait:
+                    self._touch_locked(now - seconds)
+                    self._touch_locked(now)
 
     def _stage_locked(self, stage: str, wait: bool = False) -> _Stage:
         table = self._waits if wait else self._stages

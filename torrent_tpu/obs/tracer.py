@@ -73,12 +73,15 @@ def valid_trace_id(raw: str) -> bool:
     return 0 < len(raw) <= 64 and all(c in _ID_OK for c in raw)
 
 
+_PLAIN = frozenset((bool, int, float))
+
+
 def _clean_attr(value):
     """Span attrs are scalars only — payload bytes must never enter the
     trace store (the flight recorder dumps it verbatim)."""
-    if isinstance(value, bool) or value is None:
+    if value is None or type(value) in _PLAIN:  # the common case, first
         return value
-    if isinstance(value, (int, float)):
+    if isinstance(value, (bool, int, float)):
         return value
     if isinstance(value, (bytes, bytearray, memoryview)):
         return f"<{len(value)} bytes>"
@@ -211,37 +214,48 @@ class Tracer:
         """Record a finished span explicitly (the scheduler/fabric path:
         stage boundaries are known timestamps, not ``with`` scopes).
         Returns the new span id, usable as a later stage's parent."""
-        now = time.monotonic()
-        t0 = now if t0 is None else t0
-        t1 = max(t0, now if t1 is None else t1)
+        if t0 is None or t1 is None:
+            now = time.monotonic()
+            t0 = now if t0 is None else t0
+            t1 = now if t1 is None else t1
+        if t1 < t0:
+            t1 = t0
         clean = {k: _clean_attr(v) for k, v in attrs.items()}
+        # id and store under one acquisition, and no clock read where
+        # both stamps came with the call: a bridge request adds a dozen
+        # of these on the serving loop
         with self._lock:
             span_id = self._span_id()
-        self._store(Span(trace_id, span_id, parent_id, name, t0, t1, status, clean))
+            self._store_locked(
+                Span(trace_id, span_id, parent_id, name, t0, t1, status, clean)
+            )
         return span_id
 
     # ----------------------------------------------------------- store
 
     def _store(self, span: Span) -> None:
         with self._lock:
-            self.spans_total += 1
-            spans = self._traces.get(span.trace_id)
-            if spans is None:
-                spans = self._traces[span.trace_id] = []
-                while len(self._traces) > self._max_traces:
-                    evicted, _ = self._traces.popitem(last=False)
-                    self._dropped.pop(evicted, None)
-            else:
-                self._traces.move_to_end(span.trace_id)
-            if len(spans) >= self._max_spans:
-                # keyed only by traces live in _traces and popped when
-                # they evict — cardinality rides the trace ring's cap
-                self._dropped[span.trace_id] = (  # bounded-by: _max_traces
-                    self._dropped.get(span.trace_id, 0) + 1
-                )
-            else:
-                spans.append(span)
-            self._recent.append(span)
+            self._store_locked(span)
+
+    def _store_locked(self, span: Span) -> None:
+        self.spans_total += 1
+        spans = self._traces.get(span.trace_id)
+        if spans is None:
+            spans = self._traces[span.trace_id] = []
+            while len(self._traces) > self._max_traces:
+                evicted, _ = self._traces.popitem(last=False)
+                self._dropped.pop(evicted, None)
+        else:
+            self._traces.move_to_end(span.trace_id)
+        if len(spans) >= self._max_spans:
+            # keyed only by traces live in _traces and popped when
+            # they evict — cardinality rides the trace ring's cap
+            self._dropped[span.trace_id] = (  # bounded-by: _max_traces
+                self._dropped.get(span.trace_id, 0) + 1
+            )
+        else:
+            spans.append(span)
+        self._recent.append(span)
 
     # ---------------------------------------------------------- output
 

@@ -44,7 +44,8 @@ bytes, and shed counts are exported via ``utils/metrics.py``
 the same lifecycle: always-on log2 latency histograms (queue wait,
 launch, per-tenant end-to-end) feed ``/metrics`` as real Prometheus
 histograms, traced submissions get per-stage spans (enqueue →
-admission/shed → lane wait → launch/retry/bisect → digest → verdict),
+admission/shed → lane wait → launch/retry/bisect → digest → verdict,
+and for a ``submit()`` caller → wake: how late the loop ran it again),
 the flight recorder dumps a black box on breaker-open and
 retry-exhausted failures, and device launches are annotated in the
 deep-dive profiler timeline via ``obs/profiler.py``. The pipeline
@@ -54,7 +55,11 @@ puts (h2d), launches, D2H fetches, and the verdict demux — feeding the
 bottleneck attributor behind ``GET /v1/pipeline`` and ``doctor
 --bottleneck``; each stage entry is also a host span in the profiler's
 trace, and a lane's waits (idle, flush deadline, pipeline semaphore)
-are recorded beside the stages, never among them.
+are recorded beside the stages, never among them; so is ``verdict_wake``,
+``submit()``'s word on the loop's lag, after the fact and without a span.
+``metrics_snapshot()`` carries the sum and count of the queue-wait and
+e2e histogram families (``queue_wait_s_sum`` / ``_pieces``, ``e2e_s_sum``
+/ ``e2e_pieces``), so that a delta of two snapshots makes a mean.
 
 Failure domains. A launch exception must not fail every co-batched
 ticket across all tenants, so dispatch is fault-isolated in two layers:
@@ -306,7 +311,7 @@ class _Submission:
     """
 
     __slots__ = ("mode", "results", "remaining", "future", "trace",
-                 "traced_done", "flush")
+                 "traced_done", "flush", "t_resolved")
 
     def __init__(
         self, n: int, mode: str, loop: asyncio.AbstractEventLoop,
@@ -324,15 +329,25 @@ class _Submission:
         # across launches whose halves fail separately must not get one
         # span per failing demux)
         self.traced_done = False
+        # the instant the future got its result or its exception (on the
+        # loop thread, in the demux): ``submit()`` measures from here how
+        # late the loop ran the waiting caller again
+        self.t_resolved: float | None = None
 
     def deliver(self, idx: int, value) -> None:
         self.results[idx] = value
         self.remaining -= 1
         if self.remaining == 0 and not self.future.done():
+            self.t_resolved = time.monotonic()
             if self.mode == "verify":
                 self.future.set_result(bytes(self.results))
             else:
                 self.future.set_result(self.results)
+
+    def fail(self, error: BaseException) -> None:
+        if not self.future.done():
+            self.t_resolved = time.monotonic()
+            self.future.set_exception(error)
 
 
 class _Ticket:
@@ -1741,6 +1756,16 @@ class HashPlaneScheduler:
         caller's own next step, not a setting: everyone else's
         submissions still wait for fill or ``flush_deadline``.
         """
+        sub = await self._enqueue_sub(
+            tenant, pieces, expected, algo, piece_length, wait, flush
+        )
+        return sub.future
+
+    async def _enqueue_sub(
+        self, tenant, pieces, expected, algo, piece_length, wait, flush
+    ) -> _Submission:
+        """:meth:`enqueue`'s body; the submission itself is what
+        :meth:`submit` needs back (its ``t_resolved``, its trace)."""
         if algo not in DIGEST_LEN:
             raise ValueError(f"unknown algo {algo!r}")
         mode = "digest" if expected is None else "verify"
@@ -1750,7 +1775,7 @@ class HashPlaneScheduler:
         sub = _Submission(len(pieces), mode, loop, flush)
         if not pieces:
             sub.future.set_result(b"" if mode == "verify" else [])
-            return sub.future
+            return sub
         # span context captured HERE (the caller's task still holds it);
         # everything downstream runs in lane tasks / worker threads
         ctx = tracer().current_context()
@@ -1818,7 +1843,7 @@ class HashPlaneScheduler:
             # later stages (lane wait, launch, digest) hang off the
             # enqueue span — carried by the submission, not contextvars
             sub.trace = (ctx[0], enq_id)
-        return sub.future
+        return sub
 
     async def enqueue_staged(
         self,
@@ -1861,9 +1886,31 @@ class HashPlaneScheduler:
 
     async def submit(self, tenant: str, pieces, expected=None, algo="sha1",
                      piece_length=None, wait: bool = False):
-        """``enqueue`` + await: returns digests (or ok-bytes) directly."""
-        fut = await self.enqueue(tenant, pieces, expected, algo, piece_length, wait)
-        return await fut
+        """``enqueue`` + await: returns digests (or ok-bytes) directly.
+
+        The one caller that can say how late the loop woke it: the
+        submission stamps the instant the demux resolved it, and the
+        time from there to this coroutine running again is the ledger
+        wait ``verdict_wake`` (recorded after the fact: no span) and the
+        span ``sched.wake`` of a traced request. Callers of
+        :meth:`enqueue` await the future themselves and record nothing.
+        """
+        sub = await self._enqueue_sub(
+            tenant, pieces, expected, algo, piece_length, wait, False
+        )
+        try:
+            return await sub.future
+        finally:
+            if sub.t_resolved is not None:
+                t_woke = time.monotonic()
+                pipeline_ledger().record(
+                    "verdict_wake", 0, t_woke - sub.t_resolved, wait=True
+                )
+                if sub.trace is not None:
+                    tracer().add_span(
+                        sub.trace[0], "sched.wake", parent_id=sub.trace[1],
+                        t0=sub.t_resolved, t1=t_woke,
+                    )
 
     async def _admit(self, ts: _Tenant, nbytes: int, wait: bool) -> None:
         cfg = self.config
@@ -2352,8 +2399,7 @@ class HashPlaneScheduler:
             self._queued_bytes -= tkt.charged
             e2e_by_tenant.setdefault(tkt.tenant, []).append(t_now - tkt.ts)
             if error is not None:
-                if not tkt.sub.future.done():
-                    tkt.sub.future.set_exception(error)
+                tkt.sub.fail(error)
                 if tkt.sub.trace is not None:
                     done_subs.setdefault(id(tkt.sub), tkt.sub)
                 continue
@@ -2431,6 +2477,12 @@ class HashPlaneScheduler:
         _, queue_wait_pieces, queue_wait_s_sum = histograms().family_snapshot(
             _H_QUEUE_WAIT[0]
         ) or (None, 0, 0.0)
+        # enqueue-to-verdict likewise (a ticket's enqueue → its demux):
+        # less the wait above it is the time inside a launch, assembly
+        # and the hop back to the loop included
+        _, e2e_pieces, e2e_s_sum = histograms().family_snapshot(_H_E2E[0]) or (
+            None, 0, 0.0,
+        )
         return {
             "queue_pieces": pending,
             "queue_bytes": self._queued_bytes,
@@ -2440,6 +2492,8 @@ class HashPlaneScheduler:
             "launches": self._launches,
             "queue_wait_s_sum": queue_wait_s_sum,
             "queue_wait_pieces": queue_wait_pieces,
+            "e2e_s_sum": e2e_s_sum,
+            "e2e_pieces": e2e_pieces,
             "fill_sum": self._fill_sum,
             "mean_fill": (self._fill_sum / self._launches) if self._launches else 0.0,
             "flush_reasons": dict(self._flush_reasons),

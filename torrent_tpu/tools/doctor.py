@@ -914,7 +914,7 @@ async def _trace_smoke() -> str:
 
     got = [n for root in tree["spans"] for n in names(root)]
     for stage in ("sched.enqueue", "sched.admission", "sched.lane_wait",
-                  "sched.launch", "sched.digest"):
+                  "sched.launch", "sched.digest", "sched.wake"):
         assert stage in got, f"span tree missing {stage}: {got}"
     rendered = histograms().render()
     for family in ("torrent_tpu_sched_queue_wait_seconds",

@@ -209,6 +209,8 @@ def test_no_shard_in_flight_aliases_a_staging_slab(devices, monkeypatch):
         return out
 
     monkeypatch.setattr(verifier, "alloc_padded", aligned)
+    # a keeper that holds nothing yet, so the pass allocates its pair here
+    monkeypatch.setattr(verifier, "_staging_pair", verifier._StagingPair())
     monkeypatch.setattr(verifier.TPUVerifier, "_put_sharded", put_sharded)
     mesh = make_mesh(jax.devices()[:devices])
     bits = verify_pieces(storage, info, hasher="tpu", batch_size=batch, mesh=mesh)

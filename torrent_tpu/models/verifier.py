@@ -249,6 +249,94 @@ def step_cache_stats() -> dict[str, int]:
     return _step_cache.stats()
 
 
+# The most host memory a process keeps as a recheck's staging pair, both
+# slabs together. The CLI's ``--batch 256`` at 256 KiB pieces asks for
+# 2 x 67 MB and ``--batch 1024`` for 2 x 269 MB; ``--batch 4096`` at
+# 1 MiB would pin 8.6 GB, so a pair over this is allocated a pass.
+STAGING_KEEP_BYTES = 1 << 30
+
+
+class _StagingPair:
+    """The process's two staging slabs of a recheck, and the counters of
+    their use.
+
+    ``verify_storage`` fills one padded slab while the device consumes
+    the other. Allocated anew a pass, each slab's first fill is paid in
+    page faults (on four chips 0.2 s of ``first_load`` and 0.16 s of the
+    first ``read_wait`` in a 0.60 s pass; PERF.md §6, PR 35), so one pair
+    is kept, as ``_step_cache`` keeps the jitted steps and
+    ``models.v2._LeafSlab`` the leaf slab. A pass of the same row width
+    and no more rows takes the C-contiguous row prefix of the same
+    pages; another width or more rows replaces the pair, the old one
+    freed first, so a process holds one pair at most, and none over
+    ``STAGING_KEEP_BYTES``. Nothing is zeroed here: ``load()`` makes
+    every row of every batch sound before it is launched (``read_batch``
+    zero-fills the rows it reads into; the pad columns and the rows past
+    the last piece are cleared), so by then a kept slab holds nothing of
+    an earlier pass.
+
+    Checked out for one ``verify_storage`` call and back in at its end,
+    under one lock. A caller that finds the pair out (two rechecks at
+    once in a session) gets a transient pair and never waits."""
+
+    def __init__(self):
+        self._lock = named_lock("models.verifier._staging_lock")
+        self._cells = guard_attrs("models.verifier.staging", "pair")
+        self._pair: list[np.ndarray] | None = None  # bounded-by: STAGING_KEEP_BYTES
+        self._out = False
+        self._counts = {
+            "staging_slab_allocs": 0, "staging_slab_reuses": 0, "staging_slab_transient": 0,
+        }
+
+    def checkout(
+        self, rows: int, piece_length: int
+    ) -> tuple[list[tuple[np.ndarray, np.ndarray]], bool]:
+        """``([(padded, view), (padded, view)], kept)``: two slabs of
+        :func:`alloc_padded`'s shape for the caller alone, holding
+        whatever the last pass left. ``kept`` says they are the kept
+        pair's, and the caller then owes a :meth:`checkin` once nothing
+        reads or writes them any more."""
+        width = padded_len_for(piece_length)
+        with self._lock:
+            self._cells.write("pair")
+            if self._out or 2 * rows * width > STAGING_KEEP_BYTES:
+                self._counts["staging_slab_transient"] += 1
+                return [alloc_padded(rows, piece_length) for _ in range(2)], False
+            pair = self._pair
+            if pair is not None and pair[0].shape[1] == width and pair[0].shape[0] >= rows:
+                self._counts["staging_slab_reuses"] += 1
+            else:
+                self._pair = pair = None  # the old pair goes before the new one comes
+                # calloc'd: a page is first touched by the read that fills it
+                self._pair = pair = [alloc_padded(rows, piece_length)[0] for _ in range(2)]
+                self._counts["staging_slab_allocs"] += 1
+            self._out = True
+            return [(p[:rows], p[:rows, :piece_length]) for p in pair], True
+
+    def checkin(self) -> None:
+        with self._lock:
+            self._cells.write("pair")
+            self._out = False
+
+    def stats(self) -> dict[str, int]:
+        with self._lock:
+            self._cells.read("pair")
+            return dict(self._counts)
+
+
+_staging_pair = _StagingPair()
+
+
+def staging_slab_stats() -> dict[str, int]:
+    """``staging_slab_allocs`` / ``_reuses`` / ``_transient``: recheck
+    passes whose check-out allocated or replaced the process's kept
+    staging pair, found it large enough, or took a transient pair (the
+    kept one was out, or the pair asked for is over
+    ``STAGING_KEEP_BYTES``). Rendered by ``/metrics``
+    (utils/metrics.py)."""
+    return _staging_pair.stats()
+
+
 class TPUVerifier:
     def __init__(
         self,
@@ -625,6 +713,7 @@ class TPUVerifier:
 
         loader = ThreadPoolExecutor(max_workers=1)
         io_pool = ThreadPoolExecutor(max_workers=stripes) if stripes > 1 else None
+        kept = False
         try:
             with led.track("pass_setup"):
                 expected_all = digests_to_words(info.pieces)
@@ -632,9 +721,12 @@ class TPUVerifier:
                 # device consumes the other (the TPU analogue of the
                 # reference's Promise.all hashing pipeline,
                 # tools/make_torrent.ts:96-111). ``io_threads`` stripes
-                # each batch's disk reads in parallel.
+                # each batch's disk reads in parallel. They are the
+                # process's kept pair where it is in and fits
+                # (_StagingPair), so their pages are warm from the
+                # second pass on.
                 with annotate("alloc_staging"):
-                    staging = [alloc_padded(b, plen) for _ in range(2)]
+                    staging, kept = _staging_pair.checkout(b, plen)
                 t0 = time.perf_counter()
                 fut = loader.submit(load, 0, 0)
                 with annotate("first_load"):
@@ -690,7 +782,14 @@ class TPUVerifier:
         finally:
             loader.shutdown(wait=True)
             if io_pool is not None:
-                io_pool.shutdown(wait=False)
+                # waited for, since the slabs outlive the pass: a load
+                # that failed on one stripe leaves its others reading
+                io_pool.shutdown(wait=True)
+            # the kept pair goes back only now: no thread fills a slab,
+            # and no device array aliases one (a put has copied it by
+            # the time it returns, on the CPU backend too)
+            if kept:
+                _staging_pair.checkin()
         self.last_result = VerifyResult(
             bitfield=bitfield,
             n_pieces=n,

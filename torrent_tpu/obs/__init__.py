@@ -131,8 +131,9 @@ def render_obs_metrics() -> str:
     the swarm wire-plane families (``torrent_tpu_swarm_*`` + bounded
     ``torrent_tpu_peer_*``), the seeder plane's ``torrent_tpu_serve_*``
     (only once this process has actually served — tracker-only scrapes
-    stay lean), the jitted SHA-1 steps' build and reuse counters (only
-    once this process has imported the verifier, and so JAX), the v2
+    stay lean), the jitted SHA-1 steps' build and reuse counters and a
+    recheck's kept staging pair's (only once this process has imported
+    the verifier, and so JAX), the v2
     leaf plane's launch, row and slab counters (once ``models/v2`` is in), and the
     flight-recorder dump counters. Appended by both the bridge's
     ``/metrics`` and the session ``MetricsServer``."""
@@ -141,6 +142,7 @@ def render_obs_metrics() -> str:
         render_leaf_metrics,
         render_leaf_slab_metrics,
         render_serve_metrics,
+        render_staging_slab_metrics,
         render_step_metrics,
         render_swarm_metrics,
     )
@@ -152,6 +154,7 @@ def render_obs_metrics() -> str:
         histograms().render()
         + render_pipeline_metrics()
         + (render_step_metrics(verifier.step_cache_stats()) if verifier else "")
+        + (render_staging_slab_metrics(verifier.staging_slab_stats()) if verifier else "")
         + (render_leaf_metrics(v2.leaf_launch_stats()) if v2 else "")
         + (render_leaf_slab_metrics(v2.leaf_slab_stats()) if v2 else "")
         + render_swarm_metrics(swarm_telemetry().snapshot())

@@ -213,6 +213,26 @@ def render_step_metrics(stats: dict) -> str:
     )
 
 
+def render_staging_slab_metrics(stats: dict) -> str:
+    """Prometheus rendering of a recheck's kept staging pair.
+
+    ``stats`` is ``torrent_tpu.models.verifier.staging_slab_stats()``. A
+    long-lived process that rechecks a torrent a call should show
+    reuses rising by one a call; allocs rising with the calls means
+    geometries alternate, transient that rechecks overlap or ask for a
+    pair over ``STAGING_KEEP_BYTES``: every such pass faults its two
+    slabs in again."""
+    lines = []
+    for key, text in (
+        ("staging_slab_allocs", "Recheck passes that allocated or replaced the process's kept staging pair"),
+        ("staging_slab_reuses", "Recheck passes that found the kept staging pair large enough"),
+        ("staging_slab_transient", "Recheck passes that took a transient staging pair: the kept one was out, or the pair is over the cap"),
+    ):
+        name = f"torrent_tpu_verifier_{key}_total"
+        lines += [f"# HELP {name} {text}", f"# TYPE {name} counter", f"{name} {stats[key]}"]
+    return "\n".join(lines) + "\n"
+
+
 def render_leaf_metrics(stats: dict) -> str:
     """Prometheus rendering of the v2 leaf plane's launch counters.
 

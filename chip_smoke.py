@@ -10,8 +10,8 @@ points a user calls — the two Pallas kernels at their published
 geometries, the library flow (author → parse → recheck → corrupt →
 recheck) at the two reference fixture geometries, the HTTP bridge, BEP 52
 authoring and recheck, on a multi-chip host the sharded recheck, and a
-seed-to-leech swarm transfer over localhost whose pieces are verified in
-device micro-batches — and checks every answer against ``hashlib``.
+seed-to-leech swarm transfer over localhost whose pieces are judged on the
+leecher's ingest scheduler — and checks every answer against ``hashlib``.
 
 Stdout is two lines, each one JSON object. The first is the report: the
 versions, the compile-cache directory, and per phase ``ok`` / wall seconds
@@ -607,13 +607,18 @@ def phase_shards(ctx) -> dict:
     }
 
 
-def _ingest_flushes() -> dict:
+def _ingest_flushes(s: dict) -> dict:
+    """How the leecher's downloaded pieces were judged so far, from its
+    ingest scheduler's snapshot: launches of the device planes, and every
+    road to hashlib (a failed submission the session judged itself, a
+    launch of a lane degraded to the hashlib plane)."""
     from torrent_tpu.obs.hist import histograms
     from torrent_tpu.session.torrent import _H_INGEST_VERIFY
 
+    fell_back = histograms().get(*_H_INGEST_VERIFY, plane="hashlib_fallback").snapshot()[1]
     return {
-        k: histograms().get(*_H_INGEST_VERIFY, plane=k).snapshot()[1]
-        for k in ("device", "hashlib_fallback")
+        "device": s["launches"] - s["cpu_fallback_launches"],
+        "hashlib_fallback": fell_back + s["cpu_fallback_launches"],
     }
 
 
@@ -642,8 +647,6 @@ async def _session(ctx, tmp) -> dict:
             piece_length=plen,
         )
     )
-    before = _ingest_flushes()
-
     def client():
         kw = {} if verify_batch is None else {"verify_batch_size": verify_batch}
         return Client(
@@ -656,6 +659,7 @@ async def _session(ctx, tmp) -> dict:
     seed, leech = client(), client()
     await seed.start()
     await leech.start()
+    before = _ingest_flushes(leech.ingest_scheduler.metrics_snapshot())
     try:
         # the seed's add() rechecks its directory on the device (no resume
         # file yet) and must come up seeding every piece
@@ -665,6 +669,8 @@ async def _session(ctx, tmp) -> dict:
         assert t_leech.verifier is not None and t_leech.bitfield.count() == 0
         await asyncio.wait_for(t_leech.on_complete.wait(), 600)
         verifier = t_leech.verifier
+        ingest = leech.ingest_scheduler.metrics_snapshot()
+        flushes = {k: v - before[k] for k, v in _ingest_flushes(ingest).items()}
     finally:
         await seed.close()
         await leech.close()
@@ -673,9 +679,14 @@ async def _session(ctx, tmp) -> dict:
     # what the leech wrote is the payload, piece for piece, by hashlib
     on_disk = verify_pieces(Storage(FsStorage(leech_dir), meta.info), meta.info, hasher="cpu")
     assert on_disk.all(), f"leech wrote {int((~on_disk).sum())} bad pieces"
-    flushes = {k: v - before[k] for k, v in _ingest_flushes().items()}
-    assert flushes["device"] > 0, "no ingest micro-batch reached the device"
+    # every downloaded piece went through the leecher's scheduler (tenant
+    # `ingest`, one more for the lane's warm-up), on device launches alone
+    judged = ingest["tenants"]["ingest"]["served_pieces"] - 1
+    assert judged >= meta.info.num_pieces, f"{judged} of {meta.info.num_pieces} pieces judged on the scheduler"
+    assert flushes["device"] > 1, "no ingest launch reached the device"
     assert flushes["hashlib_fallback"] == 0, flushes
+    kernels = {lane["kernel"] for lane in ingest["lane_stats"].values()}
+    assert "hashlib" not in kernels, kernels
     return {
         "bytes": 2 * total,  # the seed's recheck + the leech's ingest
         "pieces": meta.info.num_pieces,
@@ -683,12 +694,14 @@ async def _session(ctx, tmp) -> dict:
         "verify_batch_size": verifier.batch_size,
         "backend": verifier.backend,
         "ingest_flushes": flushes,
+        "ingest_pieces_per_launch": round(judged / (flushes["device"] - 1), 2),
     }
 
 
 def phase_session(ctx) -> dict:
     """One seed, one leech, a tracker, all on localhost in this process:
-    every piece the leech completes is verified in device micro-batches."""
+    every piece the leech completes is judged on its ingest scheduler's
+    device lane, none by hashlib."""
     with tempfile.TemporaryDirectory(prefix="chip_smoke_swarm_") as tmp:
         return asyncio.run(asyncio.wait_for(_session(ctx, tmp), 900))
 

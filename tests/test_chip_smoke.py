@@ -52,8 +52,8 @@ def test_cpu_dry_run_passes_every_phase():
     assert result["phases"]["2_library"]["multi"]["recheck_backends"] == ["jax", "pallas"]
     assert result["phases"]["5_shards"]["sharded_recheck_devices"] == 4
     assert result["fallbacks"]["sched_cpu_fallback_launches"] == 0
-    # the session's hashlib fallback reads zero from a phase that flushed
-    # ingest micro-batches, not from a counter nothing touched
+    # the session's hashlib fallback reads zero from a phase whose pieces
+    # were launched on the ingest scheduler, not from a counter nothing touched
     flushes = result["phases"]["6_session"]["ingest_flushes"]
     assert flushes["device"] > 0 and flushes["hashlib_fallback"] == 0
     assert result["fallbacks"]["session_ingest_hashlib_fallbacks"] == 0

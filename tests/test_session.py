@@ -346,8 +346,8 @@ class TestReviewRegressions:
 
 
 class TestTpuIngestVerify:
-    """Completed pieces verified through the batched hash plane during a
-    live swarm transfer (hasher='tpu'), not just at resume-recheck."""
+    """Completed pieces verified through the client's hash-plane scheduler
+    during a live swarm transfer (hasher='tpu'), not just at resume-recheck."""
 
     def test_seed_to_leech_with_tpu_hasher(self, tmp_path):
         from torrent_tpu.models.verifier import TPUVerifier
@@ -377,6 +377,7 @@ class TestTpuIngestVerify:
                 assert t_seed.state == TorrentState.SEEDING
                 t_leech = await leech.add(m, Storage(MemoryStorage(), m.info))
                 assert t_leech.verifier is not None
+                assert t_leech.ingest_scheduler is leech.ingest_scheduler is not None
                 await asyncio.wait_for(t_leech.on_complete.wait(), timeout=30)
                 assert t_leech.storage.get(0, len(payload)) == payload
             finally:
@@ -387,25 +388,39 @@ class TestTpuIngestVerify:
 
         run(go())
 
-    def test_batched_verify_flags_corrupt_piece(self):
-        """Direct micro-batch check: good pieces pass, corrupt fails, and
-        concurrent finishers share one flush."""
-        from torrent_tpu.models.verifier import TPUVerifier
+    def test_concurrent_finishers_share_one_launch_and_corrupt_is_flagged(self):
+        """Direct check of the ingest road: good pieces pass, corrupt
+        fails, and pieces that finish together ride one scheduler launch
+        of the tenant ``ingest`` (each submission carries the flush hint,
+        so nobody sits out the deadline)."""
+        from torrent_tpu.sched import HashPlaneScheduler, SchedulerConfig
 
         async def go():
             t, payload = TestSchedulerUnits().make_torrent(payload_len=4 * 32768)
-            t.verifier = TPUVerifier(piece_length=32768, batch_size=4, backend="jax")
+            sched = await HashPlaneScheduler(
+                SchedulerConfig(batch_target=4, flush_deadline=5.0), hasher="tpu"
+            ).start()
+            t.ingest_scheduler = sched
             t.config.hasher = "tpu"
             datas = [payload[i * 32768 : (i + 1) * 32768] for i in range(3)]
             corrupt = bytearray(datas[1])
             corrupt[0] ^= 0xFF
-            results = await asyncio.gather(
-                t._verify_piece_data(0, datas[0], t.info.pieces[0]),
-                t._verify_piece_data(1, bytes(corrupt), t.info.pieces[1]),
-                t._verify_piece_data(2, datas[2], t.info.pieces[2]),
-            )
+            try:
+                results = await asyncio.wait_for(
+                    asyncio.gather(
+                        t._verify_piece_data(0, datas[0], t.info.pieces[0]),
+                        t._verify_piece_data(1, bytes(corrupt), t.info.pieces[1]),
+                        t._verify_piece_data(2, datas[2], t.info.pieces[2]),
+                    ),
+                    30,
+                )
+                snap = sched.metrics_snapshot()
+            finally:
+                await sched.close()
             assert results == [True, False, True]
-            assert t._verify_pending == [] and not t._verify_flushing
+            assert snap["launches"] == 1 and snap["flush_reasons"]["hint"] == 1
+            assert snap["tenants"]["ingest"]["served_pieces"] == 3
+            assert t._verify_pending == [] and not t._verify_flushing  # the v2 micro-batch was not the road
 
         run(go())
 

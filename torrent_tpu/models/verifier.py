@@ -592,10 +592,14 @@ class TPUVerifier:
     # ------------------------------------------------------------ authoring
 
     def hash_pieces(self, pieces: list[bytes]) -> list[bytes]:
-        """SHA1 digests for a ragged list of pieces (authoring path).
+        """SHA1 digests for a ragged list of pieces: the authoring path
+        (``tools/make_torrent.py``, the v2 hybrid's v1 pieces, ``doctor``)
+        and nothing else — a download's pieces are judged on the client's
+        hash-plane scheduler, whose launches follow what finished together.
 
         Chunks into fixed ``batch_size`` launches so one executable serves
-        any piece count; rows are padded with ``nblocks=0`` sentinels.
+        any piece count; rows are padded with ``nblocks=0`` sentinels, and
+        every launch allocates, uploads and hashes a whole batch.
         """
         if not pieces:
             return []

@@ -94,7 +94,11 @@ span, because some fifteen requests sit in one at once): ``http_head``
 (accept → headers parsed), ``http_body`` (→ the body read),
 ``verdict_wake`` (a submission resolved → ``submit()`` running again)
 and the whole, ``http_request`` (accept → the reply written and the
-socket closed).
+socket closed). A download's peer loops likewise: ``ingest_verdict_wait``
+(``session/torrent.py:_finish_piece``: a finished piece put to the judge
+→ its verdict back, one entry a piece; the loop that delivered the last
+block requests nothing meanwhile, and every peer's loop may sit there at
+once).
 
 The ledger also integrates cross-stage occupancy overlap — wall
 seconds with ≥2 distinct stages simultaneously busy and the

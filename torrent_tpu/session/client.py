@@ -2,8 +2,10 @@
 
 Owns the listening socket and peer identity, routes inbound handshakes to
 torrents by info hash *before* replying so unknown torrents are dropped
-silently (client.ts:85-104), and shares one TPUVerifier across torrents
-when the 'tpu' hasher is selected.
+silently (client.ts:85-104). With the 'tpu' hasher it owns (or is given)
+one ``HashPlaneScheduler`` that judges every v1 piece its torrents
+download, and keeps one TPUVerifier a piece length for their resume
+rechecks.
 
 Fixed vs the reference: config defaults are copied per-instance instead
 of mutating a shared defaults object (client.ts:47, SURVEY §8.2), and the
@@ -45,7 +47,9 @@ class ClientConfig:
     hasher: str = "cpu"  # 'cpu' | 'tpu' piece verification (BASELINE API)
     # Shared hash-plane scheduler (torrent_tpu.sched): when set, every
     # torrent's resume/self-heal recheck submits to this queue as a
-    # low-priority tenant instead of dispatching private device batches
+    # low-priority tenant instead of dispatching private device batches,
+    # and a hasher='tpu' client's downloaded pieces are judged on it too
+    # (tenant "ingest") instead of on a scheduler of the client's own
     scheduler: object | None = None
     torrent: TorrentConfig = field(default_factory=TorrentConfig)
     enable_upnp: bool = False  # optional, off by default (SURVEY §7.8)
@@ -96,6 +100,12 @@ class Client:
         self.torrents: dict[bytes, Torrent] = {}
         self._server: asyncio.AbstractServer | None = None
         self._verifier_cache: dict[int, object] = {}
+        # hasher='tpu': the scheduler downloaded v1 pieces are judged on
+        # (start() builds one unless the config brought one) and the
+        # piece lengths whose lane has had its warm-up launch
+        self.ingest_scheduler = None
+        self._owns_ingest_scheduler = False
+        self._warmed_lanes: set[int] = set()
         self.external_ip: str | None = None
         self.port: int | None = None  # assigned by start()
         self.dht = None  # net.dht.DHTNode when enable_dht
@@ -176,6 +186,15 @@ class Client:
             self._accept, self.config.host, self.config.port
         )
         self.port = self._server.sockets[0].getsockname()[1]
+        if self.config.hasher == "tpu":
+            given = self.config.scheduler or self.config.torrent.scheduler
+            if given is None:
+                from torrent_tpu.sched import HashPlaneScheduler
+
+                given = await HashPlaneScheduler(hasher="tpu").start()
+                self._owns_ingest_scheduler = True
+            given.register_tenant("ingest")
+            self.ingest_scheduler = given
         if self.config.enable_upnp:
             # before DHT: a learned external IP lets the DHT node mint a
             # BEP 42-compliant id at construction
@@ -325,6 +344,13 @@ class Client:
         for torrent in list(self.torrents.values()):
             await torrent.stop()
         self.torrents.clear()
+        if self._owns_ingest_scheduler:
+            # after the torrents: a piece still awaiting its verdict is
+            # answered (flush reason "shutdown"), never left pending
+            await self.ingest_scheduler.close()
+            self._owns_ingest_scheduler = False
+        self.ingest_scheduler = None
+        self._warmed_lanes.clear()
         if self.lsd is not None:
             self.lsd.close()
             self.lsd = None
@@ -353,7 +379,9 @@ class Client:
     # ------------------------------------------------------------ torrents
 
     def _verifier_for(self, piece_length: int):
-        """One shared TPUVerifier per piece geometry (compiled once)."""
+        """One shared TPUVerifier per piece geometry (compiled once), for
+        the torrents' resume rechecks (``Torrent.recheck``); downloaded
+        pieces go to the ingest scheduler."""
         if self.config.hasher != "tpu":
             return None
         v = self._verifier_cache.get(piece_length)
@@ -366,6 +394,24 @@ class Client:
             )
             self._verifier_cache[piece_length] = v
         return v
+
+    async def _warm_ingest_lane(self, piece_length: int) -> None:
+        """One launch on the lane of ``piece_length`` before a torrent of
+        it can finish a piece: the lane's first launch builds its plane,
+        which compiles and runs every rung of the row ladder (in the
+        scheduler's worker thread), so no download meets a compile."""
+        bucket = self.ingest_scheduler.bucket_for(piece_length)
+        if bucket in self._warmed_lanes:
+            return
+        self._warmed_lanes.add(bucket)
+        try:
+            fut = await self.ingest_scheduler.enqueue(
+                "ingest", [bytes(piece_length)], algo="sha1",
+                piece_length=piece_length, wait=True, flush=True,
+            )
+            await fut
+        except Exception as e:  # the first piece meets the same fault, and falls back
+            log.warning("ingest lane warm-up failed (%s)", e)
 
     async def add(
         self,
@@ -418,17 +464,21 @@ class Client:
                 else self.config.torrent.scheduler
             ),
         )
+        # the shared TPUVerifier and the ingest scheduler's lanes are the
+        # SHA-1 plane — v2 pieces verify against merkle roots instead
+        # (session/torrent.py v2 branch)
+        v1 = not getattr(metainfo.info, "v2", False)
+        ingest_sched = self.ingest_scheduler if v1 else None
+        if ingest_sched is not None:
+            await self._warm_ingest_lane(metainfo.info.piece_length)
         torrent = Torrent(
             metainfo=metainfo,
             storage=storage,
             peer_id=self.config.peer_id,
             port=self.external_port or self.port,
             config=torrent_config,
-            # the shared TPUVerifier is the SHA-1 plane — v2 pieces verify
-            # against merkle roots instead (session/torrent.py v2 branch)
-            verifier=None
-            if getattr(metainfo.info, "v2", False)
-            else self._verifier_for(metainfo.info.piece_length),
+            verifier=self._verifier_for(metainfo.info.piece_length) if v1 else None,
+            ingest_scheduler=ingest_sched,
             resume_store=resume_store,
             dht=self.dht,
             upload_bucket=self.upload_bucket,

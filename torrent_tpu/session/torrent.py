@@ -13,8 +13,10 @@ This is the completed design:
 - **choke policy**: periodic round unchoking the top downloaders plus one
   optimistic random peer (BEP 3 semantics).
 - **verification hook** (the gap at torrent.ts:183-193): pieces assemble
-  in memory, SHA1-verify off-thread (or batched on TPU via the hash
-  plane), and only verified pieces are written + ``have``-broadcast.
+  in memory, SHA1-verify off-thread (hasher 'tpu': one submission a
+  piece to the client's shared ``HashPlaneScheduler``, tenant
+  ``ingest``, where pieces that finished together share a launch), and
+  only verified pieces are written + ``have``-broadcast.
 - **resume-recheck**: ``start()`` runs ``verify_pieces`` (hasher
   'cpu'|'tpu') to rebuild the bitfield before announcing — the subsystem
   the reference lists as roadmap (README.md:34) and the BASELINE north
@@ -59,12 +61,18 @@ log = get_logger("session.torrent")
 
 _UNSET = object()  # lazy-field sentinel (None is a meaningful value)
 
-# live-ingest micro-batch verify wall time by plane; the per-plane counts
-# are how a silent drop from the device to hashlib stays visible
+# live-ingest verify wall time by plane: a piece's submission to the
+# scheduler (v1) or a v2 micro-batch; the per-plane counts are how a
+# silent drop from the device to hashlib stays visible
 _H_INGEST_VERIFY = (
     "torrent_tpu_ingest_verify_seconds",
-    "ingest verify micro-batch wall time by plane (device | hashlib_fallback)",
+    "ingest verify wall time by plane (device | hashlib_fallback)",
 )
+
+# the ledger wait a finished piece's verify is: the peer loop (or webseed
+# loop) that delivered the last block awaits the verdict and asks for
+# nothing meanwhile. One entry a piece, on either hasher.
+_INGEST_VERDICT_WAIT = "ingest_verdict_wait"
 
 # recv-stage ledger batching: socket-wait seconds and landed block bytes
 # flush to the pipeline ledger once per this many events (or 250 ms of
@@ -229,7 +237,9 @@ class TorrentConfig:
     # express interest (and bounds eviction thrash).
     evict_grace: float = 15.0
     announce_retry: float = 30.0
-    hasher: str = "cpu"  # 'cpu' | 'tpu' — resume-recheck + batch verify
+    hasher: str = "cpu"  # 'cpu' | 'tpu' — resume-recheck + ingest verify
+    # rows of a resume-recheck's device batch (the client's TPUVerifier);
+    # downloaded pieces launch at the ingest scheduler's own row ladder
     verify_batch_size: int = 256
     # Shared hash-plane scheduler (torrent_tpu.sched.HashPlaneScheduler).
     # When set, resume/self-heal rechecks ride the shared verify queue as
@@ -306,7 +316,8 @@ class Torrent:
         peer_id: bytes,
         port: int,
         config: TorrentConfig | None = None,
-        verifier=None,  # optional TPUVerifier to share across torrents
+        verifier=None,  # optional TPUVerifier to share across torrents (rechecks)
+        ingest_scheduler=None,  # the client's HashPlaneScheduler: v1 ingest verify
         resume_store=None,  # optional session/resume.py store
         dht=None,  # optional net.dht.DHTNode for trackerless discovery
         upload_bucket=None,  # optional utils/ratelimit.TokenBucket (client-global)
@@ -326,6 +337,7 @@ class Torrent:
         self.port = port
         self.config = config or TorrentConfig()
         self.verifier = verifier
+        self.ingest_scheduler = ingest_scheduler
         self.resume_store = resume_store
         self.dht = dht
         self.upload_bucket = upload_bucket
@@ -366,9 +378,14 @@ class Torrent:
             per_ip=self.config.per_ip_limit,
         )
         self._partials: dict[int, _PartialPiece] = {}
-        # TPU ingest-verification micro-batching (see _verify_piece_data)
+        # v2 device ingest-verification micro-batching (see
+        # _verify_piece_data; v1 pieces go to ingest_scheduler)
         self._verify_pending: list = []
         self._verify_flushing = False
+        # ``on_piece_verdict(index, outcome)`` is called once for every
+        # delivery _finish_piece judged ("ok" | "corrupt" | "io_error"),
+        # in the order the verdicts were applied
+        self.on_piece_verdict = None
         self._tasks: set[asyncio.Task] = set()
         # one live fetch loop per webseed/httpseed URL (see
         # _spawn_seed_loops re-entrancy)
@@ -802,6 +819,8 @@ class Torrent:
 
     async def start(self) -> None:
         """Resume from checkpoint or recheck existing data, then join."""
+        # a torrent that has judged no piece yet reads 0 s of it
+        pipeline_ledger().declare_wait(_INGEST_VERDICT_WAIT)
         self.state = TorrentState.CHECKING
         if not self._try_fastresume():
             await self.recheck()
@@ -2746,9 +2765,11 @@ class Torrent:
         webseed loop's per-URL strike counter — must distinguish corrupt
         data from a local disk problem.
 
-        With the TPU hasher, completed pieces from concurrent peers are
-        verified as one device batch (the swarm-ingest face of the hash
-        plane); otherwise per-piece hashlib off-thread.
+        With the TPU hasher a v1 piece is one submission to the client's
+        hash-plane scheduler, where pieces that finished on other peers
+        meanwhile share its launch; otherwise per-piece hashlib
+        off-thread. Either way the caller awaits the verdict here: the
+        ledger wait ``ingest_verdict_wait``, one entry a piece.
         """
         if self._partials.get(partial.index) is not partial:
             # Another path (endgame peer vs webseed) already finished or
@@ -2758,11 +2779,20 @@ class Torrent:
         del self._partials[partial.index]
         data = bytes(partial.buffer)
         expected = self.info.pieces[partial.index]
-        if not await self._verify_piece_data(partial.index, data, expected):
+        t0 = time.monotonic()
+        try:
+            valid = await self._verify_piece_data(partial.index, data, expected)
+        finally:
+            # after the fact and without a span: every peer loop of a
+            # fast swarm may sit here at once (obs/ledger.py, `record`)
+            pipeline_ledger().record(
+                _INGEST_VERDICT_WAIT, len(data), time.monotonic() - t0, wait=True
+            )
+        if not valid:
             log.warning("piece %d failed verification; re-requesting", partial.index)
             self.downloaded -= partial.length  # don't count poisoned data
             self._credit_corruption(partial.contributors)
-            return "corrupt"
+            return self._verdict(partial.index, "corrupt")
         self._absolve(partial.contributors)
         base = partial.index * self.info.piece_length
         try:
@@ -2772,9 +2802,10 @@ class Torrent:
                 await asyncio.to_thread(self._write_piece, base, data)
         except StorageError as e:
             log.error("failed to persist piece %d: %s", partial.index, e)
-            return "io_error"
+            return self._verdict(partial.index, "io_error")
         self.bitfield.set(partial.index)
         self._notify_piece(partial.index)
+        self._verdict(partial.index, "ok")
         if self._piece_priority[partial.index] > 0:
             self._wanted_missing = max(0, self._wanted_missing - 1)
         if self.bitfield.count() % 16 == 0:
@@ -2797,6 +2828,12 @@ class Torrent:
                 pass
         await self._maybe_completed()
         return "ok"
+
+    def _verdict(self, index: int, outcome: str) -> str:
+        """Publish a judged delivery's outcome (``on_piece_verdict``)."""
+        if self.on_piece_verdict is not None:
+            self.on_piece_verdict(index, outcome)
+        return outcome
 
     async def _maybe_completed(self) -> None:
         """Transition to seeding once every *wanted* piece is on disk.
@@ -2879,11 +2916,11 @@ class Torrent:
     # ------------------------------------------------- ingest verification
 
     async def _verify_piece_data(self, index: int, data: bytes, expected: bytes) -> bool:
-        """One piece's hash check, batched onto the TPU when available.
+        """One piece's hash check, on the TPU when available.
 
-        Concurrent finishers pile into ``_verify_pending`` and a single
-        micro-batch flush hashes them all in one device launch; callers
-        await their own piece's future. CPU mode: hashlib off-thread.
+        v1, hasher 'tpu': one submission to the client's hash-plane
+        scheduler (:meth:`_verify_on_scheduler`). CPU mode: hashlib
+        off-thread.
         v2 torrents (session/v2.py): the expected digest is the piece's
         merkle subtree root — SHA-256 leaves folded per BEP 52, off the
         event loop (≤64 leaves per piece; the batched device planes pay
@@ -2899,10 +2936,11 @@ class Torrent:
                 and pad == self.info.piece_length // 16384
             ):
                 # Full-subtree piece: batch onto the device leaf plane
-                # with every other concurrent finisher — the same
-                # micro-batch machinery as v1 (_flush_verify_batch routes
-                # on self.v2); tail pieces (short data / oversized pad)
-                # fold on the CPU below.
+                # with every other concurrent finisher. This micro-batch
+                # (_verify_pending → _flush_verify_batch) is the v2 arm
+                # alone and stays as it was: the scheduler has no merkle
+                # lane yet (ROADMAP Reach A2). Tail pieces (short data /
+                # oversized pad) fold on the CPU below.
                 #
                 # Whether the batch beats piece_root_cpu depends on
                 # how many pieces finish together and on the device's
@@ -2922,20 +2960,49 @@ class Torrent:
                 return piece_root_cpu(data, pad) == expected
             root = await asyncio.to_thread(piece_root_cpu, data, pad)
             return root == expected
-        if self.verifier is None or self.config.hasher != "tpu":
-            if len(data) <= INLINE_IO_MAX:
-                return hashlib.sha1(data).digest() == expected
-            digest = await asyncio.to_thread(lambda: hashlib.sha1(data).digest())
-            return digest == expected
-        fut: asyncio.Future = asyncio.get_running_loop().create_future()
-        self._verify_pending.append((index, data, expected, fut))
-        if not self._verify_flushing:
-            self._verify_flushing = True
-            self._spawn(self._flush_verify_batch(), name="verify-batch")
-        return await fut
+        if self.ingest_scheduler is not None:  # a hasher='tpu' client's
+            return await self._verify_on_scheduler(data, expected)
+        return await self._verify_hashlib(data, expected)
+
+    @staticmethod
+    async def _verify_hashlib(data: bytes, expected: bytes) -> bool:
+        if len(data) <= INLINE_IO_MAX:
+            return hashlib.sha1(data).digest() == expected
+        digest = await asyncio.to_thread(lambda: hashlib.sha1(data).digest())
+        return digest == expected
+
+    async def _verify_on_scheduler(self, data: bytes, expected: bytes) -> bool:
+        """A finished v1 piece as one ``verify`` submission of the tenant
+        ``ingest``. ``wait=True``: a full queue holds the peer loop back,
+        it never sheds a piece. ``flush=True``: the caller awaits this
+        very future and requests nothing until it resolves, which is the
+        hint's definition — the lane takes at once, whatever finished on
+        other peers meanwhile rides along, and the launch runs at the
+        smallest warmed rung that holds the take. A submission the
+        scheduler rejects (closing) or fails (retry and bisection
+        exhausted) is judged by hashlib instead, counted under
+        ``plane="hashlib_fallback"``."""
+        from torrent_tpu.sched import SchedLaunchError, SchedRejected
+
+        t0 = time.monotonic()
+        plane = "device"
+        try:
+            fut = await self.ingest_scheduler.enqueue(
+                "ingest", [data], [expected], algo="sha1",
+                piece_length=self.info.piece_length, wait=True, flush=True,
+            )
+            ok = bool((await fut)[0])
+        except (SchedRejected, SchedLaunchError) as e:
+            plane = "hashlib_fallback"
+            log.warning("tpu ingest verify failed (%s); hashlib fallback", e)
+            ok = await self._verify_hashlib(data, expected)
+        histograms().get(*_H_INGEST_VERIFY, plane=plane).observe(time.monotonic() - t0)
+        return ok
 
     async def _flush_verify_batch(self) -> None:
-        """Drain the pending-verification queue in device batches."""
+        """Drain the v2 pending-verification queue in device batches."""
+        from torrent_tpu.models.merkle import piece_root_cpu
+
         try:
             # one event-loop tick lets concurrent _finish_piece calls join
             await asyncio.sleep(0)
@@ -2944,33 +3011,22 @@ class Torrent:
                 del self._verify_pending[: len(batch)]
                 pieces = [b[1] for b in batch]
                 expected = [b[2] for b in batch]
-                device_fn = (
-                    self._verify_batch_device_v2 if self.v2 else self._verify_batch_device
-                )
                 t0 = time.monotonic()
                 plane = "device"
                 try:
-                    ok = await asyncio.to_thread(device_fn, pieces, expected)
+                    ok = await asyncio.to_thread(
+                        self._verify_batch_device_v2, pieces, expected
+                    )
                 except Exception as e:  # device trouble: fail safe to hashlib
                     plane = "hashlib_fallback"
                     log.warning("tpu ingest verify failed (%s); hashlib fallback", e)
-                    if self.v2:
-                        from torrent_tpu.models.merkle import piece_root_cpu
-
-                        lpp = self.info.piece_length // 16384
-                        ok = await asyncio.to_thread(
-                            lambda: [
-                                piece_root_cpu(p, lpp) == e2
-                                for p, e2 in zip(pieces, expected)
-                            ]
-                        )
-                    else:
-                        ok = await asyncio.to_thread(
-                            lambda: [
-                                hashlib.sha1(p).digest() == e2
-                                for p, e2 in zip(pieces, expected)
-                            ]
-                        )
+                    lpp = self.info.piece_length // 16384
+                    ok = await asyncio.to_thread(
+                        lambda: [
+                            piece_root_cpu(p, lpp) == e2
+                            for p, e2 in zip(pieces, expected)
+                        ]
+                    )
                 histograms().get(*_H_INGEST_VERIFY, plane=plane).observe(
                     time.monotonic() - t0
                 )
@@ -2983,14 +3039,6 @@ class Torrent:
                 if not fut.done():
                     fut.set_result(False)  # torn down mid-flight: re-request
             self._verify_pending.clear()
-
-    def _verify_batch_device(self, pieces: list[bytes], expected: list[bytes]):
-        from torrent_tpu.ops.padding import digests_to_words
-
-        digests = self.verifier.hash_pieces(pieces)
-        want = digests_to_words(expected)
-        got = digests_to_words(digests)
-        return (got == want).all(axis=1)
 
     def _verify_batch_device_v2(self, pieces: list[bytes], expected: list[bytes]):
         """Batched BEP 52 ingest verify: ONE leaf-plane dispatch plus the

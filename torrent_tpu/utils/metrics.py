@@ -1194,7 +1194,8 @@ class MetricsServer:
 
     ``scheduler``: optionally a hash-plane scheduler whose queue/fill/
     shed counters are appended to the session exposition, so one scrape
-    covers both the swarm and the verify queue it feeds.
+    covers both the swarm and the verify queue it feeds; without one,
+    the client's own ingest scheduler (``hasher="tpu"``) is rendered.
     ``fabric``: optionally a running ``FabricExecutor`` — its per-shard
     gauges AND its fleet rollup (``torrent_tpu_fleet_*``) join the same
     exposition, so the session endpoint carries the swarm-wide view just
@@ -1260,8 +1261,11 @@ class MetricsServer:
                 ctype = "application/json"
             elif len(parts) >= 2 and parts[0] == b"GET" and parts[1].split(b"?")[0] == b"/metrics":
                 text = render_metrics(self.client)
-                if self.scheduler is not None:
-                    text += render_sched_metrics(self.scheduler)
+                # a hasher='tpu' client's own ingest scheduler (tenant
+                # "ingest") is in its exposition without being handed over
+                sched = self.scheduler or self.client.ingest_scheduler
+                if sched is not None:
+                    text += render_sched_metrics(sched)
                 if self.fabric is not None:
                     text += render_fabric_metrics(self.fabric.metrics_snapshot())
                     text += render_fleet_metrics(self.fabric.fleet_snapshot())

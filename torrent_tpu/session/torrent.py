@@ -17,6 +17,15 @@ This is the completed design:
   piece to the client's shared ``HashPlaneScheduler``, tenant
   ``ingest``, where pieces that finished together share a launch), and
   only verified pieces are written + ``have``-broadcast.
+- **a piece's life**: *requested* (blocks in ``_inflight_count``, owned
+  by the peers asked) → *partial* (``_partials``: the picker finishes it
+  first, any peer may add a block, a webseed loop may reserve it) → *at
+  the judge* (``_judging``, from the last block's landing until the
+  verdict is acted on: ``_finish_piece`` alone owns it; no scan picks
+  it, the endgame leaves it out, a webseed loop may not reserve it and a
+  late block of it is dropped, while "what is left" still counts it) →
+  *written* (in ``bitfield``, ``have`` sent) or *refused* (missing
+  again, pickable at once, the ready peers refilled).
 - **resume-recheck**: ``start()`` runs ``verify_pieces`` (hasher
   'cpu'|'tpu') to rebuild the bitfield before announcing — the subsystem
   the reference lists as roadmap (README.md:34) and the BASELINE north
@@ -378,6 +387,15 @@ class Torrent:
             per_ip=self.config.per_ip_limit,
         )
         self._partials: dict[int, _PartialPiece] = {}
+        # pieces at the judge: the last block landed, the verdict is not
+        # acted on yet (_finish_piece owns them; nothing may request,
+        # assemble or reserve one). The counters say it engages:
+        # scans/blocks that passed such a piece over, and complete
+        # partials that reached _finish_piece for a piece already valid
+        # or at the judge (dropped unjudged; 0 unless the picker regresses)
+        self._judging: set[int] = set()
+        self._judging_skips = 0
+        self._duplicate_judged = 0
         # v2 device ingest-verification micro-batching (see
         # _verify_piece_data; v1 pieces go to ingest_scheduler)
         self._verify_pending: list = []
@@ -2491,6 +2509,10 @@ class Torrent:
         have_arr = self.bitfield.as_numpy()
         peer_arr = peer.bitfield.as_numpy()
         wanted: list[tuple[int, int, int]] = []
+        # a piece at the judge is nobody's to ask for (it has no partial
+        # and no block in flight, yet its bytes are all here)
+        judging = self._judging
+        skips = 0
 
         def pickable(index: int) -> bool:
             return not peer.peer_choking or index in peer.allowed_fast_in
@@ -2542,6 +2564,9 @@ class Torrent:
         if len(wanted) < budget and self._stream_positions:
             for first, n in sorted(self._stream_positions.values()):
                 for index in range(first, min(first + n, self.info.num_pieces)):
+                    if index in judging:
+                        skips += 1
+                        continue
                     if (
                         have_arr[index]
                         or index in self._partials
@@ -2558,6 +2583,9 @@ class Torrent:
         # says these are cheap for it to serve (e.g. still in cache)
         if len(wanted) < budget:
             for index in peer.suggested:
+                if index in judging:
+                    skips += 1
+                    continue
                 if (
                     have_arr[index]
                     or index in self._partials
@@ -2575,6 +2603,9 @@ class Torrent:
                 if have_arr[index]:
                     done_prefix += 1
                     continue
+                if index in judging:
+                    skips += 1
+                    continue
                 if (
                     index in self._partials
                     or not peer_arr[index]
@@ -2590,6 +2621,7 @@ class Torrent:
             if done_prefix > 64 and done_prefix * 2 > len(self._rarity_order):
                 self._rarity_dirty = True
 
+        self._judging_skips += skips
         if not wanted:
             if peer.peer_choking:
                 # The choked-fast path must never trip global endgame:
@@ -2612,11 +2644,14 @@ class Torrent:
                 peer.fill_starved = True
                 return
             # Endgame: everything missing is in flight somewhere — duplicate
-            # requests so one slow peer can't stall completion.
+            # requests so one slow peer can't stall completion. A piece at
+            # the judge is missing and has nothing left to duplicate.
+            self._judging_skips += sum(1 for i in judging if peer_arr[i])
             remaining = [
                 blk
                 for i in self.bitfield.missing()
-                if peer_arr[i]
+                if i not in judging
+                and peer_arr[i]
                 and pickable(i)
                 and self._piece_priority[i] > 0
                 for blk in self._missing_blocks(i)
@@ -2699,6 +2734,15 @@ class Torrent:
         self._recv_charge(pacing_s, len(block))
         if self.bitfield.has(index):
             return  # duplicate from endgame
+        if index in self._judging:
+            # a late block of a piece at the judge: every byte of it is
+            # here already, and a partial born now would be completed
+            # with fifteen more requests. Its other copies are recalled
+            # as any arrived block's are.
+            self._judging_skips += 1
+            if self._endgame or self._inflight_count[blk] > 0:
+                await self._cancel_everywhere(blk, except_peer=peer)
+            return
         partial = self._partials.get(index)
         if partial is None:
             partial = self._partials[index] = _PartialPiece(
@@ -2770,13 +2814,74 @@ class Torrent:
         meanwhile share its launch; otherwise per-piece hashlib
         off-thread. Either way the caller awaits the verdict here: the
         ledger wait ``ingest_verdict_wait``, one entry a piece.
+
+        From here until the verdict is acted on the piece is at the
+        judge (``_judging``) and this call owns it: whatever way the
+        call ends, the piece leaves the set, in the bitfield or missing
+        and pickable again.
         """
-        if self._partials.get(partial.index) is not partial:
+        index = partial.index
+        if self._partials.get(index) is not partial:
             # Another path (endgame peer vs webseed) already finished or
             # reset this piece — finishing it twice would double-count
             # stats and KeyError on the second removal.
             return "stale"
-        del self._partials[partial.index]
+        del self._partials[index]
+        if index in self._judging or self.bitfield.has(index):
+            # a second delivery of a piece that has its judge: the picker
+            # hands no such piece out, so this counts a regression. It is
+            # dropped unjudged (a second "ok" would count the piece twice)
+            self._duplicate_judged += 1
+            self.downloaded -= partial.length
+            return "stale"
+        self._judging.add(index)
+        try:
+            outcome = await self._judge_and_write(partial)
+        except (asyncio.CancelledError, Exception):
+            # the verdict never came (the judge raised, the awaiting loop
+            # was cancelled): the piece is missing again, as after a refusal
+            if not self._stopping:
+                self._spawn(self._refill_ready_peers(), name="refill-unjudged")
+            raise
+        finally:
+            self._judging.discard(index)
+        if outcome != "ok":
+            self._verdict(index, outcome)
+            # Missing again and pickable at once. The delivering peer
+            # refills itself (_ingest_block's tail) unless this verdict
+            # banned it; peers that found nothing to ask while the piece
+            # was judged sit starved on an empty pipeline and no message
+            # of theirs is due, so they are reached from here.
+            await self._refill_ready_peers()
+            return outcome
+        self._notify_piece(index)
+        self._verdict(index, "ok")
+        if self._piece_priority[index] > 0:
+            self._wanted_missing = max(0, self._wanted_missing - 1)
+        if self.bitfield.count() % 16 == 0:
+            self._checkpoint()  # periodic progress checkpoint
+        # snapshot: each send awaits, and an inbound peer registering
+        # during the broadcast mutates self.peers (observed as
+        # "dictionary keys changed during iteration" killing the
+        # ingesting peer's loop in an 8-leech fanout swarm)
+        for p in list(self.peers.values()):
+            if self.peers.get(p.peer_id) is not p:
+                continue  # dropped during an earlier send's await
+            try:
+                await proto.send_message(p.writer, proto.Have(index=index))
+                if p.am_interested:
+                    await self._update_interest(p)
+            except (ConnectionError, OSError):
+                # a dead writer here must not tear down the INGESTING
+                # peer's loop, and interest updates on a dropped peer
+                # would assign inflight blocks nothing will ever release
+                pass
+        await self._maybe_completed()
+        return "ok"
+
+    async def _judge_and_write(self, partial: _PartialPiece) -> str:
+        """A piece at the judge: its verdict and, if valid, its write and
+        its bit. Returns ``"ok"`` | ``"corrupt"`` | ``"io_error"``."""
         data = bytes(partial.buffer)
         expected = self.info.pieces[partial.index]
         t0 = time.monotonic()
@@ -2792,7 +2897,7 @@ class Torrent:
             log.warning("piece %d failed verification; re-requesting", partial.index)
             self.downloaded -= partial.length  # don't count poisoned data
             self._credit_corruption(partial.contributors)
-            return self._verdict(partial.index, "corrupt")
+            return "corrupt"
         self._absolve(partial.contributors)
         base = partial.index * self.info.piece_length
         try:
@@ -2802,32 +2907,22 @@ class Torrent:
                 await asyncio.to_thread(self._write_piece, base, data)
         except StorageError as e:
             log.error("failed to persist piece %d: %s", partial.index, e)
-            return self._verdict(partial.index, "io_error")
+            return "io_error"
         self.bitfield.set(partial.index)
-        self._notify_piece(partial.index)
-        self._verdict(partial.index, "ok")
-        if self._piece_priority[partial.index] > 0:
-            self._wanted_missing = max(0, self._wanted_missing - 1)
-        if self.bitfield.count() % 16 == 0:
-            self._checkpoint()  # periodic progress checkpoint
-        # snapshot: each send awaits, and an inbound peer registering
-        # during the broadcast mutates self.peers (observed as
-        # "dictionary keys changed during iteration" killing the
-        # ingesting peer's loop in an 8-leech fanout swarm)
-        for p in list(self.peers.values()):
-            if self.peers.get(p.peer_id) is not p:
-                continue  # dropped during an earlier send's await
-            try:
-                await proto.send_message(p.writer, proto.Have(index=partial.index))
-                if p.am_interested:
-                    await self._update_interest(p)
-            except (ConnectionError, OSError):
-                # a dead writer here must not tear down the INGESTING
-                # peer's loop, and interest updates on a dropped peer
-                # would assign inflight blocks nothing will ever release
-                pass
-        await self._maybe_completed()
         return "ok"
+
+    async def _refill_ready_peers(self) -> None:
+        """Offer what just became pickable to every peer that can be
+        asked (the fill self-gates on budget and choke state)."""
+        for p in list(self.peers.values()):  # awaits below; dict may mutate
+            if not p.snubbed and not p.peer_choking and p.am_interested:
+                try:
+                    await self._fill_pipeline(p)
+                except (ConnectionError, OSError):
+                    # a reset socket whose peer-loop hasn't noticed yet
+                    # must not end the caller: the choke loop for the
+                    # torrent's remaining lifetime, another peer's loop
+                    continue
 
     def _verdict(self, index: int, outcome: str) -> str:
         """Publish a judged delivery's outcome (``on_piece_verdict``)."""
@@ -3359,15 +3454,7 @@ class Torrent:
                 self._swarm_obs.on_snub(self._obs_key(p))
                 released_any = True
         if released_any:
-            for p in list(self.peers.values()):
-                if not p.snubbed and not p.peer_choking and p.am_interested:
-                    try:
-                        await self._fill_pipeline(p)
-                    except (ConnectionError, OSError):
-                        # a reset socket whose peer-loop hasn't noticed
-                        # yet must not kill the CHOKE loop for the
-                        # torrent's remaining lifetime
-                        continue
+            await self._refill_ready_peers()
 
     async def _choke_loop(self) -> None:
         """Unchoke by DRR deficit + one seeded optimistic slot (BEP 3
@@ -3506,6 +3593,9 @@ class Torrent:
 
         def eligible(index: int) -> bool:
             if self.bitfield.has(index) or index in busy:
+                return False
+            if index in self._judging:
+                self._judging_skips += 1
                 return False
             if self._piece_priority[index] <= 0:
                 return False
@@ -3746,6 +3836,9 @@ class Torrent:
             "encrypted_peers": self._count_encrypted_peers(),
             "stream_readers": len(self._stream_positions),
             "partials": len(self._partials),
+            "judging": len(self._judging),
+            "judging_skips": self._judging_skips,
+            "duplicate_judged": self._duplicate_judged,
             "max_upload_bps": self.config.max_upload_bps,
             "max_download_bps": self.config.max_download_bps,
             "serve": {

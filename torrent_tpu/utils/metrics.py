@@ -1133,6 +1133,9 @@ def render_metrics(client) -> str:
         "# TYPE torrent_tpu_uploaded_bytes_total counter",
         f"torrent_tpu_uploaded_bytes_total {status['uploaded']}",
     ]
+    def of_status(key):
+        return lambda t: status["torrents"][t.metainfo.info_hash.hex()][key]
+
     per_torrent = [
         ("torrent_tpu_torrent_peers", "gauge", "Connected peers", lambda t: len(t.peers)),
         (
@@ -1164,6 +1167,27 @@ def render_metrics(client) -> str:
             "counter",
             "Payload bytes uploaded",
             lambda t: t.uploaded,
+        ),
+        # a piece at the judge has an owner (session/torrent.py): how many
+        # are there now, how often that kept a second peer off one, and
+        # the deliveries that reached the judge for a piece that had one (0)
+        (
+            "torrent_tpu_torrent_judging",
+            "gauge",
+            "Pieces at the judge (verdict awaited or being written)",
+            of_status("judging"),
+        ),
+        (
+            "torrent_tpu_torrent_judging_skips_total",
+            "counter",
+            "Picker scans that passed over a piece at the judge, and late blocks of one dropped",
+            of_status("judging_skips"),
+        ),
+        (
+            "torrent_tpu_torrent_duplicate_judged_total",
+            "counter",
+            "Complete deliveries of a piece already valid or at the judge",
+            of_status("duplicate_judged"),
         ),
     ]
     for name, kind, help_text, get in per_torrent:

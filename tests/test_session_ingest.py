@@ -26,6 +26,7 @@ from torrent_tpu.sched import FaultPlan, HashPlaneScheduler, SchedulerConfig
 from torrent_tpu.session.client import Client, ClientConfig
 from torrent_tpu.session.resume import FsResumeStore, ResumeData
 from torrent_tpu.session.torrent import _H_INGEST_VERIFY, TorrentConfig
+from torrent_tpu.storage.storage import FsStorage, storage_write_stats
 from torrent_tpu.utils.bitfield import Bitfield
 
 PLEN = 32768
@@ -705,3 +706,69 @@ def test_the_three_counters_are_in_status_and_metrics(tmp_path):
     assert f"torrent_tpu_torrent_judging{label} 1" in text
     assert f"torrent_tpu_torrent_judging_skips_total{label} 7" in text
     assert f"torrent_tpu_torrent_duplicate_judged_total{label} 2" in text
+
+
+# ---------------------------------------------------------------- a verified piece is one write
+
+
+class CountingFs(FsStorage):
+    """The leecher's backend, listing every ``set``: (offset, length)."""
+
+    def __init__(self, root):
+        super().__init__(root)
+        self.sets = []
+
+    def set(self, path, offset, data):
+        self.sets.append((offset, len(data)))
+        super().set(path, offset, data)
+
+
+def write_entries() -> tuple[int, int]:
+    s = pipeline_ledger().snapshot()["stages"].get("write", {})
+    return s.get("ops", 0), s.get("bytes", 0)
+
+
+@pytest.mark.parametrize("version", ["v1", "v2"])
+@pytest.mark.parametrize("hasher", ["cpu", "tpu"])
+def test_a_downloaded_piece_reaches_the_disk_as_one_write(tmp_path, hasher, version):
+    """Every valid piece is one ``method.set`` of the whole piece (one file:
+    one segment, where block by block it was one a 16 KiB block), one
+    ledger ``write`` entry, and one reuse of the file's kept handle; still
+    one delivery a piece to the judge."""
+    from torrent_tpu.utils.metrics import render_metrics
+
+    src, dest = str(tmp_path / "src"), str(tmp_path / "dest")
+    meta = write_source(src)[0] if version == "v1" else write_source_v2(src)
+    backend = CountingFs(dest)
+
+    async def go():
+        seeders = [await seeder(meta, src) for _ in range(3)]
+        leech, sched = await a_leecher(hasher)
+        judged, events = [], []
+        entries, handles = write_entries(), storage_write_stats()
+        try:
+            t = await leech.add(meta, backend)
+            slow_judge(t, judged, seconds=0.01)
+            t.on_piece_verdict = lambda index, outcome: events.append((index, outcome))
+            join(t, *seeders)
+            await asyncio.wait_for(t.on_complete.wait(), 60)
+            wrote = [a - b for a, b in zip(write_entries(), entries)]
+            opened = {k: storage_write_stats()[k] - handles[k] for k in handles}
+            return t.info.num_pieces, t.info.piece_length, judged, events, wrote, opened, render_metrics(leech)
+        finally:
+            await leech.close()
+            if sched is not None:
+                await sched.close()
+            for s in seeders:
+                await s.close()
+
+    n, plen, judged, events, wrote, opened, text = run(go())
+    assert n == 65
+    assert sorted(judged) == list(range(n))  # deliveries put to the judge == pieces
+    assert sorted(events) == [(i, "ok") for i in range(n)]
+    assert sorted(backend.sets) == [(i * plen, min(plen, LENGTH - i * plen)) for i in range(n)]
+    assert wrote == [n, LENGTH]  # one ledger entry, and the piece's bytes, a valid piece
+    assert opened == {"write_opens": 1, "write_reuses": n - 1}
+    assert "torrent_tpu_storage_write_opens_total " in text and "torrent_tpu_storage_write_reuses_total " in text
+    assert open(os.path.join(dest, "payload.bin"), "rb").read() == open(os.path.join(src, "payload.bin"), "rb").read()
+    assert backend._writers == {} == backend._handles  # the torrent's stop let go of its handles

@@ -581,10 +581,11 @@ class TestLedgerContract:
     def test_new_stages_render_lintable_and_fold_nothing(self):
         led = PipelineLedger()
         # one process can hold them all: a bridge that also runs a
-        # fabric job and a v2 stream (``merkle``, tests/test_v2_stage_spans.py)
-        new = sorted((RECHECK_STAGES | SCHED_STAGES | BRIDGE_STAGES | {"merkle"}) - set(PIPELINE_STAGES))
-        assert new == ["assemble", "decode", "merkle", "pad", "pass_setup", "reply"]
-        assert len(PIPELINE_STAGES) + len(new) == 14 <= MAX_STAGES
+        # fabric job, a v2 stream (``merkle``, tests/test_v2_stage_spans.py)
+        # and a download (``write``, tests/test_session_ingest.py)
+        new = sorted((RECHECK_STAGES | SCHED_STAGES | BRIDGE_STAGES | {"merkle", "write"}) - set(PIPELINE_STAGES))
+        assert new == ["assemble", "decode", "merkle", "pad", "pass_setup", "reply", "write"]
+        assert len(PIPELINE_STAGES) + len(new) == 15 <= MAX_STAGES
         for stage in new + list(PIPELINE_STAGES):
             with led.track(stage, 64, moved=128):
                 pass

@@ -502,3 +502,244 @@ class TestLeafWindowing:
         whole = roots_batched(entries, plen)
         windowed = roots_batched_windowed(iter(entries), plen, window=2)
         assert windowed == whole
+
+
+# ---------------------------------------------------------------- a verified piece is one write
+
+PIECE = 16 * BLOCK_SIZE  # a 256 KiB piece: sixteen blocks
+
+# piece 1 of each layout: inside one file; across two files; its own file's
+# last 100 bytes, then a BEP 47 pad span to the piece's end. With it the
+# method.set calls it takes whole, and block by block
+LAYOUTS = {
+    "one_file": (None, 1, 16),
+    "two_files": ((FileEntry(length=PIECE + 7_000, path=("a.bin",)), FileEntry(length=2 * PIECE, path=("b.bin",))), 2, 17),
+    "pad_tail": (
+        (
+            FileEntry(length=PIECE + 100, path=("a.bin",)),
+            FileEntry(length=PIECE - 100, path=(".pad", str(PIECE - 100)), pad=True),
+            FileEntry(length=PIECE, path=("b.bin",)),
+        ),
+        1,
+        1,
+    ),
+}
+
+
+class CountingFs(FsStorage):
+    """An ``FsStorage`` that lists every ``set``: (path, offset, length)."""
+
+    def __init__(self, root):
+        super().__init__(root)
+        self.sets = []
+
+    def set(self, path, offset, data):
+        self.sets.append((path, offset, len(data)))
+        super().set(path, offset, data)
+
+
+def layout(name):
+    files, segments, block_sets = LAYOUTS[name]
+    length = 3 * PIECE if files is None else sum(f.length for f in files)
+    return make_info(length, PIECE, files=files), segments, block_sets
+
+
+def tree(root):
+    """Every file under ``root``: its path relative to it → its bytes."""
+    import pathlib
+
+    root = pathlib.Path(root)
+    return {str(p.relative_to(root)): p.read_bytes() for p in root.rglob("*") if p.is_file()}
+
+
+def piece_bytes(seed=41, n=PIECE):
+    return np.random.default_rng(seed).integers(0, 256, n, dtype=np.uint8).tobytes()
+
+
+class TestWritePiece:
+    @pytest.mark.parametrize("name", sorted(LAYOUTS))
+    def test_one_write_a_segment_lays_down_the_sixteen_blocks_bytes(self, tmp_path, name):
+        info, segments, block_sets = layout(name)
+        data = piece_bytes()
+        whole, blocks = CountingFs(tmp_path / "whole"), CountingFs(tmp_path / "blocks")
+        assert Storage(whole, info).write_piece(PIECE, data) is True
+        st = Storage(blocks, info)
+        for off in range(0, PIECE, BLOCK_SIZE):
+            st.set(PIECE + off, data[off : off + BLOCK_SIZE])
+        assert (len(whole.sets), len(blocks.sets)) == (segments, block_sets)
+        assert sum(n for _, _, n in whole.sets) == sum(n for _, _, n in blocks.sets)
+        assert tree(tmp_path / "whole") == tree(tmp_path / "blocks") != {}
+        assert not any(".pad" in p for p in tree(tmp_path / "whole"))  # the pad span never reaches disk
+        assert Storage(whole, info).get(PIECE, PIECE) == (
+            data if name != "pad_tail" else data[:100] + bytes(PIECE - 100)
+        )
+        whole.close()
+        blocks.close()
+
+    def test_a_written_piece_is_written_once_until_it_is_unmarked(self):
+        info, *_ = layout("one_file")
+        m = MemoryStorage()
+        st = Storage(m, info)
+        first, second = piece_bytes(1), piece_bytes(2)
+        assert st.write_piece(PIECE, first) is True
+        assert st._written == set(range(PIECE, 2 * PIECE, BLOCK_SIZE))  # as after sixteen set() calls
+        assert st.write_piece(PIECE, second) is False
+        assert st.set(PIECE + BLOCK_SIZE, second[:BLOCK_SIZE]) is False  # set() honours the marks too
+        assert st.get(PIECE, PIECE) == first
+        st.unmark_piece_written(1)
+        assert st.write_piece(PIECE, second) is True
+        assert st.get(PIECE, PIECE) == second
+
+    def test_a_partly_written_piece_writes_its_unmarked_blocks_only(self):
+        info, *_ = layout("one_file")
+        st = Storage(MemoryStorage(), info)
+        old, new = piece_bytes(1), piece_bytes(2)
+        assert st.set(PIECE + 3 * BLOCK_SIZE, old[3 * BLOCK_SIZE : 4 * BLOCK_SIZE]) is True
+        assert st.write_piece(PIECE, new) is True
+        got = st.get(PIECE, PIECE)
+        # block for block what sixteen set() calls leave: the marked block keeps its bytes
+        assert got == new[: 3 * BLOCK_SIZE] + old[3 * BLOCK_SIZE : 4 * BLOCK_SIZE] + new[4 * BLOCK_SIZE :]
+        assert st._written == set(range(PIECE, 2 * PIECE, BLOCK_SIZE))
+
+    def test_a_failing_write_leaves_no_block_marked(self):
+        info, *_ = layout("two_files")
+
+        class FailsOnB(MemoryStorage):
+            fail = True
+
+            def set(self, path, offset, data):
+                if self.fail and path[-1] == "b.bin":
+                    self.fail = False
+                    raise StorageError("disk full")
+                super().set(path, offset, data)
+
+        st = Storage(FailsOnB(), info)
+        data = piece_bytes()
+        with pytest.raises(StorageError):
+            st.write_piece(PIECE, data)  # a.bin's segment went down, b.bin's failed
+        assert st._written == set()
+        assert st.write_piece(PIECE, data) is True  # the retry reaches the disk
+        assert st.get(PIECE, PIECE) == data
+
+
+class TestFsWriteHandles:
+    def test_a_file_is_opened_once_for_every_write(self, tmp_path):
+        from torrent_tpu.storage.storage import storage_write_stats
+
+        info, *_ = layout("one_file")
+        fs = FsStorage(tmp_path)
+        st = Storage(fs, info)
+        before = storage_write_stats()
+        for i in range(3):
+            assert st.write_piece(i * PIECE, piece_bytes(i)) is True
+        fs.set(("t",), 5, b"x")
+        after = storage_write_stats()
+        assert after["write_opens"] - before["write_opens"] == 1
+        assert after["write_reuses"] - before["write_reuses"] == 3
+        assert len(fs._writers) == 1
+        fs.close()
+
+    def test_routing_to_the_parts_mirror_and_back_drops_the_kept_handles(self, tmp_path):
+        import os
+        import pathlib
+
+        fs = FsStorage(tmp_path)
+        path = ("t", "a.bin")
+        fs.set_unwanted({path}, [path])
+        fs.set(path, 0, b"A" * 10)
+        mirror = fs._parts_abspath(path)
+        real = os.path.join(tmp_path, *path)
+        assert pathlib.Path(mirror).read_bytes() == b"A" * 10 and not os.path.exists(real)
+        held = fs._writers[path]  # a writer in flight keeps its own reference
+        fs.set_unwanted(set(), [path])  # wanted again: the mirror is promoted
+        assert fs._writers == {} and not held.closed  # dropped, not closed
+        os.pwrite(held.fileno(), b"B", 10)  # the in-flight write follows the renamed inode
+        fs.set(path, 11, b"C" * 4)
+        assert pathlib.Path(real).read_bytes() == b"A" * 10 + b"B" + b"C" * 4
+        assert not os.path.exists(mirror)
+        del held
+        fs.close()
+
+    def test_close_leaves_no_write_descriptor_open(self, tmp_path):
+        import os
+
+        if not os.path.isdir("/proc/self/fd"):
+            pytest.skip("needs /proc/self/fd")
+        before = len(os.listdir("/proc/self/fd"))
+        fs = FsStorage(tmp_path)
+        for name in ("a", "b", "c"):
+            fs.set((name,), 0, b"x" * 100)
+            fs.set((name,), 100, b"y" * 100)
+        assert len(os.listdir("/proc/self/fd")) == before + 3
+        fs.close()
+        assert len(os.listdir("/proc/self/fd")) == before
+        fs.set(("a",), 200, b"z")  # a closed backend opens again on demand
+        assert (tmp_path / "a").read_bytes() == b"x" * 100 + b"y" * 100 + b"z"
+        fs.close()
+
+    def test_a_lost_piece_is_written_again_where_its_file_was_deleted(self, tmp_path):
+        import os
+
+        info, *_ = layout("one_file")
+        fs = FsStorage(tmp_path)
+        st = Storage(fs, info)
+        data = piece_bytes()
+        assert st.write_piece(0, data) is True
+        os.remove(tmp_path / "t")  # gone from under the kept handle
+        st.unmark_piece_written(0)  # the piece-loss path: the handles go with the marks
+        assert fs._writers == {}
+        assert st.write_piece(0, data) is True
+        assert st.get(0, PIECE) == data
+        fs.close()
+
+    def test_threads_racing_on_every_piece_write_each_once_and_count_every_write(self, tmp_path):
+        import sys
+        import threading
+
+        from torrent_tpu.storage.storage import storage_write_stats
+
+        files = tuple(FileEntry(length=4 * PIECE + 1000 * i, path=(f"f{i}.bin",)) for i in range(3))
+        info = make_info(sum(f.length for f in files), PIECE, files=files)  # pieces span files
+        fs = FsStorage(tmp_path)
+        st = Storage(fs, info)
+        payload = piece_bytes(7, info.length)
+        n = info.num_pieces
+        wins = [0] * n
+        lock = threading.Lock()
+
+        def writer():
+            for i in range(n):
+                if st.write_piece(i * PIECE, payload[i * PIECE : (i + 1) * PIECE]):
+                    with lock:
+                        wins[i] += 1
+
+        before = storage_write_stats()
+        interval = sys.getswitchinterval()
+        sys.setswitchinterval(1e-6)
+        try:
+            threads = [threading.Thread(target=writer) for _ in range(16)]
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join(timeout=60)
+            assert not any(t.is_alive() for t in threads)
+        finally:
+            sys.setswitchinterval(interval)
+        after = storage_write_stats()
+        assert wins == [1] * n  # no piece marked twice
+        segments = sum(len(list(st.segments(i * PIECE, piece_length(info, i)))) for i in range(n))
+        opens, reuses = (after[k] - before[k] for k in ("write_opens", "write_reuses"))
+        assert (opens, opens + reuses) == (3, segments)  # one open a file; no write left uncounted
+        assert st.get(0, info.length) == payload
+        fs.close()
+
+    def test_past_its_bound_the_oldest_write_handle_is_dropped(self, tmp_path, monkeypatch):
+        monkeypatch.setattr(FsStorage, "WRITE_HANDLES_MAX", 2)
+        fs = FsStorage(tmp_path)
+        for name in ("a", "b", "c"):
+            fs.set((name,), 0, name.encode() * 3)
+        assert list(fs._writers) == [("b",), ("c",)]
+        fs.set(("a",), 3, b"A")
+        assert list(fs._writers) == [("c",), ("a",)]
+        assert (tmp_path / "a").read_bytes() == b"aaaA"
+        fs.close()

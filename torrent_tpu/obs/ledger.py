@@ -99,7 +99,10 @@ socket closed). A download's peer loops likewise: ``ingest_verdict_wait``
 (``session/torrent.py:_finish_piece``: a finished piece put to the judge
 → its verdict back, one entry a piece; the loop that delivered the last
 block requests nothing meanwhile, and every peer's loop may sit there at
-once). Hybrid authoring's SHA-1 arm is ``track("author_v1", wait=True)``
+once), and the stage ``write`` (``_judge_and_write``: a valid piece's
+``Storage.write_piece``, timed in the thread that makes it; a ``record``
+for the same reason, every peer's loop may be writing at once). Hybrid
+authoring's SHA-1 arm is ``track("author_v1", wait=True)``
 (``models/v2._hybrid_hash_file``: a chunk's concatenation, slices and
 ``hash_pieces`` launches, and the file's tail piece; bytes are the
 chunk's): a wait with a span, because the ``h2d`` / ``launch`` /
@@ -142,9 +145,10 @@ PIPELINE_STAGES = ("recv", "read", "stage", "h2d", "launch", "digest", "verdict"
 
 # unknown stage names fold into "other" past this bound — the ledger's
 # cardinality must stay fixed no matter what a plane_factory plane does.
-# One process can hold 14 today: the eight above, ``pass_setup``,
-# ``pad``, ``assemble``, ``merkle``, and the bridge's ``decode`` and
-# ``reply`` (tests/test_stage_spans.py keeps the budget)
+# One process can hold 15 today: the eight above, ``pass_setup``,
+# ``pad``, ``assemble``, ``merkle``, the bridge's ``decode`` and
+# ``reply``, and a download's ``write`` (tests/test_stage_spans.py keeps
+# the budget)
 MAX_STAGES = 16
 
 

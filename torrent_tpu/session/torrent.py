@@ -1116,6 +1116,9 @@ class Torrent:
         self._recv_flush()  # residual wire charges reach the ledger
         self._egress_flush()  # and residual serve charges with them
         self._checkpoint(include_partials=True)  # stop: keep in-flight work
+        # the storage's kept handles go with the torrent; a write still
+        # running in its thread finishes on its own reference
+        self.storage.drop_handles()
         if self.trackers:
             try:
                 await asyncio.wait_for(
@@ -2902,12 +2905,14 @@ class Torrent:
         base = partial.index * self.info.piece_length
         try:
             if len(data) <= INLINE_IO_MAX:
-                self._write_piece(base, data)  # µs-scale pwrite: no hop
+                seconds = self._write_piece(base, data)  # µs-scale pwrite: no hop
             else:
-                await asyncio.to_thread(self._write_piece, base, data)
+                seconds = await asyncio.to_thread(self._write_piece, base, data)
         except StorageError as e:
             log.error("failed to persist piece %d: %s", partial.index, e)
             return "io_error"
+        # after the fact and without a span, as the verdict's wait above
+        pipeline_ledger().record("write", len(data), seconds)
         self.bitfield.set(partial.index)
         return "ok"
 
@@ -2959,9 +2964,12 @@ class Torrent:
         self.on_complete.set()
         self.request_peers()  # announce `completed` promptly
 
-    def _write_piece(self, base: int, data: bytes) -> None:
-        for off in range(0, len(data), BLOCK_SIZE):
-            self.storage.set(base + off, data[off : off + BLOCK_SIZE])
+    def _write_piece(self, base: int, data: bytes) -> float:
+        """Persist a verified piece; returns the seconds the write took in
+        the thread that made it (the ledger stage ``write``)."""
+        t0 = time.monotonic()
+        self.storage.write_piece(base, data)
+        return time.monotonic() - t0
 
     def _credit_corruption(self, contributors) -> None:
         """Failure detection: strike every contributor address of a corrupt

@@ -19,6 +19,7 @@ and the written map can be rebuilt from a verified bitfield on resume.
 from __future__ import annotations
 
 import hashlib
+import io
 import os
 
 from torrent_tpu.analysis.sanitizer import named_lock
@@ -170,11 +171,7 @@ class Storage:
                 return False
             self._written.add(offset)
         try:
-            pos = 0
-            for path, foff, chunk in self.segments(offset, len(data)):
-                if path is not None:  # pad spans are never persisted
-                    self.method.set(path, foff, data[pos : pos + chunk])
-                pos += chunk
+            self._write_segments(offset, data)
         except Exception:
             # A failed write must not poison duplicate suppression — the
             # peer will re-send the block and the retry must go to disk.
@@ -182,6 +179,43 @@ class Storage:
                 self._written.discard(offset)
             raise
         return True
+
+    def write_piece(self, offset: int, data: bytes) -> bool:
+        """Write a verified piece: one ``method.set`` a file segment (pad
+        spans skipped), where ``set`` block by block makes sixteen a
+        256 KiB piece. Returns False, writing nothing, if every block of
+        the range was already written.
+
+        Every block offset is marked under one turn of the lock, so the
+        written map reads as after a ``set`` of each block; a failed write
+        unmarks them all, so that a retry reaches the disk. A piece only
+        some of whose blocks are marked goes through ``set`` block by
+        block, which writes exactly the unmarked ones."""
+        blocks = range(offset, offset + len(data), BLOCK_SIZE)
+        with self._lock:
+            fresh = [b for b in blocks if b not in self._written]
+            if len(fresh) == len(blocks):
+                self._written.update(blocks)
+        if not fresh:
+            return False
+        if len(fresh) < len(blocks):
+            written = [self.set(b, data[b - offset : b - offset + BLOCK_SIZE]) for b in fresh]
+            return any(written)
+        try:
+            self._write_segments(offset, data)
+        except Exception:
+            with self._lock:
+                self._written.difference_update(blocks)
+            raise
+        return True
+
+    def _write_segments(self, offset: int, data: bytes) -> None:
+        pos = 0
+        for path, foff, chunk in self.segments(offset, len(data)):
+            if path is not None:  # pad spans are never persisted
+                # a whole-length slice of bytes is the object itself: no copy
+                self.method.set(path, foff, data[pos : pos + chunk])
+            pos += chunk
 
     def exists(self) -> bool:
         """All files present at full length (resume precondition probe)."""
@@ -206,12 +240,23 @@ class Storage:
         The piece-loss path (BEP 54 self-healing) re-downloads a piece
         whose blocks are already in the written map — without this the
         replacement bytes verify in memory, ``set`` returns False for
-        every block, and the disk keeps the corrupt/missing data."""
+        every block, and the disk keeps the corrupt/missing data. The file
+        may be gone from under the backend's kept handles too, so they
+        are dropped: the rewrite opens its path again."""
         with self._lock:
             plen = piece_length(self.info, index)
             base = index * self.info.piece_length
             for boff in range(0, plen, BLOCK_SIZE):
                 self._written.discard(base + boff)
+        self.drop_handles()
+
+    def drop_handles(self) -> None:
+        """Let go of the backend's kept file handles (``FsStorage``): the
+        next read or write opens its path again. No-op on backends that
+        keep none."""
+        drop = getattr(self.method, "drop_handles", None)
+        if drop is not None:
+            drop()
 
     # ------------------------------------------------------------ batch IO
 
@@ -395,18 +440,52 @@ class Storage:
 # ---------------------------------------------------------------- backends
 
 
+_write_stats_lock = named_lock("storage.write_stats._lock")
+_write_stats = {"write_opens": 0, "write_reuses": 0}  # under _write_stats_lock
+
+
+def storage_write_stats() -> dict[str, int]:
+    """``write_opens`` / ``write_reuses``: ``FsStorage.set`` calls of this
+    process that opened a file for writing, or found it open already on
+    the handle their backend keeps. Rendered by ``/metrics``
+    (utils/metrics.py)."""
+    with _write_stats_lock:
+        return dict(_write_stats)
+
+
+def _count_write(key: str) -> None:
+    with _write_stats_lock:
+        _write_stats[key] += 1
+
+
+class _KeptWriter(io.FileIO):
+    """A file ``FsStorage`` keeps open for writing. Dropped by it, the
+    handle closes with its last reference: at once, or as a write under
+    way on it returns."""
+
+    def __del__(self):
+        self.close()
+
+
 class FsStorage:
     """Filesystem backend (storage.ts:140-206 ``fsStorage``).
 
     Keeps an open-handle cache instead of the reference's open/seek/close
-    per call — read_batch hits the same files tens of thousands of times.
+    per call — read_batch hits the same files tens of thousands of times —
+    and, apart from it, a write handle a file: a download writes every
+    piece of a file through one open, where an open a call cost the
+    interpreter five syscalls a block.
     """
 
     PARTS_DIR = ".parts"
+    # write handles kept at once; the oldest is dropped past it, so a
+    # torrent of many files holds no more descriptors than this for writing
+    WRITE_HANDLES_MAX = 64
 
     def __init__(self, root: str | os.PathLike):
         self.root = os.fspath(root)
         self._handles: dict[tuple[str, ...], object] = {}
+        self._writers: dict[tuple[str, ...], object] = {}  # bounded-by: WRITE_HANDLES_MAX
         self._lock = named_lock("storage.fs._lock")
         # deselected files: their boundary-piece spill is routed into a
         # hidden .parts mirror instead of creating visible stub files
@@ -427,11 +506,22 @@ class FsStorage:
         with self._lock:
             self._unwanted = new
             # drop (don't close) cached handles: a worker thread may be
-            # mid-pread on one — clearing lets in-flight readers finish
-            # on their own reference while new opens re-route
+            # mid-pread or mid-pwrite on one — clearing lets it finish on
+            # its own reference (a file object closes with its last one)
+            # while new opens re-route
             self._handles.clear()
+            self._writers.clear()
         for path in {tuple(p) for p in all_paths} - new:
             self._promote(path)
+
+    def drop_handles(self) -> None:
+        """Drop (don't close) every kept handle, as ``set_unwanted`` does:
+        the next IO resolves and opens its path again, and a handle closes
+        with its last reference, at once unless an IO is under way on it
+        (``close()`` closes them outright)."""
+        with self._lock:
+            self._handles.clear()
+            self._writers.clear()
 
     def _parts_abspath(self, path: tuple[str, ...]) -> str:
         cached = self._parts_cache.get(path)
@@ -505,23 +595,33 @@ class FsStorage:
             )
         return data
 
-    def set(self, path: tuple[str, ...], offset: int, data: bytes) -> None:
-        try:
-            # resolve+open under the lock (see _promote): routing and the
-            # rename can't interleave with this open. The pwrite itself
-            # runs unlocked — it follows the fd's inode wherever a
-            # concurrent promote renamed it.
-            with self._lock:
+    def _open_write(self, path: tuple[str, ...]):
+        # resolve+open under the lock (see _promote): routing and the
+        # rename can't interleave with this open
+        with self._lock:
+            f = self._writers.get(path)
+            opened = f is None
+            if opened:
                 abspath = self._abspath(path)
                 os.makedirs(os.path.dirname(abspath), exist_ok=True)
                 # in-place update without truncating (storage.ts:174-196)
-                fd = os.open(abspath, os.O_WRONLY | os.O_CREAT, 0o644)
-            try:
-                os.pwrite(fd, data, offset)
-            finally:
-                os.close(fd)
-        except OSError as e:
+                f = _KeptWriter(os.open(abspath, os.O_WRONLY | os.O_CREAT, 0o644), "wb")
+                if len(self._writers) >= self.WRITE_HANDLES_MAX:
+                    del self._writers[next(iter(self._writers))]  # dropped, not closed
+                self._writers[path] = f
+        _count_write("write_opens" if opened else "write_reuses")
+        return f
+
+    def set(self, path: tuple[str, ...], offset: int, data: bytes) -> None:
+        try:
+            f = self._open_write(path)
+            # the pwrite runs unlocked: positional, and it follows the
+            # handle's inode wherever a concurrent promote renamed it
+            written = os.pwrite(f.fileno(), data, offset)
+        except (OSError, ValueError) as e:
             raise StorageError(f"write failed to {path}: {e}") from e
+        if written != len(data):
+            raise StorageError(f"short write to {path}: {written} of {len(data)} bytes at {offset}")
 
     def exists(self, path: tuple[str, ...], length: int | None = None) -> bool:
         try:
@@ -532,12 +632,13 @@ class FsStorage:
 
     def close(self) -> None:
         with self._lock:
-            for f in self._handles.values():
+            for f in (*self._handles.values(), *self._writers.values()):
                 try:
                     f.close()  # type: ignore[union-attr]
                 except Exception:
                     pass
             self._handles.clear()
+            self._writers.clear()
 
 
 class MemoryStorage:

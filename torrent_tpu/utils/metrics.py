@@ -233,6 +233,24 @@ def render_staging_slab_metrics(stats: dict) -> str:
     return "\n".join(lines) + "\n"
 
 
+def render_storage_write_metrics(stats: dict) -> str:
+    """Prometheus rendering of ``FsStorage``'s kept write handles.
+
+    ``stats`` is ``torrent_tpu.storage.storage.storage_write_stats()``. A
+    download should show one open a file and a reuse for every later
+    segment written; opens rising with the pieces mean the handles are
+    dropped between writes (routing changed, or more files than
+    ``FsStorage.WRITE_HANDLES_MAX`` written in turn)."""
+    lines = []
+    for key, text in (
+        ("write_opens", "Storage writes that opened their file"),
+        ("write_reuses", "Storage writes on a file handle their backend kept open"),
+    ):
+        name = f"torrent_tpu_storage_{key}_total"
+        lines += [f"# HELP {name} {text}", f"# TYPE {name} counter", f"{name} {stats[key]}"]
+    return "\n".join(lines) + "\n"
+
+
 def render_leaf_metrics(stats: dict) -> str:
     """Prometheus rendering of the v2 leaf plane's launch counters.
 
@@ -1223,7 +1241,9 @@ def render_metrics(client) -> str:
                 f'torrent_tpu_torrent_state{{info_hash="{ih.hex()}",state="{state}"}} '
                 f"{1 if state == current else 0}"
             )
-    return "\n".join(lines) + "\n"
+    from torrent_tpu.storage.storage import storage_write_stats
+
+    return "\n".join(lines) + "\n" + render_storage_write_metrics(storage_write_stats())
 
 
 class MetricsServer:

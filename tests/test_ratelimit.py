@@ -10,7 +10,7 @@ from torrent_tpu.session.client import Client, ClientConfig
 from torrent_tpu.utils.ratelimit import TokenBucket
 from tests.test_fast import _messages, _mk_fast_peer
 from tests.test_selection import make_multifile_torrent, PLEN
-from tests.test_session import run
+from tests.test_session import judged, run
 
 
 class _FakeClock:
@@ -128,6 +128,7 @@ class TestSessionWiring:
             t.download_bucket = _Spy()
             peer = _mk_fast_peer(t)
             await t._ingest_block(peer, 0, 0, payload[:16384])
+            await judged(t)
             assert taken == [16384]
 
         run(go())
@@ -162,6 +163,7 @@ class TestPerTorrentCaps:
             assert taken["global_up"] == [16384] and taken["own_up"] == [16384]
             t.bitfield = type(t.bitfield)(t.info.num_pieces)  # accept ingest
             await t._ingest_block(peer, 0, 0, payload[:16384])
+            await judged(t)
             assert taken["global_down"] == [16384] and taken["own_down"] == [16384]
 
         run(go())

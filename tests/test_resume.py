@@ -19,7 +19,7 @@ from torrent_tpu.session.resume import (
 from torrent_tpu.session.torrent import Torrent, TorrentConfig
 from torrent_tpu.storage.storage import MemoryStorage, Storage
 
-from tests.test_session import build_torrent_bytes, fast_config
+from tests.test_session import build_torrent_bytes, fast_config, judged
 
 
 def run(coro, timeout=30):
@@ -159,6 +159,7 @@ class TestTorrentFastresume:
             await t2._ingest_block(
                 peer, 1, BLOCK_SIZE, payload[plen + BLOCK_SIZE : 2 * plen]
             )
+            await judged(t2)
             assert t2.bitfield.has(1)
             assert t2.storage.get(plen, plen) == payload[plen : 2 * plen]
 
@@ -181,6 +182,7 @@ class TestTorrentFastresume:
             assert t4._try_fastresume() is True
             peer4 = _mk_fast_peer(t4)
             await t4._ingest_block(peer4, 0, BLOCK_SIZE, payload3[BLOCK_SIZE:plen])
+            await judged(t4)
             assert not t4.bitfield.has(0)  # hash rejected the poisoned mix
 
         run(go())

@@ -27,6 +27,14 @@ def run(coro, timeout=60):
     return asyncio.run(asyncio.wait_for(coro, timeout))
 
 
+async def judged(torrent) -> None:
+    """Until every piece the torrent's peer loops handed to a judge task
+    has its verdict acted on: the block that completes a piece returns
+    before the piece is judged."""
+    while tasks := [t for t in torrent._tasks if t.get_name().startswith("judge-")]:
+        await asyncio.wait(tasks)
+
+
 def build_torrent_bytes(payload: bytes, piece_len: int, announce: bytes, name=b"swarm-test"):
     pieces = b"".join(
         hashlib.sha1(payload[i : i + piece_len]).digest() for i in range(0, len(payload), piece_len)
@@ -1066,6 +1074,7 @@ class TestConfigIsolationAndRaces:
             for begin, data in blocks:
                 await t._ingest_block(a, 0, begin, data)
                 await t._ingest_block(b, 0, begin, data)
+            await judged(t)
             assert t.bitfield.has(0)
             assert t.downloaded == 32768  # each block counted exactly once
 

@@ -29,6 +29,8 @@ from torrent_tpu.session.torrent import _H_INGEST_VERIFY, TorrentConfig
 from torrent_tpu.storage.storage import FsStorage, storage_write_stats
 from torrent_tpu.utils.bitfield import Bitfield
 
+from tests.test_session import judged
+
 PLEN = 32768
 LENGTH = 2 * 1024 * 1024 + 5003
 SEED = 2147483777
@@ -497,9 +499,10 @@ async def eventually(what, said, limit=30.0):
 
 class Held:
     """The leecher alone with one seeder (127.0.0.2) until piece X is at
-    the judge, where the stub keeps it; then a second seeder (127.0.0.3)
-    is let in, delivers everything else and finds nothing left to ask.
-    ``release()`` lets the verdict come."""
+    the judge, where the stub keeps it, and alone there (the peer loop
+    went on past X and its other pieces' verdicts came); then a second
+    seeder (127.0.0.3) is let in, and finds nothing left to ask once
+    everything else is written. ``release()`` lets the verdict come."""
 
     def __init__(self, tmp_path, first_root=None, hasher="cpu", **torrent_kw):
         self.src, self.dest = str(tmp_path / "src"), str(tmp_path / "dest")
@@ -520,6 +523,7 @@ class Held:
         t.on_piece_verdict = lambda index, outcome: self.events.append((index, outcome))
         t._connect_new_peers([AnnouncePeer(ip="127.0.0.2", port=self.first.port)])
         await eventually(lambda: X in t._judging, "piece X at the judge")
+        await eventually(lambda: t._judging == {X}, "piece X alone at the judge")
         return self
 
     async def let_the_second_in(self):
@@ -772,3 +776,311 @@ def test_a_downloaded_piece_reaches_the_disk_as_one_write(tmp_path, hasher, vers
     assert "torrent_tpu_storage_write_opens_total " in text and "torrent_tpu_storage_write_reuses_total " in text
     assert open(os.path.join(dest, "payload.bin"), "rb").read() == open(os.path.join(src, "payload.bin"), "rb").read()
     assert backend._writers == {} == backend._handles  # the torrent's stop let go of its handles
+
+
+# ---------------------------------------------------------------- a peer goes on while its piece is judged
+
+
+class Gate:
+    """The torrent's judge behind a gate a piece: a delivery put to it is
+    listed in ``asked`` and waits until its piece is let through (the
+    first ``hold`` deliveries only, where given), then gets the real
+    verdict."""
+
+    def __init__(self, torrent, hold=None):
+        self.real = torrent._verify_piece_data
+        self.hold = hold
+        self.asked: list[int] = []
+        self.gates: dict[int, asyncio.Event] = {}
+        self.opened = False
+        torrent._verify_piece_data = self.judge
+
+    def gate(self, index) -> asyncio.Event:
+        if index not in self.gates:
+            self.gates[index] = asyncio.Event()
+            if self.opened:
+                self.gates[index].set()
+        return self.gates[index]
+
+    def let(self, *indices):
+        for i in indices:
+            self.gate(i).set()
+
+    def open(self):
+        self.opened = True
+        for g in self.gates.values():
+            g.set()
+
+    async def judge(self, index, data, expected):
+        self.asked.append(index)
+        if self.hold is None or len(self.asked) <= self.hold:
+            await self.gate(index).wait()
+        return await self.real(index, data, expected)
+
+
+def lone(tmp_path, plen, pieces, **config):
+    """A torrent (not started) over an empty memory store, with ``pieces``
+    whole pieces of ``plen`` bytes, and its payload."""
+    from torrent_tpu.session.client import generate_peer_id
+    from torrent_tpu.session.torrent import Torrent
+    from torrent_tpu.storage.storage import MemoryStorage, Storage
+
+    src = str(tmp_path / "src")
+    meta, _ = write_source(src, plen=plen, length=pieces * plen)
+    payload = open(os.path.join(src, meta.info.name), "rb").read()
+    t = Torrent(
+        metainfo=meta, storage=Storage(MemoryStorage(), meta.info), peer_id=b"-TT0001-lonelonelone",
+        port=1, config=TorrentConfig(**config),
+    )
+    return t, payload
+
+
+def fake_seeder(t, host="10.0.0.2"):
+    """A connected peer that holds every piece and has unchoked us; what
+    we ask of it is on its fake writer."""
+    from torrent_tpu.session.peer import PeerConnection
+    from tests.test_session import _FakeWriter
+
+    peer = PeerConnection(
+        peer_id=b"S" * 20, reader=object(), writer=_FakeWriter(), num_pieces=t.info.num_pieces, address=(host, 6881)
+    )
+    for i in range(t.info.num_pieces):
+        peer.bitfield.set(i)
+    t.peers[peer.peer_id] = peer
+    t._avail += peer.bitfield.as_numpy()
+    peer.peer_choking, peer.am_interested = False, True
+    return peer
+
+
+async def deliver(t, peer, payload, index):
+    """Every block of piece ``index`` of ``payload`` from ``peer``, as its
+    loop ingests them."""
+    from torrent_tpu.storage.piece import BLOCK_SIZE
+
+    plen = t.info.piece_length
+    piece = payload[index * plen : (index + 1) * plen]
+    for begin in range(0, len(piece), BLOCK_SIZE):
+        await t._ingest_block(peer, index, begin, piece[begin : begin + BLOCK_SIZE])
+
+
+def verdict_wait() -> tuple[int, float]:
+    w = pipeline_ledger().snapshot()["waits"].get("ingest_verdict_wait", {})
+    return w.get("ops", 0), w.get("busy_s", 0.0)
+
+
+@pytest.mark.parametrize("hasher", ["cpu", "tpu"])
+def test_the_peer_downloads_on_while_its_piece_is_judged(tmp_path, hasher):
+    """One seeder; the first piece put to the judge is held there. The
+    peer loop that delivered it goes on: it ingests the next blocks and
+    asks for more, and every other piece is delivered, judged and
+    written before that verdict comes. Every hand-off after the first
+    found the peer's held piece at the judge."""
+    src, dest = str(tmp_path / "src"), str(tmp_path / "dest")
+    length = 16 * PLEN + 5003
+    meta, _ = write_source(src, length=length)
+    n = meta.info.num_pieces
+
+    async def go():
+        seed = await seeder(meta, src)
+        leech, sched = await a_leecher(hasher)
+        events: list = []
+        try:
+            t = await leech.add(meta, dest)
+            gate = Gate(t, hold=1)
+            t.on_piece_verdict = lambda index, outcome: events.append((index, outcome))
+            join(t, seed)
+            await eventually(lambda: t.bitfield.count() == n - 1, "every other piece written")
+            first = gate.asked[0]
+            (peer,) = t.peers.values()
+            assert t._judging == {first} and not t.bitfield.has(first)
+            assert peer.bytes_down >= length - PLEN  # asked for and ingested behind the held piece
+            during = t.status()
+            gate.let(first)
+            await asyncio.wait_for(t.on_complete.wait(), 30)
+            return during, t.status(), sorted(gate.asked), events
+        finally:
+            await leech.close()
+            if sched is not None:
+                await sched.close()
+            await seed.close()
+
+    during, status, asked, events = run(go())
+    assert during["judge_overlapped"] == n - 1 and during["judging_peak"] >= 2
+    assert asked == list(range(n)) and sorted(events) == [(i, "ok") for i in range(n)]
+    assert status["judging"] == 0 and status["duplicate_judged"] == 0 and status["downloaded"] == length
+
+
+@pytest.mark.parametrize(("plen", "allowed"), [(16384, 32), (262144, 2), (1 << 20, 1)], ids=["16k", "256k", "1m"])
+def test_a_completion_over_the_bound_waits_for_the_peers_oldest_verdict(tmp_path, plen, allowed):
+    """A peer's pieces at the judge hold at most twice the bytes its
+    pipeline asks for (2 x 16 x 16 KiB), and at least one piece: 32 at
+    16 KiB, two at 256 KiB, one at 1 MiB. The completion past that waits
+    for the oldest verdict, and the ledger wait is that hold alone."""
+    t, payload = lone(tmp_path, plen, allowed + 2)
+
+    async def go():
+        gate = Gate(t)
+        peer = fake_seeder(t)
+        ops0, busy0 = verdict_wait()
+        for i in range(allowed):
+            await deliver(t, peer, payload, i)
+        await asyncio.sleep(0)
+        assert t._judging == set(range(allowed)) and sorted(gate.asked) == list(range(allowed))
+        assert verdict_wait() == (ops0 + allowed, busy0)  # went on at once: an entry each, 0 s
+        held = asyncio.ensure_future(deliver(t, peer, payload, allowed))
+        await asyncio.sleep(0.05)
+        assert not held.done() and t.status()["judge_bound_waits"] == 1
+        assert allowed in t._partials and allowed not in t._judging  # complete, not handed over yet
+        gate.let(0)
+        await asyncio.wait_for(held, 10)
+        ops, busy = verdict_wait()
+        assert ops == ops0 + allowed + 1 and busy - busy0 >= 0.05
+        assert t._judging == set(range(1, allowed + 1))
+        gate.open()
+        await judged(t)
+        return t.status()
+
+    status = run(go())
+    assert status["judging"] == 0 and status["judge_bound_waits"] == 1 and status["judging_peak"] == allowed
+    assert status["judge_overlapped"] == (allowed - 1) + (allowed > 1)
+    assert [t.bitfield.has(i) for i in range(allowed + 2)] == [True] * (allowed + 1) + [False]
+
+
+def test_a_refusal_by_a_judge_task_bans_the_poisoner_and_its_other_verdicts_still_come(tmp_path):
+    """Six pieces of one peer at the judge, three of them poisoned: the
+    third refusal in a row bans the address and drops the peer from a
+    judge task, and the verdicts still owed are acted on all the same."""
+    t, payload = lone(tmp_path, PLEN, 8, max_corrupt_pieces=3)
+    bad = bytearray(payload)
+    for i in (0, 2, 4):
+        bad[(i + 1) * PLEN - 1] ^= 0x5A
+
+    async def go():
+        gate = Gate(t)
+        peer = fake_seeder(t, host="10.0.0.9")
+        events: list = []
+        t.on_piece_verdict = lambda index, outcome: events.append((index, outcome))
+        for i in range(6):
+            await deliver(t, peer, bytes(bad), i)
+        await asyncio.sleep(0)
+        assert t._judging == set(range(6)) and t.peers
+        gate.let(0, 2)
+        await eventually(lambda: not t._judging & {0, 2}, "two refusals acted on")
+        assert t.peers and not t._banned
+        gate.let(4)
+        await eventually(lambda: 4 not in t._judging, "the third refusal acted on")
+        assert set(t._banned) == {"10.0.0.9"} and not t.peers and peer.writer.closed
+        assert t._judging == {1, 3, 5}  # still owed
+        gate.open()
+        await judged(t)
+        return events
+
+    events = run(go())
+    assert t._judging == set() and sorted(events) == [(i, "corrupt" if i % 2 == 0 else "ok") for i in range(6)]
+    assert [t.bitfield.has(i) for i in range(8)] == [False, True, False, True, False, True, False, False]
+
+
+@pytest.mark.parametrize("where", ["verdict", "write"])
+def test_stop_with_pieces_at_the_judge_leaves_none_there_and_no_task(tmp_path, where):
+    """``stop()`` cancels the judge tasks as it does every task. One whose
+    write runs in its thread waits for the bytes: a write cannot be
+    called back, so the piece ends in the bitfield with its verdict."""
+    import threading
+
+    plen = 262144  # over INLINE_IO_MAX: the write takes a thread
+    t, payload = lone(tmp_path, plen, 3)
+
+    async def go():
+        gate = Gate(t)
+        peer = fake_seeder(t)
+        events: list = []
+        t.on_piece_verdict = lambda index, outcome: events.append((index, outcome))
+        writing, resume = threading.Event(), threading.Event()
+        real = t._write_piece
+
+        def slow_write(base, data):
+            writing.set()
+            resume.wait(10)
+            return real(base, data)
+
+        t._write_piece = slow_write
+        for i in range(2):
+            await deliver(t, peer, payload, i)
+        await asyncio.sleep(0)
+        tasks = [task for task, _ in peer.at_judge]
+        assert len(tasks) == 2 and t._judging == {0, 1}
+        if where == "write":
+            gate.let(0)
+            await eventually(writing.is_set, "piece 0 in its write")
+            stopping = asyncio.ensure_future(t.stop())
+            await asyncio.sleep(0.05)
+            assert not stopping.done()  # the cancel waits for the bytes
+            resume.set()
+            await asyncio.wait_for(stopping, 10)
+        else:
+            resume.set()
+            await t.stop()
+        return tasks, events
+
+    tasks, events = run(go())
+    assert t._judging == set() and not t._tasks and all(task.done() for task in tasks)
+    if where == "write":
+        assert events == [(0, "ok")] and t.bitfield.has(0) and t.storage.get(0, plen) == payload[:plen]
+    else:
+        assert events == [] and t.bitfield.count() == 0
+
+
+@pytest.mark.parametrize("hasher", ["cpu", "tpu"])
+def test_overlapped_verdicts_judge_every_piece_once(tmp_path, hasher):
+    """Three seeders and a verdict of 20 ms: each peer has several pieces
+    at the judge at once, and still every piece is put to the judge once
+    (no duplicate came back with the overlap)."""
+    src, dest = str(tmp_path / "src"), str(tmp_path / "dest")
+    meta, _ = write_source(src)
+
+    async def go():
+        seeders = [await seeder(meta, src) for _ in range(3)]
+        leech, sched = await a_leecher(hasher)
+        judged_, events = [], []
+        try:
+            t = await leech.add(meta, dest)
+            slow_judge(t, judged_, seconds=0.02)
+            t.on_piece_verdict = lambda index, outcome: events.append((index, outcome))
+            join(t, *seeders)
+            await asyncio.wait_for(t.on_complete.wait(), 60)
+            return t.info.num_pieces, judged_, events, t.status()
+        finally:
+            await leech.close()
+            if sched is not None:
+                await sched.close()
+            for s in seeders:
+                await s.close()
+
+    n, judged_, events, status = run(go())
+    assert sorted(judged_) == list(range(n)) and sorted(events) == [(i, "ok") for i in range(n)]
+    assert status["duplicate_judged"] == 0 and status["judging"] == 0
+    assert status["judge_overlapped"] > 0 and status["judging_peak"] > 3  # more than one a peer
+
+
+def test_the_judge_counters_are_in_status_and_metrics(tmp_path):
+    from torrent_tpu.utils.metrics import render_metrics
+
+    src = str(tmp_path / "src")
+    meta, _ = write_source(src, length=4 * PLEN)
+
+    async def go():
+        leech, _ = await a_leecher("cpu")
+        try:
+            t = await leech.add(meta, str(tmp_path / "dest"))
+            t._judge_overlapped, t._judge_bound_waits, t._judging_peak = 11, 3, 9
+            return t.status(), leech.status()["torrents"][meta.info_hash.hex()], render_metrics(leech)
+        finally:
+            await leech.close()
+
+    status, through_client, text = run(go())
+    assert (status["judge_overlapped"], status["judge_bound_waits"], status["judging_peak"]) == (11, 3, 9)
+    assert through_client["judging_peak"] == 9
+    label = f'{{info_hash="{meta.info_hash.hex()}",name="payload.bin"}}'
+    assert f"torrent_tpu_torrent_judge_overlapped_total{label} 11" in text
+    assert f"torrent_tpu_torrent_judge_bound_waits_total{label} 3" in text
+    assert f"torrent_tpu_torrent_judging_peak{label} 9" in text

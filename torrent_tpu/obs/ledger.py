@@ -96,12 +96,14 @@ span, because some fifteen requests sit in one at once): ``http_head``
 ``verdict_wake`` (a submission resolved → ``submit()`` running again)
 and the whole, ``http_request`` (accept → the reply written and the
 socket closed). A download's peer loops likewise: ``ingest_verdict_wait``
-(``session/torrent.py:_finish_piece``: a finished piece put to the judge
-→ its verdict back, one entry a piece; the loop that delivered the last
-block requests nothing meanwhile, and every peer's loop may sit there at
-once), and the stage ``write`` (``_judge_and_write``: a valid piece's
-``Storage.write_piece``, timed in the thread that makes it; a ``record``
-for the same reason, every peer's loop may be writing at once). Hybrid
+(``session/torrent.py:_hand_to_judge``: what the loop that delivered a
+piece's last block waits before it goes on, one entry a piece put to the
+judge: 0 s where the piece went to its judge task at once, else the time
+the loop's bound held it for its oldest verdict; a webseed loop's, the
+whole verdict; every peer's loop may sit there at once), and the stage
+``write`` (``_judge_and_write``: a valid piece's ``Storage.write_piece``,
+timed in the thread that makes it; a ``record`` for the same reason,
+every judge task may be writing at once). Hybrid
 authoring's SHA-1 arm is ``track("author_v1", wait=True)``
 (``models/v2._hybrid_hash_file``: a chunk's concatenation, slices and
 ``hash_pieces`` launches, and the file's tail piece; bytes are the

@@ -88,6 +88,9 @@ class PeerConnection:
     # maintained at the same mutation sites): block round-trip times for
     # the swarm telemetry's RTT histograms
     req_sent_at: dict[tuple[int, int, int], float] = field(default_factory=dict)
+    # this peer's deliveries at the judge, oldest first: (judge task,
+    # piece bytes); Torrent._hand_to_judge bounds their bytes
+    at_judge: list[tuple[asyncio.Task, int]] = field(default_factory=list)
     # memoized swarm-telemetry key (Torrent._obs_key): the per-message
     # accounting path must not rebuild the string per 16 KiB block
     obs_key: str | None = None

@@ -20,10 +20,12 @@ This is the completed design:
 - **a piece's life**: *requested* (blocks in ``_inflight_count``, owned
   by the peers asked) → *partial* (``_partials``: the picker finishes it
   first, any peer may add a block, a webseed loop may reserve it) → *at
-  the judge* (``_judging``, from the last block's landing until the
-  verdict is acted on: ``_finish_piece`` alone owns it; no scan picks
-  it, the endgame leaves it out, a webseed loop may not reserve it and a
-  late block of it is dropped, while "what is left" still counts it) →
+  the judge* (``_judging``, from the hand-off of its last block until
+  the verdict is acted on: ``_judge_piece`` alone owns it, in a judge
+  task of its own while the peer loop that delivered it reads on, up to
+  a bound; no scan picks it, the endgame leaves it out, a webseed loop
+  may not reserve it and a late block of it is dropped, while "what is
+  left" still counts it) →
   *written* (in ``bitfield``, ``have`` sent) or *refused* (missing
   again, pickable at once, the ready peers refilled).
 - **resume-recheck**: ``start()`` runs ``verify_pieces`` (hasher
@@ -78,9 +80,10 @@ _H_INGEST_VERIFY = (
     "ingest verify wall time by plane (device | hashlib_fallback)",
 )
 
-# the ledger wait a finished piece's verify is: the peer loop (or webseed
-# loop) that delivered the last block awaits the verdict and asks for
-# nothing meanwhile. One entry a piece, on either hasher.
+# what the loop that delivered a piece's last block waits for its verdict:
+# a peer loop, only while its bound holds it (_hand_to_judge, 0 s where it
+# went on at once); a webseed loop, the whole verdict. One entry a piece
+# put to the judge, on either hasher.
 _INGEST_VERDICT_WAIT = "ingest_verdict_wait"
 
 # recv-stage ledger batching: socket-wait seconds and landed block bytes
@@ -388,20 +391,26 @@ class Torrent:
         )
         self._partials: dict[int, _PartialPiece] = {}
         # pieces at the judge: the last block landed, the verdict is not
-        # acted on yet (_finish_piece owns them; nothing may request,
+        # acted on yet (_judge_piece owns them; nothing may request,
         # assemble or reserve one). The counters say it engages:
         # scans/blocks that passed such a piece over, and complete
-        # partials that reached _finish_piece for a piece already valid
+        # partials that reached _take_to_judge for a piece already valid
         # or at the judge (dropped unjudged; 0 unless the picker regresses)
         self._judging: set[int] = set()
         self._judging_skips = 0
         self._duplicate_judged = 0
+        # a peer loop hands a piece to a judge task and goes on
+        # (_hand_to_judge): hand-offs while that peer had a piece at the
+        # judge already, hand-offs the bound held, the most pieces at once
+        self._judge_overlapped = 0
+        self._judge_bound_waits = 0
+        self._judging_peak = 0
         # v2 device ingest-verification micro-batching (see
         # _verify_piece_data; v1 pieces go to ingest_scheduler)
         self._verify_pending: list = []
         self._verify_flushing = False
         # ``on_piece_verdict(index, outcome)`` is called once for every
-        # delivery _finish_piece judged ("ok" | "corrupt" | "io_error"),
+        # delivery _judge_piece judged ("ok" | "corrupt" | "io_error"),
         # in the order the verdicts were applied
         self.on_piece_verdict = None
         self._tasks: set[asyncio.Task] = set()
@@ -2773,9 +2782,9 @@ class Torrent:
             await self._cancel_everywhere(blk_key, except_peer=peer)
 
         if partial.complete:
-            await self._finish_piece(partial)
+            await self._hand_to_judge(peer, partial)
             if self.peers.get(peer.peer_id) is not peer:
-                return  # this very peer got banned/dropped by the verify
+                return  # banned/dropped by a verdict the bound waited for
         # Refill with hysteresis: topping up the one freed slot per block
         # re-runs the picker per block (an O(pieces) scan each — measured
         # at ~40% of a fast transfer's CPU, O(n²) over a download). Let
@@ -2815,20 +2824,22 @@ class Torrent:
         With the TPU hasher a v1 piece is one submission to the client's
         hash-plane scheduler, where pieces that finished on other peers
         meanwhile share its launch; otherwise per-piece hashlib
-        off-thread. Either way the caller awaits the verdict here: the
-        ledger wait ``ingest_verdict_wait``, one entry a piece.
-
-        From here until the verdict is acted on the piece is at the
-        judge (``_judging``) and this call owns it: whatever way the
-        call ends, the piece leaves the set, in the bitfield or missing
-        and pickable again.
+        off-thread. The webseed loop awaits the verdict here; a peer loop
+        hands the piece to a judge task instead (:meth:`_hand_to_judge`).
         """
+        if not self._take_to_judge(partial):
+            return "stale"
+        return await self._judge_piece(partial)
+
+    def _take_to_judge(self, partial: _PartialPiece) -> bool:
+        """The piece goes to the judge (``_judging``), and its partial with
+        it; False where it is stale or already has its judge."""
         index = partial.index
         if self._partials.get(index) is not partial:
             # Another path (endgame peer vs webseed) already finished or
             # reset this piece — finishing it twice would double-count
             # stats and KeyError on the second removal.
-            return "stale"
+            return False
         del self._partials[index]
         if index in self._judging or self.bitfield.has(index):
             # a second delivery of a piece that has its judge: the picker
@@ -2836,25 +2847,79 @@ class Torrent:
             # dropped unjudged (a second "ok" would count the piece twice)
             self._duplicate_judged += 1
             self.downloaded -= partial.length
-            return "stale"
+            return False
         self._judging.add(index)
+        self._judging_peak = max(self._judging_peak, len(self._judging))
+        return True
+
+    async def _hand_to_judge(self, peer: PeerConnection, partial: _PartialPiece) -> None:
+        """Put a piece ``peer`` completed to the judge in a task of its
+        own and go on: the peer loop reads and asks while it is judged.
+
+        The bound: a peer's pieces at the judge hold at most twice the
+        bytes its request pipeline asks for, and at least one piece. A
+        completion over it first waits for the peer's oldest verdict.
+        That hold is the ledger wait ``ingest_verdict_wait``, one entry a
+        piece put to the judge (0 s where the loop went on at once); the
+        verdict's own latency is ``_H_INGEST_VERIFY``'s.
+        """
+        bound = 2 * self.config.pipeline_depth * BLOCK_SIZE
+        queue = peer.at_judge
+        queue[:] = [e for e in queue if not e[0].done()]
+        held = 0.0
+        if queue and sum(n for _, n in queue) + partial.length > bound:
+            self._judge_bound_waits += 1
+            t0 = time.monotonic()
+            while queue and sum(n for _, n in queue) + partial.length > bound:
+                await asyncio.wait([queue[0][0]])
+                queue[:] = [e for e in queue if not e[0].done()]
+            held = time.monotonic() - t0
+        if not self._take_to_judge(partial):
+            return
+        # after the fact and without a span: every peer loop of a fast
+        # swarm may be held here at once (obs/ledger.py, `record`)
+        pipeline_ledger().record(_INGEST_VERDICT_WAIT, partial.length, held, wait=True)
+        if queue:
+            self._judge_overlapped += 1
+        task = self._spawn(self._judge_delivery(peer, partial), name=f"judge-{partial.index}")
+        queue.append((task, partial.length))
+
+    async def _judge_delivery(self, peer: PeerConnection, partial: _PartialPiece) -> None:
+        """A judge task. A judge that raises drops the peer that
+        delivered the piece, as it ended that peer's loop when the loop
+        awaited the verdict itself."""
+        try:
+            await self._judge_piece(partial)
+        except Exception:
+            log.exception("judging piece %d failed; dropping its peer", partial.index)
+            self._drop_peer(peer)
+
+    async def _judge_piece(self, partial: _PartialPiece) -> str:
+        """Judge a piece :meth:`_take_to_judge` took and act on the
+        verdict. Until then this call owns the piece: whatever way it
+        ends, the piece leaves ``_judging``, in the bitfield or missing
+        and pickable again."""
+        index = partial.index
         try:
             outcome = await self._judge_and_write(partial)
         except (asyncio.CancelledError, Exception):
-            # the verdict never came (the judge raised, the awaiting loop
-            # was cancelled): the piece is missing again, as after a refusal
-            if not self._stopping:
+            if self.bitfield.has(index):
+                # written before a cancel reached it (_judge_and_write)
+                self._verdict(index, "ok")
+            elif not self._stopping:
+                # the verdict never came (the judge raised): the piece is
+                # missing again, as after a refusal
                 self._spawn(self._refill_ready_peers(), name="refill-unjudged")
             raise
         finally:
             self._judging.discard(index)
         if outcome != "ok":
             self._verdict(index, outcome)
-            # Missing again and pickable at once. The delivering peer
-            # refills itself (_ingest_block's tail) unless this verdict
-            # banned it; peers that found nothing to ask while the piece
-            # was judged sit starved on an empty pipeline and no message
-            # of theirs is due, so they are reached from here.
+            # Missing again and pickable at once. Peers that found nothing
+            # to ask while the piece was judged (the delivering one too:
+            # it refilled at the hand-off) may sit starved on an empty
+            # pipeline with no message of theirs due, so they are reached
+            # from here.
             await self._refill_ready_peers()
             return outcome
         self._notify_piece(index)
@@ -2887,15 +2952,7 @@ class Torrent:
         its bit. Returns ``"ok"`` | ``"corrupt"`` | ``"io_error"``."""
         data = bytes(partial.buffer)
         expected = self.info.pieces[partial.index]
-        t0 = time.monotonic()
-        try:
-            valid = await self._verify_piece_data(partial.index, data, expected)
-        finally:
-            # after the fact and without a span: every peer loop of a
-            # fast swarm may sit here at once (obs/ledger.py, `record`)
-            pipeline_ledger().record(
-                _INGEST_VERDICT_WAIT, len(data), time.monotonic() - t0, wait=True
-            )
+        valid = await self._verify_piece_data(partial.index, data, expected)
         if not valid:
             log.warning("piece %d failed verification; re-requesting", partial.index)
             self.downloaded -= partial.length  # don't count poisoned data
@@ -2907,11 +2964,21 @@ class Torrent:
             if len(data) <= INLINE_IO_MAX:
                 seconds = self._write_piece(base, data)  # µs-scale pwrite: no hop
             else:
-                seconds = await asyncio.to_thread(self._write_piece, base, data)
+                write = asyncio.ensure_future(asyncio.to_thread(self._write_piece, base, data))
+                try:
+                    seconds = await asyncio.shield(write)
+                except asyncio.CancelledError:
+                    # a cancel (stop()) cannot call back a write in its
+                    # thread: it waits for the bytes and the bit follows
+                    # them, so no piece on disk is one the torrent misses
+                    await asyncio.wait([write])
+                    if write.exception() is None:
+                        self.bitfield.set(partial.index)
+                    raise
         except StorageError as e:
             log.error("failed to persist piece %d: %s", partial.index, e)
             return "io_error"
-        # after the fact and without a span, as the verdict's wait above
+        # after the fact and without a span: many judge tasks may write at once
         pipeline_ledger().record("write", len(data), seconds)
         self.bitfield.set(partial.index)
         return "ok"
@@ -3076,12 +3143,14 @@ class Torrent:
 
     async def _verify_on_scheduler(self, data: bytes, expected: bytes) -> bool:
         """A finished v1 piece as one ``verify`` submission of the tenant
-        ``ingest``. ``wait=True``: a full queue holds the peer loop back,
-        it never sheds a piece. ``flush=True``: the caller awaits this
-        very future and requests nothing until it resolves, which is the
-        hint's definition — the lane takes at once, whatever finished on
-        other peers meanwhile rides along, and the launch runs at the
-        smallest warmed rung that holds the take. A submission the
+        ``ingest``. ``wait=True``: a full queue holds the judge task back,
+        and through the bound of :meth:`_hand_to_judge` its peer loop; it
+        never sheds a piece. ``flush=True``: the submitter is the piece's
+        judge task, which awaits this very future and submits nothing
+        else until it resolves, which is the hint's definition — the lane
+        takes at once, whatever finished on other peers (or on the same
+        peer, a piece earlier) meanwhile rides along, and the launch runs
+        at the smallest warmed rung that holds the take. A submission the
         scheduler rejects (closing) or fails (retry and bisection
         exhausted) is judged by hashlib instead, counted under
         ``plane="hashlib_fallback"``."""
@@ -3726,7 +3795,7 @@ class Torrent:
             for partial, data in zip(reserved, datas):
                 if self._partials.get(partial.index) is not partial:
                     # An endgame peer completed this piece while the HTTP
-                    # fetch was in flight — its _finish_piece already ran;
+                    # fetch was in flight — it went to the judge already;
                     # finishing ours too would double-count stats.
                     continue
                 # Count only bytes the webseed actually contributed (endgame
@@ -3741,7 +3810,12 @@ class Torrent:
                 partial.contributors.clear()
                 partial.received = set(range(0, partial.length, BLOCK_SIZE))
                 self.downloaded += partial.length - already
+                t0 = time.monotonic()
                 outcome = await self._finish_piece(partial)
+                if outcome != "stale":  # this loop waited for the verdict
+                    pipeline_ledger().record(
+                        _INGEST_VERDICT_WAIT, partial.length, time.monotonic() - t0, wait=True
+                    )
                 if outcome == "corrupt":
                     hash_failures += 1
             if hash_failures:
@@ -3847,6 +3921,9 @@ class Torrent:
             "judging": len(self._judging),
             "judging_skips": self._judging_skips,
             "duplicate_judged": self._duplicate_judged,
+            "judge_overlapped": self._judge_overlapped,
+            "judge_bound_waits": self._judge_bound_waits,
+            "judging_peak": self._judging_peak,
             "max_upload_bps": self.config.max_upload_bps,
             "max_download_bps": self.config.max_download_bps,
             "serve": {

@@ -1224,6 +1224,27 @@ def render_metrics(client) -> str:
             "Complete deliveries of a piece already valid or at the judge",
             of_status("duplicate_judged"),
         ),
+        # the peer loop hands a piece to a judge task and goes on: how often
+        # its peer had one there already, how often the bound held it, and
+        # the most pieces at the judge at once
+        (
+            "torrent_tpu_torrent_judge_overlapped_total",
+            "counter",
+            "Deliveries handed to the judge while the same peer had a piece there",
+            of_status("judge_overlapped"),
+        ),
+        (
+            "torrent_tpu_torrent_judge_bound_waits_total",
+            "counter",
+            "Hand-offs that first waited for the peer's oldest verdict",
+            of_status("judge_bound_waits"),
+        ),
+        (
+            "torrent_tpu_torrent_judging_peak",
+            "gauge",
+            "Most pieces at the judge at once since the torrent started",
+            of_status("judging_peak"),
+        ),
     ]
     for name, kind, help_text, get in per_torrent:
         lines.append(f"# HELP {name} {help_text}")

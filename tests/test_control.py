@@ -38,6 +38,7 @@ from torrent_tpu.sched import (
     SchedulerConfig,
 )
 from torrent_tpu.sched.control import build_inputs, decide, initial_state
+from torrent_tpu.sched.scheduler import Plane
 
 from test_metrics import prom_lint
 
@@ -402,16 +403,16 @@ class TestBuildInputs:
 # --------------------------------------------------- scheduler actuators
 
 
-class _GeomPlane:
-    """Fake plane with a tile-snapping geometry hook (1024-row granule)."""
+class _GeomPlane(Plane):
+    """Fake plane that launches in 1024-row tiles (its granule)."""
+
+    granule = 1024
 
     def __init__(self, algo):
         self._h = hashlib.sha256 if algo == "sha256" else hashlib.sha1
 
-    @staticmethod
-    def launch_geometry(n_rows: int, bucket: int):
-        rows = (n_rows + 1023) // 1024 * 1024
-        return rows, rows * bucket
+    def launch_rows(self, n_rows: int) -> int:
+        return -(-n_rows // 1024) * 1024
 
     def run(self, payloads):
         return [self._h(bytes(p)).digest() for p in payloads]
@@ -423,7 +424,7 @@ class TestActuatorSeams:
             sched = HashPlaneScheduler(
                 SchedulerConfig(
                     batch_target=8, flush_deadline=0.01,
-                    plane_factory=lambda algo, bucket, batch: _GeomPlane(algo),
+                    plane_factory=lambda algo, bucket, batch, sha256_backend=None: _GeomPlane(algo),
                 ),
                 hasher="cpu",
             )
@@ -451,7 +452,7 @@ class TestActuatorSeams:
                 SchedulerConfig(
                     batch_target=8, flush_deadline=0.01,
                     staging_budget=320000,
-                    plane_factory=lambda algo, bucket, batch: _GeomPlane(algo),
+                    plane_factory=lambda algo, bucket, batch, sha256_backend=None: _GeomPlane(algo),
                 ),
                 hasher="tpu",
             )
@@ -474,7 +475,7 @@ class TestActuatorSeams:
                 SchedulerConfig(
                     batch_target=8, flush_deadline=0.01,
                     staging_budget=128 * 500,
-                    plane_factory=lambda algo, bucket, batch: _GeomPlane(algo),
+                    plane_factory=lambda algo, bucket, batch, sha256_backend=None: _GeomPlane(algo),
                 ),
                 hasher="tpu",
             )

@@ -581,9 +581,15 @@ class TestTwoProcessFabric:
         items, tdir, ddir = make_library(tmp_path, [96, 160], seed=11)
         total = sum(info.num_pieces for _, info in items)
 
+        # A peer the survivor has never heard from ages from the
+        # survivor's own start: with the shared 1.5 s window, a worker 1
+        # that starts 1.5 s later (a loaded host) is lapsed before its
+        # first heartbeat, and the survivor adopts and verifies its whole
+        # shard, the one unit it then publishes included. The survivor's
+        # window is 6 s, so only the death is a lapse.
         rcs, errs = _spawn_workers(
             tdir, ddir, tmp_path, 2,
-            extra_by_pid={1: ["--die-after-units", "1"]},
+            extra_by_pid={0: ["--lapse-after", "6"], 1: ["--die-after-units", "1"]},
         )
         assert rcs[0] == 0, errs[0]
         assert rcs[1] == FAULT_EXIT_CODE, errs[1]

@@ -31,6 +31,7 @@ from torrent_tpu.sched import (
     SchedulerConfig,
     classify_error,
 )
+from torrent_tpu.sched.scheduler import Plane
 
 
 def run(coro):
@@ -64,7 +65,7 @@ def _build_torrent(length, piece_len, seed=0, name="s"):
     return info, storage
 
 
-class _StallPlane:
+class _StallPlane(Plane):
     """Blocks until released — pins queue bytes deterministically."""
 
     def __init__(self):
@@ -366,7 +367,7 @@ class TestCircuitBreaker:
         (not reset it via record_success), and callers still get correct
         digests instead of an unbounded retry+bisection cascade."""
 
-        class _ShortPlane:
+        class _ShortPlane(Plane):
             def run(self, payloads):
                 return _sha1(payloads)[:-1]  # always one digest short
 
@@ -377,7 +378,7 @@ class TestCircuitBreaker:
                     flush_deadline=0.02,
                     breaker_threshold=2,
                     breaker_cooldown=30.0,
-                    plane_factory=lambda a, b, t: _ShortPlane(),
+                    plane_factory=lambda a, b, t, sha256_backend=None: _ShortPlane(),
                 ),
                 hasher="cpu",
             )
@@ -605,7 +606,7 @@ class TestAbandonedSubmission:
                     batch_target=1,
                     flush_deadline=0.01,
                     max_queue_bytes=64 << 10,
-                    plane_factory=lambda a, b, t: stall,
+                    plane_factory=lambda a, b, t, sha256_backend=None: stall,
                 ),
                 hasher="cpu",
             )
@@ -647,7 +648,7 @@ class TestAbandonedSubmission:
             server = await BridgeServer(port=0, hasher="cpu").start()
             try:
                 stall = _StallPlane()
-                server.sched.config.plane_factory = lambda a, b, t: stall
+                server.sched.config.plane_factory = lambda a, b, t, sha256_backend=None: stall
                 body = bencode({b"pieces": [b"q" * 4096]})
                 reader, writer = await asyncio.open_connection(
                     "127.0.0.1", server.port

@@ -19,6 +19,7 @@ import pytest
 
 from torrent_tpu.codec.bencode import bdecode, bencode
 from torrent_tpu.sched import HashPlaneScheduler, SchedRejected, SchedulerConfig
+from torrent_tpu.sched.scheduler import Plane
 
 
 def run(coro):
@@ -29,7 +30,7 @@ def _pieces(n: int, plen: int = 1024, salt: int = 0) -> list[bytes]:
     return [bytes([(i + salt) % 251]) * plen for i in range(n)]
 
 
-class _StallPlane:
+class _StallPlane(Plane):
     """Test plane that blocks until released — pins queue bytes so
     admission-control behaviour is deterministic, no timing involved."""
 
@@ -197,7 +198,87 @@ def _step_cache_sizes(plane) -> tuple[int, int]:
     """Programs held by the plane's two jitted digest steps (flat road,
     sharded road)."""
     v = plane._verifier
-    return v._digest_step_flat._cache_size(), v._digest_step_donated._cache_size()
+    return v._digest_step_flat._cache_size(), v._digest_step._cache_size()
+
+
+@pytest.fixture(scope="module", params=["cpu", "sha1", "scan", "pallas"])
+def any_plane(request):
+    """``(kind, algo, plane, batch)``: one built plane of each kind at
+    64-byte pieces and a 64-piece batch — hashlib, the SHA-1 device
+    plane (a (32, 64) ladder over the tests' 8 devices), the scan plane
+    and the pallas plane (interpret mode off a TPU; its batch is one
+    1024-row tile)."""
+    from torrent_tpu.sched.scheduler import (
+        _CpuPlane,
+        _Sha1DevicePlane,
+        _Sha256DevicePlane,
+        _Sha256PallasPlane,
+    )
+
+    kind = request.param
+    if kind == "cpu":
+        return kind, "sha1", _CpuPlane("sha1"), 64
+    if kind == "sha1":
+        return kind, "sha1", _Sha1DevicePlane(64, 64), 64
+    if kind == "scan":
+        return kind, "sha256", _Sha256DevicePlane(64, 64), 64
+    return kind, "sha256", _Sha256PallasPlane(64, 64), 1024
+
+
+class TestPlaneInterface:
+    """Every plane, and a FaultyPlane around each, offers the whole
+    interface; both roads give hashlib's digests; ``launch_rows`` is
+    what the copying road really stages."""
+
+    @pytest.mark.parametrize("wrapped", [False, True], ids=["bare", "faulty"])
+    @pytest.mark.parametrize("fill", ["one", "partial", "full"])
+    def test_members_digests_and_launched_rows(self, any_plane, wrapped, fill, monkeypatch):
+        from torrent_tpu.ops.sha256_pallas import SUB_TILE_ROWS
+        from torrent_tpu.sched.faults import FaultPlan, FaultyPlane
+        from torrent_tpu.sched.scheduler import StagedSlab, _StagingSlots
+
+        kind, algo, inner, batch = any_plane
+        plane = FaultyPlane(FaultPlan(), inner) if wrapped else inner
+        for name in ("kernel", "granule", "launch_rows", "run", "run_staged"):
+            assert hasattr(plane, name), name
+        assert plane.kernel == inner.kernel and plane.kernel is not None
+        assert plane.granule == (SUB_TILE_ROWS if kind == "pallas" else 1)
+
+        n = {"one": 1, "partial": 5, "full": batch}[fill]
+        h = hashlib.sha256 if algo == "sha256" else hashlib.sha1
+        payloads = _ragged(n, 64, salt=n)
+        want = [h(p).digest() for p in payloads]
+
+        # the copying road: the rows of every slot the launch staged
+        staged: list[int] = []
+        if kind != "cpu":
+            real = inner._slots.stage
+
+            def stage(chunk, rows):
+                out = real(chunk, rows)
+                staged.append(len(out[2]))
+                return out
+
+            monkeypatch.setattr(inner._slots, "stage", stage)
+        assert plane.run(payloads) == want
+        assert plane.launch_rows(n) == (sum(staged) if kind != "cpu" else n)
+        want_rows = {"cpu": n, "sha1": 32 if n <= 32 else 64, "scan": 64, "pallas": 1024}
+        assert plane.launch_rows(n) == want_rows[kind]
+
+        # the zero-copy road: a slab filled as a reader fills it, its
+        # rows asked for in reverse, launched in place with no stage
+        pool = _StagingSlots(batch, 64)
+        slab = StagedSlab(pool, pool.checkout(), algo, 64, 64)
+        slab.prepare([len(p) for p in payloads])
+        for i, p in enumerate(payloads):
+            slab.view[i, : len(p)] = np.frombuffer(p, dtype=np.uint8)
+        slab.finalize([True] * n)
+        staged.clear()
+        try:
+            assert plane.run_staged(slab, list(range(n))[::-1]) == want[::-1]
+        finally:
+            slab.release()
+        assert staged == [] and pool.stats()[0] == 0
 
 
 class TestSha1RowLadder:
@@ -248,7 +329,7 @@ class TestSha1RowLadder:
         assert ladder_plane._ladder == (32, 64, 128, 256)
         assert ladder_plane.launch_rows(n) == rows
         if n <= 256:
-            assert ladder_plane._rung_for(n) == rows
+            assert ladder_plane._rows_for(n) == rows
 
     @pytest.mark.parametrize("n", [1, 15, 32, 33, 64, 100, 128, 200, 256, 257])
     def test_digests_match_hashlib_at_every_rung(self, ladder_plane, n):
@@ -285,8 +366,9 @@ class TestSha1RowLadder:
         assert _step_cache_sizes(ladder_plane) == sizes
 
     def test_geometry_hook_leaves_targets_alone(self, ladder_plane):
-        assert ladder_plane.launch_geometry(100, 192)[0] == 100
-        assert ladder_plane.launch_geometry(1, 192)[0] == 1
+        """The SHA-1 plane's granule is 1: any row count is a flush
+        target (the ladder follows the chunk, not the target)."""
+        assert ladder_plane.granule == 1
 
     @pytest.mark.parametrize("target", [1, 37, 100, 255, 300])
     def test_set_lane_target_applies_any_row_count_on_a_built_lane(self, target):
@@ -705,7 +787,7 @@ class TestFairnessAndBackpressure:
                 max_queue_bytes=64 << 10,
                 max_tenant_bytes=16 << 10,
                 drr_quantum=2048,  # small quantum → per-pass interleave
-                plane_factory=lambda a, b, t: stall,
+                plane_factory=lambda a, b, t, sha256_backend=None: stall,
             )
             sched = HashPlaneScheduler(cfg, hasher="cpu")
             try:
@@ -754,7 +836,7 @@ class TestFairnessAndBackpressure:
         async def go():
             order: list[str] = []
 
-            class _RecordingPlane:
+            class _RecordingPlane(Plane):
                 def run(self, payloads):
                     order.append(f"launch:{len(payloads)}")
                     return [hashlib.sha1(p).digest() for p in payloads]
@@ -762,7 +844,7 @@ class TestFairnessAndBackpressure:
             stall = _StallPlane()
             first = [True]
 
-            class _GatePlane:
+            class _GatePlane(Plane):
                 # first launch stalls (pins the queue while we enqueue),
                 # later launches record
                 def run(self, payloads):
@@ -775,7 +857,7 @@ class TestFairnessAndBackpressure:
                 batch_target=8,
                 flush_deadline=0.02,
                 drr_quantum=1024,
-                plane_factory=lambda a, b, t: _GatePlane(),
+                plane_factory=lambda a, b, t, sha256_backend=None: _GatePlane(),
             )
             sched = HashPlaneScheduler(cfg, hasher="cpu")
             try:
@@ -822,7 +904,7 @@ class TestFairnessAndBackpressure:
                 batch_target=4,
                 flush_deadline=0.01,
                 max_queue_bytes=8 << 10,
-                plane_factory=lambda a, b, t: stall,
+                plane_factory=lambda a, b, t, sha256_backend=None: stall,
             )
             sched = HashPlaneScheduler(cfg, hasher="cpu")
             try:
@@ -876,7 +958,7 @@ class TestFairnessAndBackpressure:
         async def go():
             stall = _StallPlane()
             cfg = SchedulerConfig(
-                max_queue_bytes=2048, plane_factory=lambda a, b, t: stall
+                max_queue_bytes=2048, plane_factory=lambda a, b, t, sha256_backend=None: stall
             )
             sched = HashPlaneScheduler(cfg, hasher="cpu")
             try:
@@ -1028,7 +1110,9 @@ class TestSha256PallasLane:
                 assert got == [hashlib.sha256(p).digest() for p in pieces]
                 lane = sched.metrics_snapshot()["lane_stats"]["sha256/256"]
                 assert lane["backend"] == "scan"
-                assert lane["pad_rows_total"] == 0  # scan launches are row-exact
+                # a scan launch is the whole batch: 5 live rows of 8
+                assert lane["launched_rows_total"] == 8
+                assert lane["pad_rows_total"] == 8 - 5
             finally:
                 await sched.close()
 
@@ -1124,7 +1208,7 @@ class TestSha256PallasLane:
                     batch_target=64,
                     flush_deadline=0.02,
                     max_queue_bytes=4 * row,
-                    plane_factory=lambda a, b, t: stall,
+                    plane_factory=lambda a, b, t, sha256_backend=None: stall,
                 ),
                 hasher="tpu",
             )
@@ -1155,14 +1239,14 @@ class TestSha256PallasLane:
         seam: a FaultPlan wrapping the pallas plane still trips the lane
         to the CPU plane (digests stay correct) and recovers via the
         half-open probe back onto pallas; FaultyPlane delegates the
-        launch_geometry hook to the wrapped plane."""
-        from torrent_tpu.ops.padding import padded_len_for
+        granule and launch_rows to the wrapped plane."""
         from torrent_tpu.sched import FaultPlan
 
         plan = FaultPlan(fail_first=2)
         factory = plan.plane_factory(hasher="tpu", sha256_backend="pallas")
         wrapped = factory("sha256", 256, 1024)
-        assert wrapped.launch_geometry(5, 256) == (1024, 1024 * padded_len_for(256))
+        assert wrapped.granule == 1024 and wrapped.kernel == "pallas"
+        assert wrapped.launch_rows(5) == 1024
 
         async def go():
             sched = HashPlaneScheduler(
@@ -1425,7 +1509,7 @@ class TestBridgeScheduler:
             ).start()
             try:
                 stall = _StallPlane()
-                server.sched.config.plane_factory = lambda a, b, t: stall
+                server.sched.config.plane_factory = lambda a, b, t, sha256_backend=None: stall
                 # first request fills the 1 MiB budget and stalls in-plane
                 big = asyncio.ensure_future(
                     _post(

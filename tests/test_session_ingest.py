@@ -928,6 +928,9 @@ def test_a_completion_over_the_bound_waits_for_the_peers_oldest_verdict(tmp_path
         assert t._judging == set(range(allowed)) and sorted(gate.asked) == list(range(allowed))
         assert verdict_wait() == (ops0 + allowed, busy0)  # went on at once: an entry each, 0 s
         held = asyncio.ensure_future(deliver(t, peer, payload, allowed))
+        # the hold is timed from its start, which follows the delivery's
+        # own blocks: sleep only once it has begun
+        await eventually(lambda: t.status()["judge_bound_waits"] == 1, "held at the bound")
         await asyncio.sleep(0.05)
         assert not held.done() and t.status()["judge_bound_waits"] == 1
         assert allowed in t._partials and allowed not in t._judging  # complete, not handed over yet

@@ -656,7 +656,6 @@ class TestStepModuleNames:
             v._digest_step_flat.lower(u32, nb),
             v._verify_step.lower(u8, nb, exp),
             v._digest_step.lower(u8, nb),
-            v._digest_step_donated.lower(u8, nb),
         }
         names = {re.search(r"module @(\S+)", lo.as_text()).group(1) for lo in lowered}
         assert names == STEP_MODULE_NAMES

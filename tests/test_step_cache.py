@@ -4,7 +4,7 @@
 object it wraps. A ``TPUVerifier`` used to wrap five closures of its own,
 so the first call of every recheck pass traced the scan and loaded its
 program again; now every verifier of one (backend, tile_sub, mesh) takes
-the same five jitted objects from a small cache. These cases hold the
+the same four jitted objects from a small cache. These cases hold the
 mechanism on the CPU: what is shared, what gets an entry of its own, that
 sharing changes no verdict, the bound, the fill under threads, and the
 two counters ``/metrics`` renders. What it is worth in seconds only the
@@ -30,7 +30,7 @@ from torrent_tpu.parallel.verify import verify_pieces_tpu
 from torrent_tpu.storage.storage import MemoryStorage, Storage
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-STEPS = ("_digest_step", "_verify_step", "_verify_step_flat", "_digest_step_flat", "_digest_step_donated")
+STEPS = ("_digest_step", "_verify_step", "_verify_step_flat", "_digest_step_flat")
 
 
 @pytest.fixture
@@ -67,7 +67,7 @@ def test_two_verifiers_of_one_key_hold_the_same_five_jitted_objects(cache):
     b = TPUVerifier(piece_length=16384, batch_size=16, mesh=_mesh(1))
     for name in STEPS:
         assert getattr(a, name) is getattr(b, name), name
-    assert len({id(getattr(a, name)) for name in STEPS}) == 5
+    assert len({id(getattr(a, name)) for name in STEPS}) == 4
     assert step_cache_stats() == {"step_builds": 1, "step_reuses": 1}
 
 

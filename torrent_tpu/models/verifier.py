@@ -77,13 +77,12 @@ STEP_CACHE_CAPACITY = 8
 
 
 class _Steps(NamedTuple):
-    """The five jitted steps of one (backend, tile_sub, mesh)."""
+    """The four jitted steps of one (backend, tile_sub, mesh)."""
 
     digest_step: Callable
     verify_step: Callable
     verify_step_flat: Callable
     digest_step_flat: Callable
-    digest_step_donated: Callable
 
 
 def _on_cpu(mesh) -> bool:
@@ -174,26 +173,16 @@ def _build_steps(backend: str, tile_sub: int | None, mesh) -> _Steps:
     def _digests_flat(chunks, nblocks):
         return sha1_fn(_join(chunks), nblocks)
 
-    # Donate the uploaded chunks on real accelerators: the launch
-    # consumes them exactly once, so freeing the device input buffer
-    # as the kernel runs lets the NEXT batch's H2D reuse that memory
-    # — the double-buffered ingest contract the scheduler's sha1
-    # plane relies on. XLA-CPU refuses donation (it would only emit
-    # a warning per launch), so it stays off there.
-    _donate = () if _on_cpu(mesh) else (0,)
+    # Nothing is donated: XLA gives a donated input's buffer only to an
+    # output of its shape, and every step here returns bools or digest
+    # words from a batch of pieces.
     return _Steps(
         digest_step=jax.jit(_digests, in_shardings=(shard, shard), out_shardings=shard),
         verify_step=jax.jit(
             _verify, in_shardings=(shard, shard, shard), out_shardings=shard
         ),
-        verify_step_flat=jax.jit(_verify_flat, donate_argnums=_donate),
-        digest_step_flat=jax.jit(_digests_flat, donate_argnums=_donate),
-        # the sharded twin of the donated digest step, for upload_batch
-        # on a >1-device mesh (compiled only if that path runs)
-        digest_step_donated=jax.jit(
-            _digests, in_shardings=(shard, shard), out_shardings=shard,
-            donate_argnums=_donate,
-        ),
+        verify_step_flat=jax.jit(_verify_flat),
+        digest_step_flat=jax.jit(_digests_flat),
     )
 
 
@@ -410,7 +399,6 @@ class TPUVerifier:
         self._verify_step = steps.verify_step
         self._verify_step_flat = steps.verify_step_flat
         self._digest_step_flat = steps.digest_step_flat
-        self._digest_step_donated = steps.digest_step_donated
         # 4 concurrent upload streams: chosen on a retired setup, not
         # measured on this one.
         self._upload_chunks = env_int("TORRENT_TPU_UPLOAD_CHUNKS", 4)
@@ -624,14 +612,13 @@ class TPUVerifier:
 
         Returns the device words array WITHOUT fetching — the caller's
         ``np.asarray`` is the D2H boundary (the scheduler accounts it as
-        the ledger's ``digest`` stage). The handle is donated to the
-        launch on real accelerators; it must not be reused.
+        the ledger's ``digest`` stage).
         """
         kind, data = handle
         if kind == "flat":
             return self._digest_step_flat(data, nblocks)
         dev_n = jax.device_put(np.asarray(nblocks), self._shard)
-        return self._digest_step_donated(data, dev_n)
+        return self._digest_step(data, dev_n)
 
     # ------------------------------------------------------------ authoring
 

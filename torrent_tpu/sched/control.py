@@ -17,9 +17,8 @@ drives four actuators, each individually config-gated:
   bigger launches amortize the fixed cost; the flush deadline follows
   so partial flushes have time to fill. When demand falls (fill <
   ``fill_low``) the target returns toward the static plan. Applied
-  targets snap to what the built plane actually stages via its
-  existing ``launch_geometry`` hook — a pallas lane's grown target is
-  always a tile multiple.
+  targets snap to the lane plane's ``granule`` — a pallas lane's grown
+  target is always a tile multiple.
 * **Admission budgets that follow the limiting stage**
   (``adapt_admission``): when a bottleneck is confirmed, stop admitting
   faster than it drains — the effective global queue budget becomes

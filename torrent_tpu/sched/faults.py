@@ -162,20 +162,14 @@ class FaultPlan:
             algo: str, bucket: int, batch: int, sha256_backend: str | None = None
         ):
             backend = sha256_backend if sha256_backend is not None else pin
-            from torrent_tpu.sched.scheduler import (
-                accepts_sha256_backend,
-                build_builtin_plane,
-            )
-
             if base_factory is not None:
-                # forward the resolved backend when the base factory can
-                # take it — a nested builder pinning 'pallas' on its own
-                # would bypass the budget fallback just like we would
-                if accepts_sha256_backend(base_factory):
-                    inner = base_factory(algo, bucket, batch, sha256_backend=backend)
-                else:
-                    inner = base_factory(algo, bucket, batch)
+                # forward the resolved backend: a nested factory pinning
+                # 'pallas' on its own would bypass the budget fallback
+                # just like we would
+                inner = base_factory(algo, bucket, batch, sha256_backend=backend)
             else:
+                from torrent_tpu.sched.scheduler import build_builtin_plane
+
                 inner = build_builtin_plane(
                     hasher, algo, bucket, batch, sha256_backend=backend
                 )
@@ -193,20 +187,19 @@ class FaultyPlane:
         self.launches = 0
         self._lock = named_lock("sched.faulty_plane._lock")
 
-    def launch_geometry(self, n_rows: int, bucket: int) -> tuple[int, int]:
-        """Faults change nothing about staging: delegate to the wrapped
-        plane's geometry (row-exact if it exposes none)."""
-        hook = getattr(self.inner, "launch_geometry", None)
-        if hook is None:
-            return n_rows, 0
-        return hook(n_rows, bucket)
+    # faults change nothing about what a launch runs or stages: every
+    # member of the plane interface is the wrapped plane's
+
+    @property
+    def kernel(self):
+        return self.inner.kernel
+
+    @property
+    def granule(self) -> int:
+        return self.inner.granule
 
     def launch_rows(self, n_rows: int) -> int:
-        """Likewise for the rows a copying launch stages (the SHA-1
-        plane's row ladder); row-exact where the wrapped plane says
-        nothing."""
-        rows_of = getattr(self.inner, "launch_rows", None)
-        return n_rows if rows_of is None else rows_of(n_rows)
+        return self.inner.launch_rows(n_rows)
 
     def _apply_faults(self, payloads) -> None:
         """Count the launch and raise per the plan. ``payloads`` may be
@@ -258,13 +251,8 @@ class FaultyPlane:
 
     def run_staged(self, slab, rows: list[int]) -> list[bytes]:
         """Zero-copy launch form: same fault plan, applied to the slab's
-        ticket rows, then delegated to the wrapped plane's staged path
-        (or its copy path when it has none)."""
+        ticket rows, then delegated to the wrapped plane's staged path."""
         from torrent_tpu.sched.scheduler import SlotRow
 
-        slot_rows = [SlotRow(slab, r) for r in rows]
-        self._apply_faults(slot_rows)
-        inner_staged = getattr(self.inner, "run_staged", None)
-        if inner_staged is not None:
-            return inner_staged(slab, rows)
-        return self.inner.run(slot_rows)
+        self._apply_faults([SlotRow(slab, r) for r in rows])
+        return self.inner.run_staged(slab, rows)

@@ -91,23 +91,26 @@ land disk reads directly in its row-strided view, and submit it with
 per-piece ``bytes``), single-slab launches hit the planes'
 ``run_staged`` form (the slab IS the launch buffer — the ledger's
 ``stage`` copy stage records zero bytes), and device planes H2D the
-slab outside ``_device_lock`` with donated input buffers so batch
-N+1's transfer overlaps batch N's kernel. Slabs are reference counted
+slab outside ``_device_lock`` so batch N+1's transfer overlaps batch
+N's kernel. Slabs are reference counted
 (one ref per ticket, released at demux on every path) and the pools'
 ``outstanding`` gauge must return to 0 — see ARCHITECTURE.md
 "Zero-copy ingest" for ownership rules and the fallback matrix.
 
-The SHA-1 device plane's copying road launches at the rows a flush
-holds: the smallest rung of a fixed, warmed row ladder
-(:func:`_row_ladder`) that takes the chunk, so a sparse deadline flush
-stages, uploads and hashes 32 rows where a full take uses the plane's
-whole batch. ``lane_stats`` counts every attempt's launched and pad rows.
+Every plane offers one interface (:class:`Plane`: ``kernel``,
+``granule``, ``launch_rows``, ``run``, ``run_staged``), and the three
+device planes write their launch once (:class:`_DevicePlane`). The
+SHA-1 device plane's copying road launches at the rows a flush holds:
+the smallest rung of a fixed, warmed row ladder (:func:`_row_ladder`)
+that takes the chunk, so a sparse deadline flush stages, uploads and
+hashes 32 rows where a full take uses the plane's whole batch.
+``lane_stats`` counts every attempt's launched and pad rows.
 
 The scheduler autopilot (``sched/control.py``) closes the observe→act
 loop over these sensors: a periodic controller turns ledger/attribution
 snapshot deltas into bounded actuator moves through the seams below —
 ``set_lane_target`` / ``set_lane_deadline`` (adaptive batching, snapped
-via the planes' ``launch_geometry`` hooks), ``set_admission_factor``
+to the planes' ``granule``), ``set_admission_factor``
 (admit no faster than the limiting stage drains), and
 ``steer_lane_backend`` (hysteresis-guarded backend trials). With no
 autopilot attached every seam stays at its static default and behavior
@@ -127,6 +130,7 @@ from __future__ import annotations
 
 import asyncio
 import bisect
+import functools
 import hashlib
 import time
 from collections import deque
@@ -233,8 +237,9 @@ class SchedulerConfig:
     # (explicitly registered tenants are pinned) — bounds the state an
     # attacker can create with fresh X-Tenant values per request
     max_idle_tenants: int = 1024
-    # test/extension hook: (algo, bucket, batch) -> plane with
-    # .run(payloads) -> list[digest]; None = built-in planes
+    # test/extension hook: (algo, bucket, batch, *, sha256_backend) ->
+    # a Plane (the lane's resolved v2 backend, or None); None = built-in
+    # planes
     plane_factory: Callable | None = None
     # relaunches of a failed batch before bisection, transient errors
     # only (a deterministic payload error skips straight to bisection)
@@ -571,23 +576,6 @@ def build_builtin_plane(
     return _Sha1DevicePlane(bucket, batch)
 
 
-def accepts_sha256_backend(fn) -> bool:
-    """Whether a plane-factory callable takes the optional
-    ``sha256_backend`` kwarg — the seam stays backward compatible with
-    3-arg factories, but a factory that can take the lane's resolved
-    backend must get it (a 'pallas' pin must not override a
-    budget-forced scan fallback; see :meth:`_build_plane`)."""
-    import inspect
-
-    try:
-        params = inspect.signature(fn).parameters
-    except (TypeError, ValueError):  # builtins/partials w/o signature
-        return False
-    return "sha256_backend" in params or any(
-        p.kind is inspect.Parameter.VAR_KEYWORD for p in params.values()
-    )
-
-
 class _StagingSlots:
     """Reusable tail-zeroed staging slots shared by the device planes.
 
@@ -629,13 +617,13 @@ class _StagingSlots:
             slot = (padded, view, np.zeros(self.rows, dtype=np.int64))
         return slot
 
-    def stage(self, chunk: list[bytes], rows: int | None = None):
+    def stage(self, chunk: list[bytes], rows: int):
         """Checkout a slot and stage ``chunk`` into its first ``rows``
-        rows (default: the whole slot).
+        rows.
 
         Returns ``(slot, padded, nblocks)`` with ``nblocks`` of length
         ``rows``; rows past ``len(chunk)`` are ``nblocks=0`` sentinels.
-        Bounding ``rows`` to the launch (the pallas plane's tile bucket)
+        Bounding ``rows`` to the launch (a plane's ``launch_rows``)
         skips the staging work for slot rows the launch never reads —
         untouched rows keep their recorded extents, so later reuse still
         tail-zeroes them correctly. The caller runs its launch, then
@@ -645,7 +633,6 @@ class _StagingSlots:
 
         from torrent_tpu.ops.padding import alloc_padded, pad_in_place
 
-        rows = self.rows if rows is None else rows
         # pipeline-ledger "stage" boundary: the host copy into the
         # staging slot (the tracker's lock is leaf-scoped at entry/exit;
         # the copy itself runs outside any obs lock)
@@ -859,31 +846,40 @@ def _masked_nblocks(slab: StagedSlab, rows: list[int]):
     return nb
 
 
-def _donating_wrapper(fn):
-    """Jit-wrap ``fn(data, nblocks)`` donating the data buffer on real
-    accelerators (H2D of batch N+1 then overlaps the kernel of batch N
-    without doubling device-resident input memory). On the CPU backend
-    donation is refused by XLA and would only warn, so the fn is
-    returned unwrapped."""
-    import jax
+class Plane:
+    """What the scheduler asks of a plane, every member of it, so no
+    caller probes for one.
 
-    if jax.default_backend() == "cpu":
-        return fn
-    return jax.jit(fn, donate_argnums=(0,))
+    ``kernel`` names what a launch runs (``lane_stats``); ``granule`` is
+    the row multiple a launch stages, which flush targets snap to;
+    ``launch_rows(n)`` is the rows :meth:`run` stages, uploads and hashes
+    for ``n`` payloads, which the lane's pad-row count charges; ``run``
+    hashes payloads, ``run_staged`` the listed rows of a pre-staged
+    :class:`StagedSlab`. The defaults are a row-exact plane's, which
+    hashes a slab's rows through ``run``: a ``plane_factory`` plane
+    subclasses this and supplies ``run``."""
+
+    kernel: str | None = None
+    granule = 1
+
+    def launch_rows(self, n_rows: int) -> int:
+        return n_rows
+
+    def run(self, payloads: list) -> list[bytes]:
+        raise NotImplementedError
+
+    def run_staged(self, slab: StagedSlab, rows: list[int]) -> list[bytes]:
+        return self.run([SlotRow(slab, r) for r in rows])
 
 
-class _CpuPlane:
-    """hashlib fallback plane — the CPU-path parity backend."""
+class _CpuPlane(Plane):
+    """hashlib fallback plane — the CPU-path parity backend. It stages
+    nothing, so it is row-exact."""
 
     kernel = "hashlib"  # what a launch runs, for metrics_snapshot lane_stats
 
     def __init__(self, algo: str):
         self._h = hashlib.sha256 if algo == "sha256" else hashlib.sha1
-
-    @staticmethod
-    def launch_geometry(n_rows: int, bucket: int) -> tuple[int, int]:
-        """hashlib stages nothing: no padding, no staging footprint."""
-        return n_rows, 0
 
     def run(self, payloads: list[bytes]) -> list[bytes]:
         h = self._h
@@ -928,13 +924,114 @@ def _row_ladder(batch: int, granule: int = 1) -> tuple[int, ...]:
     return tuple(sorted(x for x in rungs if x <= batch))
 
 
-class _Sha1DevicePlane:
+class _DevicePlane(Plane):
+    """What the three device planes share: a batch, the piece-length
+    guard, reusable staging slots (:class:`_StagingSlots`, which zero
+    only a row's stale tail where ``hash_pieces``-style staging zeroes a
+    fresh buffer every launch), and the launch, written once
+    (:meth:`_launch`) for the copying road (:meth:`run`) and the
+    zero-copy one (:meth:`run_staged`).
+
+    A subclass names its ``kernel`` and its ``_lock_name`` and supplies
+    the rows a chunk launches at (:meth:`_rows_for`), the rows a staged
+    slab launches at (:meth:`_slab_rows`), the device put (:meth:`_put`)
+    and the jitted step (:meth:`_step`).
+
+    The jitted call is serialized per plane (``_device_lock``): two
+    worker threads entering the same compiled executable concurrently
+    can deadlock inside the XLA runtime (observed as an intermittent
+    pipelined-launch hang on XLA-CPU). Host staging — the copy + pad,
+    the expensive host-side part — and the put still overlap across
+    pipelined launches; only the device call is single-file, and the
+    device serializes launches anyway."""
+
+    _lock_name: str
+
+    def __init__(self, bucket: int, batch: int):
+        self._bucket = bucket
+        self._batch = batch
+        self._slots = _StagingSlots(batch, bucket)
+        self._device_lock = named_lock(self._lock_name)
+
+    def _rows_for(self, n_rows: int) -> int:
+        """Rows one chunk of ``n_rows`` (at most the batch) launches at."""
+        raise NotImplementedError
+
+    def _slab_rows(self, slab: StagedSlab, rows: list[int]) -> int | None:
+        """Rows of ``slab`` a zero-copy launch of ``rows`` takes, or None
+        where the plane cannot launch the slab in place."""
+        raise NotImplementedError
+
+    def _put(self, padded, nblocks):
+        """The device input of one launch (the ``h2d`` stage)."""
+        raise NotImplementedError
+
+    def _step(self, dev, nblocks):
+        """Dispatch the jitted step on ``_put``'s input; async."""
+        raise NotImplementedError
+
+    def launch_rows(self, n_rows: int) -> int:
+        """Whole batches, then the rest's chunk."""
+        full, rest = divmod(n_rows, self._batch)
+        return full * self._batch + (self._rows_for(rest) if rest else 0)
+
+    def _launch(self, padded, nblocks, nbytes: int):
+        """One device launch in the ledger's three stages: the put
+        (``h2d``, outside the device lock so batch N+1's transfer
+        overlaps batch N's kernel), the jitted dispatch under the lock
+        (``launch``, async), the blocking fetch (``digest``). Bytes are
+        payload bytes throughout so cross-stage rates compare; the put
+        moves the padded footprint. Returns the digest words."""
+        import numpy as np
+
+        led = pipeline_ledger()
+        with led.track("h2d", nbytes, moved=padded.nbytes):
+            dev = self._put(padded, nblocks)
+        with self._device_lock:
+            with led.track("launch", nbytes):
+                words = self._step(dev, nblocks)
+        with led.track("digest", nbytes):
+            return np.asarray(words)
+
+    def run(self, payloads: list) -> list[bytes]:
+        """The copying road: a chunk of at most the batch is staged into
+        a slot at :meth:`launch_rows` rows and launched."""
+        from torrent_tpu.ops.padding import words_to_digests
+
+        if any(len(p) > self._bucket for p in payloads):
+            # a too-long piece would fail mid-stage with the slot out
+            raise ValueError("piece longer than plane piece_length")
+        out: list[bytes] = []
+        for start in range(0, len(payloads), self._batch):
+            chunk = payloads[start : start + self._batch]
+            rows = self.launch_rows(len(chunk))
+            slot, padded, nblocks = self._slots.stage(chunk, rows)
+            try:
+                words = self._launch(padded[:rows], nblocks, sum(len(p) for p in chunk))
+                out.extend(words_to_digests(words[: len(chunk)]))
+            finally:
+                self._slots.checkin(slot)
+        return out
+
+    def run_staged(self, slab: StagedSlab, rows: list[int]) -> list[bytes]:
+        """The zero-copy road: the pre-staged slab IS the launch buffer —
+        no ``stage`` copy; rows outside the ticket set are masked to
+        ``nblocks=0`` so one static shape serves every fill level and
+        bisection half. A slab the plane cannot launch in place takes
+        the copying road."""
+        from torrent_tpu.ops.padding import words_to_digests
+
+        n = self._slab_rows(slab, rows)
+        if n is None:
+            return super().run_staged(slab, rows)
+        nbytes = int(slab.lengths[list(rows)].sum())
+        words = self._launch(slab.padded[:n], _masked_nblocks(slab, rows)[:n], nbytes)
+        return words_to_digests(words[rows])
+
+
+class _Sha1DevicePlane(_DevicePlane):
     """SHA-1 device plane: one compiled TPUVerifier per bucket (the
     geometry-grouped compile cache the bulk/verify loops relied on).
-
-    Stages into reusable per-plane :class:`_StagingSlots` instead of
-    ``hash_pieces`` (which allocates + zeroes a fresh buffer every
-    launch).
 
     The copying road (``run``) launches at the smallest rung of a fixed
     row ladder (:func:`_row_ladder`) that holds the chunk: a deadline
@@ -943,23 +1040,17 @@ class _Sha1DevicePlane:
     device every rung takes the verifier's flat chunked upload
     (``flat_rows``), on a mesh ``upload_batch``'s sharded handle. Every
     rung is compiled and run once when the plane is built, so no later
-    launch meets a shape for the first time.
+    launch meets a shape for the first time. A staged slab launches
+    whole."""
 
-    The jitted execution itself is serialized per plane
-    (``_device_lock``): two worker threads entering the same compiled
-    executable concurrently can deadlock inside the XLA runtime
-    (observed as an intermittent pipelined-launch hang on XLA-CPU).
-    Host staging — the copy + pad, the expensive host-side part — still
-    overlaps across pipelined launches; only the device call is single-
-    file, and the device serializes launches anyway."""
+    _lock_name = "sched.sha1_plane._device_lock"
 
     def __init__(self, bucket: int, batch: int):
         from torrent_tpu.models.verifier import TPUVerifier
 
         v = self._verifier = TPUVerifier(piece_length=bucket, batch_size=batch)
+        super().__init__(bucket, v.batch_size)
         self.kernel = "pallas" if v.backend == "pallas" else "scan"
-        self._slots = _StagingSlots(v.batch_size, bucket)
-        self._device_lock = named_lock("sched.sha1_plane._device_lock")
         self._ladder = _row_ladder(v.batch_size, v.mesh.size)
         v.flat_rows.update(self._ladder)
         self._warm()
@@ -984,188 +1075,69 @@ class _Sha1DevicePlane:
         finally:
             self._slots.checkin(slot)
 
-    def _rung_for(self, n_rows: int) -> int:
-        """Smallest rung holding ``n_rows`` (at most the plane's batch)."""
+    def _rows_for(self, n_rows: int) -> int:
+        """Smallest rung holding ``n_rows``."""
         return self._ladder[bisect.bisect_left(self._ladder, n_rows)]
 
-    def launch_rows(self, n_rows: int) -> int:
-        """Rows ``run`` stages, uploads and hashes for ``n_rows``
-        payloads: whole batches, then the rest's rung. What the lane's
-        pad-row accounting charges for the copying road."""
-        b = self._ladder[-1]
-        full, rest = divmod(n_rows, b)
-        return full * b + (self._rung_for(rest) if rest else 0)
-
-    @staticmethod
-    def launch_geometry(n_rows: int, bucket: int) -> tuple[int, int]:
-        """Any row count is a valid flush target (the ladder follows the
-        chunk a launch holds, not the lane's target), so targets snap to
-        themselves; staging charges the padded row width. The rows a
-        launch really stages are :meth:`launch_rows`."""
-        from torrent_tpu.ops.padding import padded_len_for
-
-        return n_rows, n_rows * padded_len_for(bucket)
-
-    def _launch_padded(self, padded, nblocks, nb: int):
-        """One device launch with the real stage split: explicit upload
-        (h2d, outside the device lock so batch N+1's transfer overlaps
-        batch N's kernel), jitted dispatch under the lock (async — with
-        a donated input buffer on real devices), blocking fetch (digest)
-        back outside it. Falls back to the fused ``digest_batch`` when
-        the flat upload path can't take this shape (multi-process mesh,
-        odd geometry)."""
-        import numpy as np
-
-        led = pipeline_ledger()
-        v = self._verifier
-        if not v.upload_supported(padded):
-            # fallback (multi-process mesh, odd geometry): digest_batch
-            # opens its own stages — on a multi-process mesh the fused
-            # `launch` that carries the moved bytes, never a zero-length
-            # h2d span
-            with self._device_lock:
-                return v.digest_batch(padded, nblocks, nb)
-        with led.track("h2d", nb, moved=padded.nbytes):
-            handle = v.upload_batch(padded)
-        with self._device_lock:
-            with led.track("launch", nb):
-                words_dev = v.digest_uploaded(handle, nblocks)
-        with led.track("digest", nb):
-            return np.asarray(words_dev)
-
-    def run(self, payloads: list[bytes]) -> list[bytes]:
-        from torrent_tpu.ops.padding import words_to_digests
-
-        v = self._verifier
-        b = v.batch_size
-        if any(len(p) > v.piece_length for p in payloads):
-            # same guard as the sha256 planes: a too-long piece would
-            # fail mid-stage with the slot checked out
-            raise ValueError("piece longer than plane piece_length")
-        out: list[bytes] = []
-        for start in range(0, len(payloads), b):
-            chunk = payloads[start : start + b]
-            nb = sum(len(p) for p in chunk)
-            rung = self._rung_for(len(chunk))
-            slot, padded, nblocks = self._slots.stage(chunk, rows=rung)
-            try:
-                words = self._launch_padded(padded[:rung], nblocks, nb)
-                out.extend(words_to_digests(words[: len(chunk)]))
-            finally:
-                self._slots.checkin(slot)
-        return out
-
-    def run_staged(self, slab: StagedSlab, rows: list[int]) -> list[bytes]:
-        """Zero-copy launch: the pre-staged slab IS the launch buffer —
-        no ``_StagingSlots.stage`` copy, rows outside the ticket set are
-        masked to ``nblocks=0`` so one static shape serves every fill
-        level and bisection half."""
-        from torrent_tpu.ops.padding import words_to_digests
-
+    def _slab_rows(self, slab: StagedSlab, rows: list[int]) -> int | None:
+        # A row count merely SMALLER than the verifier's (tile/mesh)
+        # rounded batch is fine — upload_batch's sharded form takes any
+        # mesh-divisible shape; another row width or a row count the
+        # mesh does not divide is not.
         v = self._verifier
         if (
             slab.padded.shape[1] != v.padded_len
             or slab.rows_total > v.batch_size
             or slab.rows_total % max(1, v.mesh.size)
         ):
-            # row width / mesh-divisibility mismatch: copy path. A row
-            # count merely SMALLER than the verifier's (tile/mesh)
-            # rounded batch is fine — upload_batch's sharded form takes
-            # any mesh-divisible shape, so zero-copy launches survive
-            # the batch rounding real accelerators apply.
-            return self.run([SlotRow(slab, r) for r in rows])
-        nb = int(slab.lengths[list(rows)].sum())
-        words = self._launch_padded(
-            slab.padded, _masked_nblocks(slab, rows), nb
-        )
-        return words_to_digests(words[rows])
+            return None
+        return slab.rows_total
+
+    def _put(self, padded, nblocks):
+        return self._verifier.upload_batch(padded)
+
+    def _step(self, handle, nblocks):
+        return self._verifier.digest_uploaded(handle, nblocks)
+
+    def _launch(self, padded, nblocks, nbytes: int):
+        """A mesh the explicit upload cannot feed (multi-process, odd
+        geometry) takes the fused ``digest_batch``, which opens its own
+        stages: on a multi-process mesh the fused ``launch`` carries the
+        moved bytes, never a zero-length ``h2d`` span."""
+        v = self._verifier
+        if v.upload_supported(padded):
+            return super()._launch(padded, nblocks, nbytes)
+        with self._device_lock:
+            return v.digest_batch(padded, nblocks, nbytes)
 
 
-class _Sha256DevicePlane:
+class _Sha256DevicePlane(_DevicePlane):
     """SHA-256 (BEP 52) scan-backend plane — the fallback when the
     pallas kernel is unavailable (non-TPU device, ``scan`` selected, or
-    a bucket whose tile floor would blow the lane staging budget)."""
+    a bucket whose tile floor would blow the lane staging budget). Every
+    launch is the whole batch."""
 
     kernel = "scan"
+    _lock_name = "sched.sha256_scan_plane._device_lock"
 
-    def __init__(self, bucket: int, batch: int):
-        from torrent_tpu.ops.sha256_jax import make_sha256_fn
+    def _rows_for(self, n_rows: int) -> int:
+        return self._batch
 
-        self._fn = make_sha256_fn("jax")
-        # donated variant for the launch: frees the device input buffer
-        # as the kernel consumes it, so the next batch's H2D can reuse
-        # that memory while this kernel runs (identity on CPU)
-        self._fn_launch = _donating_wrapper(self._fn)
-        self._bucket = bucket
-        self._batch = batch
-        self._slots = _StagingSlots(batch, bucket)
-        # serialize the jitted call: concurrent entry from pipelined
-        # worker threads can deadlock the XLA runtime (see sha1 plane)
-        self._device_lock = named_lock("sched.sha256_scan_plane._device_lock")
+    def _slab_rows(self, slab: StagedSlab, rows: list[int]) -> int | None:
+        return slab.rows_total
 
-    @staticmethod
-    def launch_geometry(n_rows: int, bucket: int) -> tuple[int, int]:
-        from torrent_tpu.ops.padding import padded_len_for
-
-        return n_rows, n_rows * padded_len_for(bucket)
-
-    def run(self, payloads: list[bytes]) -> list[bytes]:
+    def _put(self, padded, nblocks):
         import jax.numpy as jnp
-        import numpy as np
 
-        from torrent_tpu.models.merkle import words32_to_digests
+        return jnp.asarray(padded), jnp.asarray(nblocks)
 
-        if any(len(p) > self._bucket for p in payloads):
-            raise ValueError("piece longer than plane piece_length")
-        out: list[bytes] = []
-        b = self._batch
-        led = pipeline_ledger()
-        for start in range(0, len(payloads), b):
-            chunk = payloads[start : start + b]
-            nb = sum(len(p) for p in chunk)
-            slot, padded, nblocks = self._slots.stage(chunk)
-            try:
-                # ledger stage boundaries: the explicit device put (h2d,
-                # outside the device lock so transfers overlap kernels),
-                # the jitted dispatch (launch — async, donated input),
-                # D2H fetch (digest). Bytes are payload bytes throughout
-                # so cross-stage rates compare (the physical transfer
-                # moves the padded footprint).
-                with led.track("h2d", nb, moved=padded.nbytes):
-                    dev_p = jnp.asarray(padded)
-                    dev_n = jnp.asarray(nblocks)
-                with self._device_lock:
-                    with led.track("launch", nb):
-                        words_dev = self._fn_launch(dev_p, dev_n)
-                with led.track("digest", nb):
-                    words = np.asarray(words_dev)
-                out.extend(words32_to_digests(words[: len(chunk)]))
-            finally:
-                self._slots.checkin(slot)
-        return out
+    def _step(self, dev, nblocks):
+        from torrent_tpu.ops.sha256_jax import sha256_pieces_jax
 
-    def run_staged(self, slab: StagedSlab, rows: list[int]) -> list[bytes]:
-        """Zero-copy launch from a pre-staged slab (no ``stage`` copy;
-        non-ticket rows masked to sentinels, static full-slab shape)."""
-        import jax.numpy as jnp
-        import numpy as np
-
-        from torrent_tpu.models.merkle import words32_to_digests
-
-        led = pipeline_ledger()
-        nb = int(slab.lengths[list(rows)].sum())
-        with led.track("h2d", nb, moved=slab.padded.nbytes):
-            dev_p = jnp.asarray(slab.padded)
-            dev_n = jnp.asarray(_masked_nblocks(slab, rows))
-        with self._device_lock:
-            with led.track("launch", nb):
-                words_dev = self._fn_launch(dev_p, dev_n)
-        with led.track("digest", nb):
-            words = np.asarray(words_dev)
-        return words32_to_digests(words[rows])
+        return sha256_pieces_jax(*dev)
 
 
-class _Sha256PallasPlane:
+class _Sha256PallasPlane(_DevicePlane):
     """SHA-256 (BEP 52) pallas plane — the v2 fast path.
 
     The hand-tiled kernel (``ops/sha256_pallas.py``) wants tile-shaped
@@ -1174,21 +1146,20 @@ class _Sha256PallasPlane:
     plane makes sub-tile launches cheap instead:
 
     * **Row-bucketed padding**: a live batch rounds up to the nearest
-      ``SUB_TILE_ROWS`` (8×128 = 1024) multiple, and ``tile_sub_for_rows``
-      picks the largest legal sublane count that tiles the bucketed row
-      count — full-target launches keep the sweep-tuned TILE_SUB,
-      partial flushes drop to smaller tiles instead of padding 4×.
+      ``granule`` (``SUB_TILE_ROWS``, 8×128 = 1024) multiple, and
+      ``tile_sub_for_rows`` picks the largest legal sublane count that
+      tiles the bucketed row count — full-target launches keep the
+      sweep-tuned TILE_SUB, partial flushes drop to smaller tiles
+      instead of padding 4×.
     * **Sentinel rows** carry ``nblocks=0``; their chains never run and
-      their stale staging contents are masked off (same contract as the
-      scan plane).
-    * **Reusable staging slots** (:class:`_StagingSlots`) sized to the
-      lane target, with per-row stale-tail zeroing — no per-launch
-      memset. The u32 view of the slot feeds the kernel's fast path
-      (a u8→u32 bitcast on device lowers through a 4×-widened fusion).
-    * **Per-plane launch-plan cache**: the (padded_rows → tile_sub,
-      interleave2) decision is memoized per geometry; jax.jit then keys
-      the compiled executable on the same statics, so a lane serves any
-      fill level from a handful of executables.
+      their stale staging contents are masked off.
+    * **Staging slots** sized to the tile-bucketed lane target; the u32
+      view of the slot feeds the kernel's fast path (a u8→u32 bitcast
+      on device lowers through a 4×-widened fusion).
+    * **Per-plane launch-plan cache**: the (rows → tile_sub,
+      interleave2) decision is memoized, and so is the jitted callable
+      of each tiling, so a lane serves any fill level from a handful of
+      executables.
 
     interleave2 needs ≥16 sublanes with whole-vreg halves, so 1024-row
     sub-tile launches silently run the straight kernel even when the
@@ -1196,33 +1167,22 @@ class _Sha256PallasPlane:
     """
 
     kernel = "pallas"
+    granule = 8 * 128  # ops/sha256_pallas.SUB_TILE_ROWS, the smallest legal tile
+    _lock_name = "sched.sha256_pallas_plane._device_lock"
 
     def __init__(self, bucket: int, batch: int, interpret: bool | None = None):
         from torrent_tpu.ops import sha256_pallas as sp
 
         self._sp = sp
-        self._bucket = bucket
+        self._interpret = interpret
+        self._plans: dict[int, tuple[int, int, bool]] = {}  # n -> (rows, ts, il2)
+        # jitted launch callables per (tile_sub, interleave2), built on
+        # first use from the worker thread (jax backend probe included)
+        self._launch_fns: dict[tuple[int, bool], Callable] = {}
         # slots (and the max launch) are sized to the tile-bucketed
         # target, so a lane target that is already a tile multiple
         # wastes zero pad rows at full fill
-        self._batch = sp.pad_rows_for(batch)
-        self._interpret = interpret
-        self._slots = _StagingSlots(self._batch, bucket)
-        self._plans: dict[int, tuple[int, int, bool]] = {}  # n -> (rows, ts, il2)
-        # donated launch callables per (tile_sub, interleave2) — built on
-        # first use from the worker thread (jax backend probe included)
-        self._launch_fns: dict[tuple[int, bool], Callable] = {}
-        self._device_lock = named_lock("sched.sha256_pallas_plane._device_lock")
-
-    @staticmethod
-    def launch_geometry(n_rows: int, bucket: int) -> tuple[int, int]:
-        """Tile-bucketed rows; staging charges the padded footprint
-        including sentinel rows."""
-        from torrent_tpu.ops.padding import padded_len_for
-        from torrent_tpu.ops.sha256_pallas import pad_rows_for
-
-        rows = pad_rows_for(n_rows)
-        return rows, rows * padded_len_for(bucket)
+        super().__init__(bucket, sp.pad_rows_for(batch))
 
     def _plan(self, n: int) -> tuple[int, int, bool]:
         plan = self._plans.get(n)
@@ -1234,87 +1194,43 @@ class _Sha256PallasPlane:
             plan = self._plans[n] = (rows, ts, il2)
         return plan
 
+    def _rows_for(self, n_rows: int) -> int:
+        return self._plan(n_rows)[0]
+
+    def _slab_rows(self, slab: StagedSlab, rows: list[int]) -> int | None:
+        # tile-bucket the slab's row count; a pool slab smaller than the
+        # granule (or bigger than the plane's max launch) is copied
+        n = self._plan(slab.rows_total)[0]
+        if n > slab.rows_total or any(r >= n for r in rows):
+            return None
+        return n
+
+    def _put(self, padded, nblocks):
+        import jax.numpy as jnp
+        import numpy as np
+
+        # the kernel's u32 fast path: rows are 128-byte aligned, so the
+        # view is free and the slab contiguous
+        return jnp.asarray(padded.view(np.uint32)), jnp.asarray(nblocks)
+
+    def _step(self, dev, nblocks):
+        _, ts, il2 = self._plan(len(nblocks))
+        return self._launch_fn(ts, il2)(*dev)
+
     def _launch_fn(self, ts: int, il2: bool):
-        """Kernel callable for a tiling, input-donated off-CPU (the
-        double-buffer memory contract; see :func:`_donating_wrapper`)."""
         fn = self._launch_fns.get((ts, il2))
         if fn is None:
+            import jax
+
             sp, interp = self._sp, self._interpret
 
-            def base(data32, nblocks, _ts=ts, _il2=il2):
+            def step(data32, nblocks):
                 return sp.sha256_pieces_pallas(
-                    data32, nblocks, interpret=interp, tile_sub=_ts,
-                    interleave2=_il2,
+                    data32, nblocks, interpret=interp, tile_sub=ts, interleave2=il2,
                 )
 
-            fn = self._launch_fns[(ts, il2)] = _donating_wrapper(base)
+            fn = self._launch_fns[(ts, il2)] = jax.jit(step)
         return fn
-
-    def run(self, payloads: list[bytes]) -> list[bytes]:
-        import jax.numpy as jnp
-        import numpy as np
-
-        from torrent_tpu.models.merkle import words32_to_digests
-
-        if any(len(p) > self._bucket for p in payloads):
-            raise ValueError("piece longer than plane piece_length")
-        out: list[bytes] = []
-        b = self._batch
-        led = pipeline_ledger()
-        for start in range(0, len(payloads), b):
-            chunk = payloads[start : start + b]
-            nb = sum(len(p) for p in chunk)
-            rows, ts, il2 = self._plan(len(chunk))
-            slot, padded, nblocks = self._slots.stage(chunk, rows)
-            try:
-                # slice to the bucketed row count, reinterpret as the
-                # kernel's u32 fast path (rows are 128-byte aligned so
-                # the view is free and the slab contiguous)
-                data32 = padded[:rows].view(np.uint32)
-                # same ledger boundaries as the scan plane: explicit put
-                # = h2d (outside the device lock so transfers overlap
-                # kernels), jitted dispatch = launch (async, donated
-                # input), fetch = digest
-                with led.track("h2d", nb, moved=data32.nbytes):
-                    dev_d = jnp.asarray(data32)
-                    dev_n = jnp.asarray(nblocks)
-                with self._device_lock:
-                    with led.track("launch", nb):
-                        words_dev = self._launch_fn(ts, il2)(dev_d, dev_n)
-                with led.track("digest", nb):
-                    words = np.asarray(words_dev)
-                out.extend(words32_to_digests(words[: len(chunk)]))
-            finally:
-                self._slots.checkin(slot)
-        return out
-
-    def run_staged(self, slab: StagedSlab, rows: list[int]) -> list[bytes]:
-        """Zero-copy launch from a pre-staged slab: tile-bucket the full
-        slab row count, mask non-ticket rows to sentinels, feed the u32
-        view of the slab directly — no ``stage`` copy."""
-        import jax.numpy as jnp
-        import numpy as np
-
-        from torrent_tpu.models.merkle import words32_to_digests
-
-        led = pipeline_ledger()
-        launch_rows, ts, il2 = self._plan(slab.rows_total)
-        if launch_rows > slab.rows_total or any(r >= launch_rows for r in rows):
-            # pool slab smaller than the tile granule (or bigger than
-            # the plane's max launch): copy path
-            return self.run([SlotRow(slab, r) for r in rows])
-        nb = int(slab.lengths[list(rows)].sum())
-        nblocks = _masked_nblocks(slab, rows)[:launch_rows]
-        data32 = slab.padded[:launch_rows].view(np.uint32)
-        with led.track("h2d", nb, moved=data32.nbytes):
-            dev_d = jnp.asarray(data32)
-            dev_n = jnp.asarray(nblocks)
-        with self._device_lock:
-            with led.track("launch", nb):
-                words_dev = self._launch_fn(ts, il2)(dev_d, dev_n)
-        with led.track("digest", nb):
-            words = np.asarray(words_dev)
-        return words32_to_digests(words[rows])
 
 
 # ------------------------------------------------------------ scheduler
@@ -1437,7 +1353,7 @@ class HashPlaneScheduler:
         padded_len)`` — big-piece buckets shrink the launch so staging
         stays bounded (the bridge's old private-buffer rule). Pallas
         sha256 lanes then snap the target to a tile multiple: UP to the
-        next ``SUB_TILE_ROWS`` granule (a full launch wastes zero pad
+        next granule of the pallas plane (a full launch wastes zero pad
         rows) but never past what the staging budget affords; a bucket
         whose single-tile floor already exceeds the budget falls back to
         the scan backend instead of overrunning it.
@@ -1452,17 +1368,14 @@ class HashPlaneScheduler:
         backend = self.sha256_backend()
         if backend == "pallas":
             from torrent_tpu.ops.sha256_pallas import (
-                SUB_TILE_ROWS,
                 TILE_LANE,
                 TILE_SUB,
-                pad_rows_for,
                 tile_sub_for_rows,
             )
 
-            if afford >= SUB_TILE_ROWS:
-                target = min(
-                    pad_rows_for(base), afford // SUB_TILE_ROWS * SUB_TILE_ROWS
-                )
+            g = _Sha256PallasPlane.granule
+            if afford >= g:
+                target = min(-(-base // g) * g, afford // g * g)
                 # prefer the sweep-tuned tiling: a row count whose ONLY
                 # legal tiling is the minimal tile_sub=8 (e.g. 5120 rows)
                 # rounds down to a full configured-tile multiple (4096 →
@@ -1532,14 +1445,21 @@ class HashPlaneScheduler:
         except ValueError:
             return None
 
+    @staticmethod
+    def _granule(lane: _Lane) -> int:
+        """The row multiple the lane's launches stage: the built plane's
+        word, else that of the plane class its backend builds."""
+        if lane.plane is not None:
+            return lane.plane.granule
+        return _Sha256PallasPlane.granule if lane.backend == "pallas" else Plane.granule
+
     def set_lane_target(self, lane_key: str, target: int) -> int | None:
         """Set a lane's flush target (autopilot batch actuator).
 
         The applied value is clamped to the staging budget and snapped
-        to what the built plane actually stages via its
-        ``launch_geometry`` hook — a pallas lane's adapted target is
-        always a tile multiple. Returns the applied target (None for an
-        unknown lane)."""
+        UP to the lane's launch granule (:meth:`_granule`) — a pallas
+        lane's adapted target is always a tile multiple. Returns the
+        applied target (None for an unknown lane)."""
         lane = self._lane_by_key(lane_key)
         if lane is None:
             return None
@@ -1550,33 +1470,15 @@ class HashPlaneScheduler:
 
             afford = max(1, self.config.staging_budget // padded_len_for(lane.bucket))
             target = min(target, afford)
-        hook = (
-            getattr(lane.plane, "launch_geometry", None)
-            if lane.plane is not None
-            else None
-        )
-        if hook is not None:
-            rows = int(hook(target, lane.bucket)[0])
-            if afford is not None and rows > afford:
-                # the hook snaps UP (pallas tile granule); a snap past
-                # the staging afford must round DOWN to the largest
-                # granule multiple instead — same discipline as the
-                # lane plan's `afford // SUB_TILE_ROWS * SUB_TILE_ROWS`.
-                # When even one granule doesn't fit, the budget beats
-                # the tiling and the raw afford stands.
-                granule = max(1, int(hook(1, lane.bucket)[0]))
-                rows = afford // granule * granule
-                if rows < 1:
-                    rows = afford
-            if rows >= 1:
-                target = rows
-        elif lane.backend == "pallas":
-            from torrent_tpu.ops.sha256_pallas import pad_rows_for
-
-            rows = max(1, pad_rows_for(target))
-            if afford is None or rows <= afford:
-                target = rows
-        lane.target = target
+        g = self._granule(lane)
+        rows = -(-target // g) * g
+        if afford is not None and rows > afford:
+            # a snap past the staging afford rounds DOWN to the largest
+            # granule multiple instead — the lane plan's discipline.
+            # When even one granule doesn't fit, the budget beats the
+            # tiling and the raw afford stands.
+            rows = afford // g * g or afford
+        lane.target = rows
         lane.event.set()  # re-evaluate the flush condition now
         return lane.target
 
@@ -1633,26 +1535,13 @@ class HashPlaneScheduler:
                 afford = max(lane.target, cfg.batch_target) * 64
             else:
                 afford = max(1, cfg.staging_budget // padded_len_for(bucket))
-            # launch granule (1 = row-exact): the controller snaps its
-            # grow cap to this so it never proposes a target the
-            # set_lane_target snap would round back down forever
-            hook = (
-                getattr(lane.plane, "launch_geometry", None)
-                if lane.plane is not None
-                else None
-            )
-            if hook is not None:
-                granule = max(1, int(hook(1, bucket)[0]))
-            elif lane.backend == "pallas":
-                from torrent_tpu.ops.sha256_pallas import SUB_TILE_ROWS
-
-                granule = SUB_TILE_ROWS
-            else:
-                granule = 1
             lanes[f"{algo}/{bucket}"] = {
                 "algo": algo,
                 "bucket": bucket,
-                "granule": granule,
+                # launch granule (1 = row-exact): the controller snaps
+                # its grow cap to this so it never proposes a target
+                # the set_lane_target snap would round back down forever
+                "granule": self._granule(lane),
                 "target": lane.target,
                 "base_target": self._lane_plan(algo, bucket)[1],
                 "afford": afford,
@@ -2091,12 +1980,9 @@ class HashPlaneScheduler:
         # floor blows the staging budget the fallback exists to enforce
         sha256_backend = lane.backend if lane.backend in ("pallas", "scan") else None
         if cfg.plane_factory is not None:
-            if accepts_sha256_backend(cfg.plane_factory):
-                return cfg.plane_factory(
-                    lane.algo, lane.bucket, lane.target,
-                    sha256_backend=sha256_backend,
-                )
-            return cfg.plane_factory(lane.algo, lane.bucket, lane.target)
+            return cfg.plane_factory(
+                lane.algo, lane.bucket, lane.target, sha256_backend=sha256_backend
+            )
         return build_builtin_plane(
             self.hasher, lane.algo, lane.bucket, lane.target,
             sha256_backend=sha256_backend,
@@ -2148,61 +2034,41 @@ class HashPlaneScheduler:
                             lane.breaker.release_probe()
                         raise
         # zero-copy launch form: when every ticket is a SlotRow of ONE
-        # pre-staged slab and the plane can consume it in place, skip
-        # the stage copy entirely (mixed batches — several slabs, or
-        # slab rows interleaved with byte payloads — take the copying
-        # run path, which stages SlotRow views like any other payload)
+        # pre-staged slab, the plane consumes it in place and skips the
+        # stage copy (mixed batches — several slabs, or slab rows
+        # interleaved with byte payloads — take the copying run path,
+        # which stages SlotRow views like any other payload)
         staged = _staged_batch(payloads)
-        run_staged = (
-            getattr(lane.plane, "run_staged", None) if staged else None
-        )
         # launched rows and pad-row waste: the rows this attempt stages,
-        # uploads and hashes, and how many of them hold no piece (the
-        # SHA-1 plane's row ladder, tile bucketing on the pallas plane;
-        # zero pad on the hashlib degradation path, which stages
-        # nothing). The built plane's own word is authoritative — a
-        # plane_factory plane (faults seam) may stage differently than
-        # the lane plan assumed: ``launch_rows`` where the copying road
-        # launches at a shape of its own choosing, else the
-        # ``launch_geometry`` hook the targets snap with (a staged
-        # slab's rows are its reader's: charged as handed over); a plane
-        # exposing neither is taken as row-exact (FaultyPlane's
-        # hook-less default agrees). Charged per actual attempt (retries
-        # and bisection halves each re-stage), under the counter lock:
-        # worker threads run this.
+        # uploads and hashes, and how many of them hold no piece — the
+        # plane's ``launch_rows`` on the copying road (the SHA-1 row
+        # ladder, tile bucketing on the pallas plane, the scan plane's
+        # whole batch), a staged slab's rows as its reader handed them
+        # over. Charged per actual attempt (retries and bisection halves
+        # each re-stage), under the counter lock: worker threads run this.
         n = len(payloads)
-        rows_of = getattr(lane.plane, "launch_rows", None)
-        hook = getattr(lane.plane, "launch_geometry", None)
-        if rows_of is not None and run_staged is None:
-            launched = rows_of(n)
-        elif hook is not None:
-            launched = hook(n, lane.bucket)[0]
-        else:
-            launched = n
+        launched = n if staged else lane.plane.launch_rows(n)
         with self._counter_lock:
             self._counter_cells.write("fault_counters")
             lane.launched_rows_total += launched
             lane.pad_rows_total += launched - n
-            if run_staged is not None:
+            if staged:
                 lane.staged_launches += 1
                 lane.staged_rows_total += staged[0].rows_total
                 lane.staged_live_rows_total += n
-        if run_staged is not None:
+        if staged:
             obs_note["staged"] = True
+            launch = functools.partial(lane.plane.run_staged, *staged)
+        else:
+            launch = functools.partial(lane.plane.run, payloads)
         try:
             if self.hasher == "cpu":
-                if run_staged is not None:
-                    digests = run_staged(*staged)
-                else:
-                    digests = lane.plane.run(payloads)
+                digests = launch()
             else:
                 from torrent_tpu.obs.profiler import maybe_profile_batch
 
                 with maybe_profile_batch(f"sched_{lane.algo}_launch_b{lane.bucket}"):
-                    if run_staged is not None:
-                        digests = run_staged(*staged)
-                    else:
-                        digests = lane.plane.run(payloads)
+                    digests = launch()
             # contract check BEFORE record_success: a plane persistently
             # returning the wrong count must feed the breaker (and trip
             # to the CPU plane) instead of resetting it every launch
@@ -2515,8 +2381,8 @@ class HashPlaneScheduler:
                     "backend": lane.backend,
                     # the built plane's own word for what its launches
                     # run (pallas | scan | hashlib); None until the first
-                    # launch builds it, or for a plane_factory plane
-                    "kernel": getattr(lane.plane, "kernel", None),
+                    # launch builds it
+                    "kernel": None if lane.plane is None else lane.plane.kernel,
                     "target": lane.target,
                     "deadline": (
                         lane.deadline

@@ -190,6 +190,57 @@ class TestLeafCounters:
         assert re.search(r'torrent_tpu_v2_leaf_rows_live_total\{kernel="scan"\} \d+', text)
 
 
+class TestIngestCounters:
+    """The session's v2 ingest counters (``session/v2.v2_ingest_stats``),
+    on ``/metrics`` beside the leaf plane's."""
+
+    def test_the_counters_render_as_prometheus_text(self):
+        from torrent_tpu.utils.metrics import render_v2_ingest_metrics
+
+        text = render_v2_ingest_metrics(
+            {"batches": 40, "pieces": 284, "short_pieces": 28, "pieces_host_hashed": 0, "fold_pieces_launched": 320}
+        )
+        prom_lint(text)
+        assert "torrent_tpu_v2_ingest_batches_total 40" in text
+        assert "torrent_tpu_v2_ingest_short_pieces_total 28" in text
+        assert "torrent_tpu_v2_ingest_pieces_host_hashed_total 0" in text
+        assert "torrent_tpu_v2_ingest_fold_pieces_launched_total 320" in text
+
+    def test_no_update_is_lost_between_threads(self):
+        import sys
+
+        from torrent_tpu.session import v2 as session_v2
+
+        counters = session_v2._IngestCounters()
+        interval = sys.getswitchinterval()
+        sys.setswitchinterval(1e-6)
+        try:
+            def work():
+                for _ in range(2000):
+                    counters.add(batches=1, pieces=3, short_pieces=1)
+                    counters.add(pieces_host_hashed=1)
+
+            threads = [threading.Thread(target=work) for _ in range(16)]
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join(timeout=60)
+            assert not any(t.is_alive() for t in threads)
+        finally:
+            sys.setswitchinterval(interval)
+        assert counters.stats() == {"batches": 32000, "pieces": 96000, "short_pieces": 32000,
+                                    "pieces_host_hashed": 32000, "fold_pieces_launched": 0}
+
+    def test_metrics_carry_them_once_the_session_arm_is_in(self):
+        from torrent_tpu.obs import render_obs_metrics
+        from torrent_tpu.session import v2 as session_v2
+
+        session_v2.ingest_verdicts([os.urandom(100)], [bytes(32)], [1], PLEN)
+        text = render_obs_metrics()
+        assert re.search(r"torrent_tpu_v2_ingest_batches_total [1-9]\d*", text)
+        assert re.search(r"torrent_tpu_v2_ingest_short_pieces_total [1-9]\d*", text)
+
+
 class TestLeafStepModuleNames:
     """``hash_step_*`` find the leaf step's device time by XLA module name."""
 

@@ -226,26 +226,26 @@ class TestV2SwarmE2E:
 
     def test_1mib_pieces_batch_ingest_on_device_plane(self, tmp_path, monkeypatch):
         """r3 verdict #5: v2 ingest at 1 MiB pieces (64 leaves each — the
-        top of the authoring ladder) routes full-subtree pieces through
-        the batched device micro-path off the event loop; the tail piece
-        folds per-piece on the CPU where the pad geometry lives."""
+        top of the authoring ladder) routes every piece through the
+        batched device micro-path off the event loop, the short tail
+        piece too, with its own leaf-pad target."""
         from torrent_tpu.server.in_memory import run_tracker
         from torrent_tpu.server.tracker import ServeOptions
+        from torrent_tpu.session import v2 as session_v2
         from torrent_tpu.session.client import Client, ClientConfig
-        from torrent_tpu.session.torrent import Torrent
 
         plen = 1 << 20
         rng = np.random.default_rng(11)
         fa = rng.integers(0, 256, 4 * plen + 700, dtype=np.uint8).tobytes()
 
         calls: list[int] = []
-        real = Torrent._verify_batch_device_v2
+        real = session_v2.ingest_verdicts
 
-        def spy(self, pieces, expected):
+        def spy(pieces, *args):
             calls.append(len(pieces))
-            return real(self, pieces, expected)
+            return real(pieces, *args)
 
-        monkeypatch.setattr(Torrent, "_verify_batch_device_v2", spy)
+        monkeypatch.setattr(session_v2, "ingest_verdicts", spy)
 
         async def go():
             server, _ = await run_tracker(
@@ -279,9 +279,9 @@ class TestV2SwarmE2E:
                 assert t2.bitfield.complete, t2.status()
                 got = open(os.path.join(ld, "d1m", "big.bin"), "rb").read()
                 assert got == fa
-                # the 4 full pieces went through the device batch path
-                # (the 700-byte tail pads past its leaf count → CPU fold)
-                assert sum(calls) == 4, calls
+                # the 4 full pieces and the 700-byte tail went through
+                # the device batch path
+                assert sum(calls) == 5, calls
             finally:
                 await c1.close()
                 await c2.close()

@@ -359,8 +359,17 @@ def _leaf_bucket(total: int) -> int:
 def _leaf_words_from_chunks(
     chunks, total: int, backend: str, on_launch=None
 ) -> np.ndarray:
-    """SHA-256 leaf hashes from an iterator of block-aligned chunks
-    → ``u32[n_blocks, 8]``.
+    """SHA-256 leaf hashes from an iterator of chunks → ``u32[n, 8]``,
+    ``n = ceil(total / BLOCK)``.
+
+    Every chunk is a launch of its own over the bucket of ``total``, its
+    leaves starting at row 0 of the slab, so a chunk that ends in a short
+    leaf pads that leaf where the chunk ends (``_pad_rows``). The output
+    is the chunks' rows end to end: where a chunk that is not
+    block-aligned stands before another, ``total`` must count whole rows
+    (the sum of each chunk's ``ceil(bytes / BLOCK)`` times ``BLOCK``), as
+    ``session/v2.ingest_verdicts`` gives it. A launch of several chunks
+    at once would break that placement.
 
     A chunk is bytes-like (copied into the slab's rows) or a
     :class:`_PathChunk` (read into them); either way the rows are those

@@ -134,7 +134,8 @@ def render_obs_metrics() -> str:
     stay lean), the jitted SHA-1 steps' build and reuse counters and a
     recheck's kept staging pair's (only once this process has imported
     the verifier, and so JAX), the v2
-    leaf plane's launch, row and slab counters (once ``models/v2`` is in), and the
+    leaf plane's launch, row and slab counters (once ``models/v2`` is in), the
+    session's v2 ingest counters (once ``session/v2`` is in), and the
     flight-recorder dump counters. Appended by both the bridge's
     ``/metrics`` and the session ``MetricsServer``."""
     from torrent_tpu.serve_plane.telemetry import serve_telemetry
@@ -146,11 +147,13 @@ def render_obs_metrics() -> str:
         render_staging_slab_metrics,
         render_step_metrics,
         render_swarm_metrics,
+        render_v2_ingest_metrics,
     )
 
     serve_obs = serve_telemetry()
     verifier = sys.modules.get("torrent_tpu.models.verifier")
     v2 = sys.modules.get("torrent_tpu.models.v2")
+    session_v2 = sys.modules.get("torrent_tpu.session.v2")
     return (
         histograms().render()
         + render_pipeline_metrics()
@@ -159,6 +162,7 @@ def render_obs_metrics() -> str:
         + (render_leaf_metrics(v2.leaf_launch_stats()) if v2 else "")
         + (render_author_metrics(verifier.author_launch_stats()) if verifier else "")
         + (render_leaf_slab_metrics(v2.leaf_slab_stats()) if v2 else "")
+        + (render_v2_ingest_metrics(session_v2.v2_ingest_stats()) if session_v2 else "")
         + render_swarm_metrics(swarm_telemetry().snapshot())
         + (
             render_serve_metrics(serve_obs.snapshot())

@@ -65,7 +65,8 @@ the last verdict),
 ``_drr_take``) and ``merkle`` (the BEP 52 fold above the leaves in
 ``models/v2.py``: each flush of ``roots_batched_windowed``, each
 file's piece-layer check in ``verify_v2`` and each file's fold in
-``_hybrid_hash_file``; bytes are the 32-byte hashes folded). The v2
+``_hybrid_hash_file``; and each v2 download batch's fold in
+``session/v2.ingest_verdicts``; bytes are the 32-byte hashes folded). The v2
 recheck (``verify_v2``) charges the chain by file: ``pass_setup`` (leaf
 function, padded slab), ``read``, ``stage`` (the chunk's copy, the
 slab's memset, the copy into it, ``pad_in_place``),
@@ -109,7 +110,10 @@ authoring's SHA-1 arm is ``track("author_v1", wait=True)``
 ``hash_pieces`` launches, and the file's tail piece; bytes are the
 chunk's): a wait with a span, because the ``h2d`` / ``launch`` /
 ``digest`` stages of its launches run inside it and a stage around them
-would count as overlap.
+would count as overlap. A v2 download's ingest batch is
+``track("ingest_v2_batch", wait=True)`` for the same reason
+(``session/v2.ingest_verdicts``, in its worker thread: the batch's leaf
+launches and fold; bytes are the batch's payload).
 
 The ledger also integrates cross-stage occupancy overlap — wall
 seconds with ≥2 distinct stages simultaneously busy and the

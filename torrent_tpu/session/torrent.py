@@ -3092,40 +3092,34 @@ class Torrent:
         scheduler (:meth:`_verify_on_scheduler`). CPU mode: hashlib
         off-thread.
         v2 torrents (session/v2.py): the expected digest is the piece's
-        merkle subtree root — SHA-256 leaves folded per BEP 52, off the
-        event loop (≤64 leaves per piece; the batched device planes pay
-        off on the full-recheck path, not per-piece ingest).
+        merkle subtree root, SHA-256 leaves folded per BEP 52. Hasher
+        'tpu': every piece, a short tail or a file of one piece too,
+        joins the micro-batch (:meth:`_flush_verify_batch`), whose leaves
+        all go to the device leaf plane. CPU mode: hashlib, inline up to
+        ``INLINE_IO_MAX``.
         """
         if self.v2:
-            from torrent_tpu.models.merkle import piece_root_cpu
+            from torrent_tpu.session.v2 import count_host_hashed
 
-            pad = self.info.piece_pad_leaves[index]
-            if (
-                self.config.hasher == "tpu"
-                and len(data) == self.info.piece_length
-                and pad == self.info.piece_length // 16384
-            ):
-                # Full-subtree piece: batch onto the device leaf plane
-                # with every other concurrent finisher. This micro-batch
-                # (_verify_pending → _flush_verify_batch) is the v2 arm
-                # alone and stays as it was: the scheduler has no merkle
-                # lane yet (ROADMAP Reach A2). Tail pieces (short data /
-                # oversized pad) fold on the CPU below.
-                #
-                # Whether the batch beats piece_root_cpu depends on
-                # how many pieces finish together and on the device's
-                # per-dispatch cost; the choice to always batch here was
-                # made on a retired setup, not measured on this one.
-                # Either way the verify leaves the event loop, which is
-                # what ingest latency cares about; a device failure
-                # falls back to hashlib inside the flush (counted:
-                # _H_INGEST_VERIFY plane="hashlib_fallback").
+            if self.config.hasher == "tpu":
+                # Batched with every other concurrent finisher, each piece
+                # with its own leaf-pad target (session/v2.ingest_verdicts).
+                # This micro-batch is the v2 arm alone: the scheduler has
+                # no merkle lane yet (ROADMAP Reach A2). The verify leaves
+                # the event loop, which is what ingest latency cares
+                # about; a device failure falls back to hashlib inside the
+                # flush (counted: _H_INGEST_VERIFY plane="hashlib_fallback",
+                # and v2_ingest_stats()["pieces_host_hashed"]).
                 fut: asyncio.Future = asyncio.get_running_loop().create_future()
                 self._verify_pending.append((index, data, expected, fut))
                 if not self._verify_flushing:
                     self._verify_flushing = True
                     self._spawn(self._flush_verify_batch(), name="verify-batch")
                 return await fut
+            from torrent_tpu.models.merkle import piece_root_cpu
+
+            count_host_hashed(1)
+            pad = self.info.piece_pad_leaves[index]
             if len(data) <= INLINE_IO_MAX:
                 return piece_root_cpu(data, pad) == expected
             root = await asyncio.to_thread(piece_root_cpu, data, pad)
@@ -3174,6 +3168,7 @@ class Torrent:
     async def _flush_verify_batch(self) -> None:
         """Drain the v2 pending-verification queue in device batches."""
         from torrent_tpu.models.merkle import piece_root_cpu
+        from torrent_tpu.session.v2 import count_host_hashed, ingest_verdicts
 
         try:
             # one event-loop tick lets concurrent _finish_piece calls join
@@ -3183,20 +3178,21 @@ class Torrent:
                 del self._verify_pending[: len(batch)]
                 pieces = [b[1] for b in batch]
                 expected = [b[2] for b in batch]
+                pads = [self.info.piece_pad_leaves[b[0]] for b in batch]
                 t0 = time.monotonic()
                 plane = "device"
                 try:
                     ok = await asyncio.to_thread(
-                        self._verify_batch_device_v2, pieces, expected
+                        ingest_verdicts, pieces, expected, pads, self.info.piece_length
                     )
                 except Exception as e:  # device trouble: fail safe to hashlib
                     plane = "hashlib_fallback"
                     log.warning("tpu ingest verify failed (%s); hashlib fallback", e)
-                    lpp = self.info.piece_length // 16384
+                    count_host_hashed(len(batch))
                     ok = await asyncio.to_thread(
                         lambda: [
-                            piece_root_cpu(p, lpp) == e2
-                            for p, e2 in zip(pieces, expected)
+                            piece_root_cpu(p, pad) == e2
+                            for p, pad, e2 in zip(pieces, pads, expected)
                         ]
                     )
                 histograms().get(*_H_INGEST_VERIFY, plane=plane).observe(
@@ -3211,27 +3207,6 @@ class Torrent:
                 if not fut.done():
                     fut.set_result(False)  # torn down mid-flight: re-request
             self._verify_pending.clear()
-
-    def _verify_batch_device_v2(self, pieces: list[bytes], expected: list[bytes]):
-        """Batched BEP 52 ingest verify: ONE leaf-plane dispatch plus the
-        fused merkle pair reduction for every concurrently-finishing
-        full-subtree piece (only those are queued — _verify_piece_data
-        folds tails on the CPU, where the pad geometry is per-piece)."""
-        from torrent_tpu.models.merkle import (
-            piece_roots_from_leaves,
-            words32_to_digests,
-        )
-        from torrent_tpu.models.v2 import _leaf_words_from_chunks
-
-        lpp = self.info.piece_length // 16384
-        # each full piece IS a block-aligned chunk: feed them straight to
-        # the leaf plane instead of joining into a second copy of the
-        # whole batch (256 x 1 MiB pieces would duplicate ~256 MiB)
-        leaves = _leaf_words_from_chunks(
-            iter(pieces), sum(len(p) for p in pieces), "auto"
-        )
-        roots = words32_to_digests(piece_roots_from_leaves(leaves, lpp))
-        return [r == e for r, e in zip(roots, expected)]
 
     # ------------------------------------------------------------- seeding
 

@@ -225,3 +225,127 @@ def multi_piece_roots(info: InfoDictV2) -> list[tuple[bytes, int]]:
         if f.length > plen:
             out.append((f.pieces_root, -(-f.length // plen)))
     return out
+
+
+# ------------------------------------------------------------ ingest verify
+
+
+class _IngestCounters:
+    """What the session's v2 ingest judged and how: the device batches
+    (``_flush_verify_batch``), the pieces in them, those shorter than a
+    full piece (a multi-piece file's tail, a file of one piece), the
+    pieces whose payload hashlib hashed instead (``hasher="cpu"``, and a
+    batch's ``hashlib_fallback``), and the fold's grid rows, padding
+    pieces included."""
+
+    KEYS = ("batches", "pieces", "short_pieces", "pieces_host_hashed", "fold_pieces_launched")
+
+    def __init__(self):
+        from torrent_tpu.analysis.sanitizer import guard_attrs, named_lock
+
+        self._lock = named_lock("session.v2._ingest_lock")
+        self._cells = guard_attrs("session.v2.ingest_counters", "counts")
+        self._counts = dict.fromkeys(self.KEYS, 0)
+
+    def add(self, **deltas: int) -> None:
+        with self._lock:
+            self._cells.write("counts")
+            for key, n in deltas.items():
+                self._counts[key] += n
+
+    def stats(self) -> dict[str, int]:
+        with self._lock:
+            self._cells.read("counts")
+            return dict(self._counts)
+
+
+_ingest_counters = _IngestCounters()
+
+
+def v2_ingest_stats() -> dict[str, int]:
+    """``batches`` / ``pieces`` / ``short_pieces`` /
+    ``pieces_host_hashed`` / ``fold_pieces_launched`` of this process's
+    v2 ingest (:class:`_IngestCounters`). Rendered by ``/metrics``
+    (utils/metrics.py)."""
+    return _ingest_counters.stats()
+
+
+def count_host_hashed(pieces: int) -> None:
+    """``pieces`` whose payload the session judged with hashlib."""
+    _ingest_counters.add(pieces_host_hashed=pieces)
+
+
+FOLD_GRID = 8  # pieces a fold launch reduces, padding included
+
+
+def fold_bucket(pieces: int) -> int:
+    """Grid rows launched to fold ``pieces`` piece roots: whole grids of
+    ``FOLD_GRID`` pieces, padded with all-zero pieces. A download then
+    meets one fold program a piece length, which its first batch
+    compiles, whatever number of pieces a later batch holds."""
+    return -(-pieces // FOLD_GRID) * FOLD_GRID
+
+
+def ingest_verdicts(pieces, expected, pads, piece_length: int) -> list[bool]:
+    """BEP 52 verdicts of a batch of delivered pieces: every piece's
+    leaves on the device leaf plane, then each piece's root against its
+    ``expected`` digest.
+
+    ``pads[i]`` is piece *i*'s leaf-pad target (``piece_pad_leaves``).
+    Each piece is a chunk of its own, so its leaves start on a row of
+    their own and a short piece's last leaf is padded where it ends.
+    Full pieces and short ones are launched in two runs, each over its own
+    row bucket: a one-leaf tail beside four full pieces would otherwise
+    double the bucket every one of their launches uploads. Pieces padded
+    to a full piece's leaves (every piece of a multi-piece file, its
+    short tail too) fold together on the device, one grid row a piece,
+    ``FOLD_GRID`` rows a launch (:func:`fold_bucket`; the padding pieces
+    are all-zero and their roots dropped). A file of one piece pads to
+    its own power of two and folds on the host from the device's leaf
+    words with ``models/v2._root_cpu``, the fold of ``roots_batched``'s
+    ``device=False`` road.
+
+    Synchronous (the session runs it in a worker thread). The batch is
+    the ledger wait ``ingest_v2_batch`` (its leaf launches' stages run
+    inside it), its fold the stage ``merkle``."""
+    import numpy as np
+
+    from torrent_tpu.models.merkle import merkle_root, words32_to_digests
+    from torrent_tpu.models.v2 import _leaf_words_from_chunks, _root_cpu
+    from torrent_tpu.obs.ledger import pipeline_ledger
+
+    lpp = piece_length // BLOCK
+    rows = [-(-len(p) // BLOCK) for p in pieces]
+    grid_pieces = [i for i, pad in enumerate(pads) if pad == lpp]
+    k = fold_bucket(len(grid_pieces))
+    _ingest_counters.add(
+        batches=1, pieces=len(pieces), short_pieces=sum(len(p) < piece_length for p in pieces)
+    )
+    ledger = pipeline_ledger()
+    roots: list = [None] * len(pieces)
+    leaves: list = [None] * len(pieces)
+    with ledger.track("ingest_v2_batch", sum(len(p) for p in pieces), wait=True):
+        for run in ([i for i, p in enumerate(pieces) if len(p) == piece_length],
+                    [i for i, p in enumerate(pieces) if len(p) < piece_length]):
+            if not run:
+                continue
+            # the chunks' rows, not their bytes, size the output: a short
+            # piece's last leaf is a row of its own wherever it stands
+            words = _leaf_words_from_chunks((pieces[i] for i in run), sum(rows[i] for i in run) * BLOCK, "auto")
+            at = 0
+            for i in run:
+                leaves[i], at = words[at : at + rows[i]], at + rows[i]
+        with ledger.track("merkle", 32 * sum(rows)):
+            if grid_pieces:
+                grid = np.zeros((k, lpp, 8), dtype=np.uint32)
+                for g, i in enumerate(grid_pieces):
+                    grid[g, : rows[i]] = leaves[i]
+                folded = [root for at in range(0, k, FOLD_GRID)
+                          for root in words32_to_digests(merkle_root(grid[at : at + FOLD_GRID]))]
+                for g, i in enumerate(grid_pieces):
+                    roots[i] = folded[g]
+            for i, pad in enumerate(pads):
+                if pad != lpp:
+                    roots[i] = _root_cpu(leaves[i], pad)
+    _ingest_counters.add(fold_pieces_launched=k)
+    return [r == e for r, e in zip(roots, expected)]

@@ -313,6 +313,26 @@ def render_leaf_slab_metrics(stats: dict) -> str:
     return "\n".join(lines) + "\n"
 
 
+def render_v2_ingest_metrics(stats: dict) -> str:
+    """Prometheus rendering of the session's v2 ingest counters.
+
+    ``stats`` is ``torrent_tpu.session.v2.v2_ingest_stats()``: device
+    batches, the pieces in them and the short ones among them, the
+    pieces whose payload hashlib hashed (``hasher="cpu"`` and the
+    batch's fallback), and the fold's grid rows, padding included."""
+    lines = []
+    for key, text in (
+        ("batches", "v2 ingest batches put to the device leaf plane"),
+        ("pieces", "Pieces in v2 ingest batches"),
+        ("short_pieces", "Pieces in v2 ingest batches shorter than a full piece"),
+        ("pieces_host_hashed", "v2 ingest pieces whose payload hashlib hashed"),
+        ("fold_pieces_launched", "Piece rows of v2 ingest folds, padding pieces included"),
+    ):
+        name = f"torrent_tpu_v2_ingest_{key}_total"
+        lines += [f"# HELP {name} {text}", f"# TYPE {name} counter", f"{name} {stats[key]}"]
+    return "\n".join(lines) + "\n"
+
+
 def render_tsan_metrics(snapshot: dict) -> str:
     """Prometheus rendering of the concurrency sanitizer's counters.
 

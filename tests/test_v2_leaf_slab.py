@@ -16,7 +16,9 @@ from torrent_tpu.codec.metainfo_v2 import BLOCK
 from torrent_tpu.models import v2
 from torrent_tpu.models.merkle import digests_to_words32
 from torrent_tpu.native import io_engine
+from torrent_tpu.obs.ledger import pipeline_ledger
 
+from test_metrics import prom_lint
 from test_v2_stage_spans import PLEN, _corpus, _launches, _on_disk, small_batches  # noqa: F401
 
 
@@ -129,11 +131,13 @@ class TestCountersAndConcurrency:
         read_file = _on_disk(tmp_path, files)
         assert all(ok.all() for ok in v2.verify_v2(read_file, meta, hasher="tpu").values())
         first, launches = v2.leaf_slab_stats(), _launches()
-        assert first == {"leaf_slab_allocs": 1, "leaf_slab_reuses": 2, "leaf_slab_transient": 0, "direct": 5, "copied": 0}
+        assert first == {"leaf_slab_allocs": 1, "leaf_slab_reuses": 2, "leaf_slab_transient": 0, "direct": 5, "copied": 0,
+                         "leaf_launches_overlapped": 4}
         assert all(ok.all() for ok in v2.verify_v2(read_file, meta, hasher="tpu").values())
         second = v2.leaf_slab_stats()
         assert _launches() - launches == 5
-        assert second == {"leaf_slab_allocs": 1, "leaf_slab_reuses": 5, "leaf_slab_transient": 0, "direct": 10, "copied": 0}
+        assert second == {"leaf_slab_allocs": 1, "leaf_slab_reuses": 5, "leaf_slab_transient": 0, "direct": 10, "copied": 0,
+                          "leaf_launches_overlapped": 8}
 
     def test_two_threads_at_once_one_takes_a_transient_slab(self, slab):
         rng = np.random.default_rng(3)
@@ -181,13 +185,155 @@ class TestCountersAndConcurrency:
         assert st["leaf_slab_transient"] == 0 and st["leaf_slab_allocs"] == 1
 
 
+def _ledger_ops() -> dict[str, int]:
+    stages = pipeline_ledger().snapshot()["stages"]
+    return {name: stages.get(name, {}).get("ops", 0) for name in ("h2d", "launch", "digest")}
+
+
+def _launch_rows() -> int:
+    return sum(c["launches"] for c in v2.leaf_launch_stats().values())
+
+
+def _flip(path: str, offset: int) -> None:
+    with open(path, "r+b") as fh:
+        fh.seek(offset)
+        byte = fh.read(1)
+        fh.seek(offset)
+        fh.write(bytes([byte[0] ^ 0x01]))
+
+
+# Files of every row bucket up to a LEAF_BATCH of 64 (16, 32 and 64 rows),
+# with PLEN = 4 leaves a piece: (name, bytes, piece to flip a byte in or None).
+WINDOW_FILES = [
+    ("several_launches_full_piece_bad", 150 * BLOCK + 777, 5),  # 151 leaves: launches of 64, 64 and 23
+    ("bucket_32_short_last_piece_bad", 25 * BLOCK + 100, 6),  # 26 leaves, the short seventh piece flipped
+    ("bucket_16_first_piece_bad", 9 * BLOCK + 5, 0),
+    ("one_piece_bad", 3 * BLOCK + 523, 0),
+    ("several_launches_clean", 64 * BLOCK * 2 + 1, None),  # 129 leaves: 64, 64 and 1
+    ("one_piece_clean", 2 * BLOCK, None),
+]
+
+
+class TestWindow:
+    """``verify_v2``'s device road keeps one leaf launch in flight across a
+    pass (:class:`v2._LeafWindow`); on the CPU backend a launch in flight
+    uploads a copy of the slab, so the next one may be staged into it."""
+
+    @pytest.fixture
+    def window_slab(self, monkeypatch):
+        """LEAF_BATCH 64 and a kept slab of 64 rows, 0xFF in every byte."""
+        monkeypatch.setattr(v2, "LEAF_BATCH", 64)
+        fresh = v2._LeafSlab()
+        padded, _ = fresh.checkout(64)
+        padded[:] = 0xFF
+        fresh.checkin()
+        monkeypatch.setattr(v2, "_leaf_slab", fresh)
+        return fresh
+
+    def _damaged(self, tmp_path):
+        rng = np.random.default_rng(45)
+        files = [((name, "f.bin"), rng.bytes(size)) for name, size, _ in WINDOW_FILES]
+        meta = v2.build_v2(files, "t", PLEN, hasher="cpu")
+        read_file = _on_disk(tmp_path, files)
+        for (path, data), (_, _, bad) in zip(files, WINDOW_FILES):
+            if bad is not None:
+                _flip(read_file(path), min(len(data) - 1, bad * PLEN + 7))
+        return meta, read_file
+
+    @pytest.mark.parametrize("road", ["path", "resident"])
+    def test_the_windowed_pass_gives_the_cpu_verdicts_twice(self, window_slab, road, tmp_path):
+        meta, read_file = self._damaged(tmp_path)
+        if road == "resident":
+            on_disk = read_file
+            read_file = lambda path: open(on_disk(path), "rb").read()  # noqa: E731
+        want = v2.verify_v2(read_file, meta, hasher="cpu")
+        assert sum(int((~ok).sum()) for ok in want.values()) == 4
+        for _ in range(2):  # the second pass over the slab the first left dirty
+            got = v2.verify_v2(read_file, meta, hasher="tpu")
+            assert set(got) == set(want)
+            for path in want:
+                assert np.array_equal(got[path], want[path]), path
+        st = window_slab.stats()
+        assert st["leaf_slab_allocs"] == 1 and st["leaf_slab_transient"] == 0
+
+    @pytest.mark.parametrize(
+        "files, launches",
+        [
+            ([(("one", "f.bin"), 3 * BLOCK)], 1),
+            ([(("a", "f.bin"), 40 * BLOCK + 9)], 3),  # 41 leaves: 16, 16 and 9 rows
+            ([(("a", "f.bin"), 40 * BLOCK + 9), (("b", "f.bin"), 5 * BLOCK), (("c", "f.bin"), 17 * BLOCK)], 6),
+        ],
+        ids=["one_launch", "one_file_of_three", "three_files_of_six"],
+    )
+    def test_a_pass_of_n_launches_overlaps_n_minus_one(self, small_batches, files, launches, monkeypatch):
+        monkeypatch.setattr(v2, "_leaf_slab", v2._LeafSlab())
+        rng = np.random.default_rng(launches)
+        files = [(path, rng.bytes(size)) for path, size in files]
+        meta = v2.build_v2(files, "t", PLEN, hasher="cpu")
+        data = dict(files)
+        counted = _launches()
+        for n_pass in (1, 2):
+            assert all(ok.all() for ok in v2.verify_v2(lambda p: data[p], meta, hasher="tpu").values())
+            assert _launches() - counted == n_pass * launches
+            assert v2.leaf_slab_stats()["leaf_launches_overlapped"] == n_pass * (launches - 1)
+
+    def test_the_ledger_the_histogram_and_the_counters_agree(self, small_batches, monkeypatch):
+        monkeypatch.setattr(v2, "_leaf_slab", v2._LeafSlab())
+        files = _corpus()
+        meta = v2.build_v2(files, "t", PLEN, hasher="cpu")
+        data = dict(files)
+        ops, counted, rows = _ledger_ops(), _launches(), _launch_rows()
+        v2.verify_v2(lambda p: data[p], meta, hasher="tpu")
+        after = _ledger_ops()
+        assert {k: after[k] - ops[k] for k in after} == {"h2d": 5, "launch": 5, "digest": 5}
+        assert _launches() - counted == _launch_rows() - rows == 5
+
+        # a pass that raises drains its window all the same
+        def stop(done, total):
+            if done >= 8:
+                raise RuntimeError("stop")
+
+        ops, counted = _ledger_ops(), _launches()
+        with pytest.raises(RuntimeError):
+            v2.verify_v2(lambda p: data[p], meta, hasher="tpu", progress_cb=stop)
+        after = _ledger_ops()
+        assert after["launch"] - ops["launch"] == after["digest"] - ops["digest"] == _launches() - counted == 3
+        assert not v2._leaf_slab._out
+
+    def test_a_file_torn_after_one_in_flight_leaves_that_file_right(self, small_batches, tmp_path, monkeypatch):
+        monkeypatch.setattr(v2, "_leaf_slab", v2._LeafSlab())
+        files = _corpus()
+        meta = v2.build_v2(files, "t", PLEN, hasher="cpu")
+        read_file = _on_disk(tmp_path, files)
+        large, mid, small = (path for path, _ in files)
+        ops = _ledger_ops()
+
+        def tear(done, total):
+            # the large file's second launch is fetched, its third in flight:
+            # the next file goes before the first of its rows is read
+            if done == 8:
+                os.remove(read_file(mid))
+
+        res = v2.verify_v2(read_file, meta, hasher="tpu", progress_cb=tear)
+        assert res[large].all() and len(res[large]) == 10
+        assert not res[mid].any() and len(res[mid]) == 3
+        assert res[small].all()
+        after = _ledger_ops()
+        assert after["launch"] - ops["launch"] == after["digest"] - ops["digest"] == 4
+        st = v2.leaf_slab_stats()
+        assert not v2._leaf_slab._out and st["leaf_slab_transient"] == 0
+        # the torn file drained the window: the small file's launch went out alone
+        assert st["leaf_launches_overlapped"] == 2
+
+
 class TestSlabMetrics:
     def test_the_counters_render_as_prometheus_text(self):
         from test_metrics import prom_lint
         from torrent_tpu.utils.metrics import render_leaf_slab_metrics
 
         text = render_leaf_slab_metrics(
-            {"leaf_slab_allocs": 2, "leaf_slab_reuses": 82, "leaf_slab_transient": 1, "direct": 84, "copied": 3}
+            {"leaf_slab_allocs": 2, "leaf_slab_reuses": 82, "leaf_slab_transient": 1, "direct": 84, "copied": 3,
+             "leaf_launches_overlapped": 83}
         )
         prom_lint(text)
         assert "torrent_tpu_v2_leaf_slab_allocs_total 2" in text
@@ -195,6 +341,19 @@ class TestSlabMetrics:
         assert "torrent_tpu_v2_leaf_slab_transient_total 1" in text
         assert 'torrent_tpu_v2_leaf_launches_staged_total{staged="direct"} 84' in text
         assert 'torrent_tpu_v2_leaf_launches_staged_total{staged="copied"} 3' in text
+        assert "torrent_tpu_v2_leaf_launches_overlapped_total 83" in text
+
+    def test_a_recheck_pass_moves_the_overlap_counter_on_metrics(self, small_batches, monkeypatch):
+        from torrent_tpu.obs import render_obs_metrics
+
+        monkeypatch.setattr(v2, "_leaf_slab", v2._LeafSlab())
+        files = _corpus()
+        meta = v2.build_v2(files, "t", PLEN, hasher="cpu")
+        data = dict(files)
+        v2.verify_v2(lambda p: data[p], meta, hasher="tpu")
+        text = render_obs_metrics()
+        prom_lint(text)
+        assert "torrent_tpu_v2_leaf_launches_overlapped_total 4" in text
 
     def test_metrics_carry_them(self, slab):
         from torrent_tpu.obs import render_obs_metrics

@@ -66,10 +66,21 @@ class TestDeviceRoad:
 
         mine = [s.removeprefix(TRACE_SPAN_PREFIX) for s in recorder.names(threading.get_ident())]
         file_start = ["pass_setup", "make_leaf_fn", "alloc_padded"]
-        launch = ["stage", "h2d", "launch", "digest"]
-        # resident bytes are not read; the fold comes after the last file's
-        # leaves, then one piece-layer check a file longer than a piece
-        assert mine == (file_start + launch * 3) + (file_start + launch) * 2 + ["merkle"] * 3, mine
+        launch = ["stage", "h2d", "launch"]
+        then_fetch = launch + ["digest"]  # a launch dispatched, then the one before it fetched
+        # resident bytes are not read; one launch is in flight across the
+        # files, so a launch's digest follows the next launch, of its file or
+        # the next; the fold comes after the last file's leaves, then one
+        # piece-layer check a file longer than a piece
+        assert mine == (
+            file_start + launch + then_fetch * 2 + (file_start + then_fetch) * 2 + ["digest"] + ["merkle"] * 3
+        ), mine
+        launches = [i for i, name in enumerate(mine) if name == "launch"]
+        digests = [i for i, name in enumerate(mine) if name == "digest"]
+        assert len(launches) == len(digests) == 5
+        for i in range(4):
+            assert launches[i + 1] < digests[i] < (launches[i + 2] if i < 3 else digests[4]), (i, mine)
+        assert digests[-1] < mine.index("merkle")
         _no_metadata(recorder.names())
         for child in recorder.of("make_leaf_fn") + recorder.of("alloc_padded"):
             assert any(recorder.inside(child, parent) for parent in recorder.of("pass_setup"))

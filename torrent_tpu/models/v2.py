@@ -178,9 +178,11 @@ def _make_leaf_fn(b: int, backend: str):
 # tests/test_v2_stage_spans.py holds the steps to this set.
 LEAF_STEP_MODULE_NAMES = frozenset({"jit__sha256_pallas_aligned", "jit_sha256_pieces_jax"})
 
-# Per-launch wall time of the v2 leaf path by kernel. Its per-kernel
-# counts are how a drop from Mosaic to the scan backend (a leaf batch
-# that is not a 1024-row multiple, a non-TPU platform) stays visible.
+# Per-launch wall time of the v2 leaf path by kernel, from the upload's
+# start to the fetch's end (in verify_v2's window of one that span holds
+# the next launch's staging and upload too). Its per-kernel counts are
+# how a drop from Mosaic to the scan backend (a leaf batch that is not a
+# 1024-row multiple, a non-TPU platform) stays visible.
 LEAF_LAUNCH_HIST = (
     "torrent_tpu_v2_leaf_launch_seconds",
     "v2 leaf-plane launch wall time (h2d + kernel + d2h) by kernel",
@@ -251,7 +253,7 @@ class _LeafSlab:
         self._out = False
         self._counts = {
             "leaf_slab_allocs": 0, "leaf_slab_reuses": 0, "leaf_slab_transient": 0,
-            "direct": 0, "copied": 0,
+            "direct": 0, "copied": 0, "leaf_launches_overlapped": 0,
         }
 
     def checkout(self, b: int) -> tuple[np.ndarray, bool]:
@@ -282,6 +284,11 @@ class _LeafSlab:
             self._cells.write("slab")
             self._counts["direct" if direct else "copied"] += 1
 
+    def count_overlap(self) -> None:
+        with self._lock:
+            self._cells.write("slab")
+            self._counts["leaf_launches_overlapped"] += 1
+
     def stats(self) -> dict[str, int]:
         with self._lock:
             self._cells.read("slab")
@@ -296,8 +303,11 @@ def leaf_slab_stats() -> dict[str, int]:
     of the process's leaf slab that allocated or grew it, found it large
     enough, or found it out and took a transient one; ``direct`` /
     ``copied``: leaf launches whose rows the native engine read into the
-    slab, or that were copied in from a resident chunk. Rendered by
-    ``/metrics`` (utils/metrics.py)."""
+    slab, or that were copied in from a resident chunk;
+    ``leaf_launches_overlapped``: leaf launches dispatched while an
+    earlier one was still unfetched (:class:`_LeafWindow`, n - 1 a
+    ``verify_v2`` pass of n launches). Rendered by ``/metrics``
+    (utils/metrics.py)."""
     return _leaf_slab.stats()
 
 
@@ -321,31 +331,98 @@ def _pad_rows(padded: np.ndarray, nbytes: int) -> np.ndarray:
     return nblocks
 
 
-def _launch_leaves(leaf_fn, padded, nblocks, nbytes: int = 0) -> np.ndarray:
-    """One counted launch of a :func:`_make_leaf_fn` pair → host
-    ``u32[b, 8]``. Synchronous, and three ledger stages: ``h2d`` blocks
-    until the batch is on the device, ``launch`` enqueues the leaf
-    function, ``digest`` fetches. ``nbytes`` is the live payload of the
-    batch; the upload moves the whole padded slab."""
+class _LeafFlight(NamedTuple):
+    """A leaf launch dispatched by :func:`_enqueue_leaves` and not yet
+    fetched: the device's ``u32[rows, 8]`` and what its fetch counts."""
+
+    out: object
+    kernel: str
+    nbytes: int
+    rows: int
+    live: int
+    t0: float
+
+
+def _enqueue_leaves(leaf_fn, padded, nblocks, nbytes: int = 0, copy: bool = False) -> _LeafFlight:
+    """The first half of a counted launch of a :func:`_make_leaf_fn`
+    pair, two ledger stages: ``h2d`` blocks until the batch is on the
+    device, ``launch`` enqueues the leaf function. ``nbytes`` is the live
+    payload of the batch; the upload moves the whole padded slab. Once it
+    returns ``padded`` may be refilled, except where the upload aliases
+    it: ``copy`` uploads a copy (:class:`_LeafWindow` on the CPU)."""
     import jax
     import jax.numpy as jnp
-
-    from torrent_tpu.obs.hist import histograms
 
     fn, kernel = leaf_fn
     ledger = pipeline_ledger()
     t0 = time.monotonic()
     with ledger.track("h2d", nbytes, moved=padded.nbytes + nblocks.nbytes):
-        on_device = jax.block_until_ready((jnp.asarray(padded), jnp.asarray(nblocks)))
+        rows = padded.copy() if copy else padded
+        on_device = jax.block_until_ready((jnp.asarray(rows), jnp.asarray(nblocks)))
     with ledger.track("launch", nbytes):
         out = fn(*on_device)
-    with ledger.track("digest", nbytes):
-        words = np.asarray(out)
-    histograms().get(*LEAF_LAUNCH_HIST, kernel=kernel).observe(
-        time.monotonic() - t0
+    return _LeafFlight(out, kernel, nbytes, padded.shape[0], int(np.count_nonzero(nblocks)), t0)
+
+
+def _launch_leaves(flight: _LeafFlight) -> np.ndarray:
+    """The second half of a counted leaf launch → host ``u32[rows, 8]``:
+    the ledger stage ``digest`` waits out the step and fetches, then the
+    launch is counted (:data:`LEAF_LAUNCH_HIST`, :func:`leaf_launch_stats`).
+    Every leaf launch's words come back here: the synchronous roads call
+    it right after :func:`_enqueue_leaves`, verify_v2's window one launch
+    later."""
+    from torrent_tpu.obs.hist import histograms
+
+    with pipeline_ledger().track("digest", flight.nbytes):
+        words = np.asarray(flight.out)
+    histograms().get(*LEAF_LAUNCH_HIST, kernel=flight.kernel).observe(
+        time.monotonic() - flight.t0
     )
-    _leaf_counters.add(kernel, padded.shape[0], int(np.count_nonzero(nblocks)))
+    _leaf_counters.add(flight.kernel, flight.rows, flight.live)
     return words
+
+
+class _LeafWindow:
+    """``verify_v2``'s window of one leaf launch across a pass: a launch
+    is fetched only once the next one, of its file or a later one, is
+    staged, uploaded and dispatched, so the chip hashes one while the host
+    reads, pads and uploads the next. Never wider, as ``verify_storage``'s.
+
+    The launches share the process's one slab (:class:`_LeafSlab`). On a
+    chip the blocking upload has copied it before the next launch is
+    staged; on the CPU backend ``jnp.asarray`` may alias an aligned slab
+    (``models/verifier._upload_must_copy``), so there a launch that goes in
+    flight uploads a copy. The synchronous roads fetch each launch before
+    they stage the next and need none."""
+
+    def __init__(self):
+        import jax
+
+        self.copy = jax.default_backend() == "cpu"
+        self._held = None  # (flight, land): the launch in flight
+
+    def put(self, flight: _LeafFlight, land) -> None:
+        """Hold ``flight``, just dispatched, and fetch the one before it;
+        ``land(words)`` takes a launch's words once it is fetched."""
+        before, self._held = self._held, (flight, land)
+        if before is not None:
+            _leaf_slab.count_overlap()
+            before_flight, before_land = before
+            before_land(_launch_leaves(before_flight))
+
+    def drain(self) -> None:
+        """Fetch the launch in flight, if any, and land its words."""
+        held, self._held = self._held, None
+        if held is not None:
+            held_flight, held_land = held
+            held_land(_launch_leaves(held_flight))
+
+    def drop(self) -> None:
+        """Fetch the launch in flight, if any, and drop its words: what a
+        pass that raises does, so that no launch outlives it uncounted."""
+        held, self._held = self._held, None
+        if held is not None:
+            _launch_leaves(held[0])
 
 
 def _leaf_bucket(total: int) -> int:
@@ -357,7 +434,7 @@ def _leaf_bucket(total: int) -> int:
 
 
 def _leaf_words_from_chunks(
-    chunks, total: int, backend: str, on_launch=None
+    chunks, total: int, backend: str, on_launch=None, window: _LeafWindow | None = None
 ) -> np.ndarray:
     """SHA-256 leaf hashes from an iterator of chunks → ``u32[n, 8]``,
     ``n = ceil(total / BLOCK)``.
@@ -375,7 +452,10 @@ def _leaf_words_from_chunks(
     :class:`_PathChunk` (read into them); either way the rows are those
     of the process's one kept slab (:class:`_LeafSlab`). Sentinel rows
     carry ``nblocks=0`` and never run. ``on_launch(leaves_done)`` is
-    called after every launch.
+    called after every launch is fetched. Without ``window`` each launch
+    is fetched before the next is staged, and the output is whole when
+    this returns; with one, every launch goes through it, and the output
+    is whole once ``window`` has fetched the last.
     """
     ledger = pipeline_ledger()
     n = max(1, -(-total // BLOCK))
@@ -385,22 +465,34 @@ def _leaf_words_from_chunks(
             leaf_fn = _make_leaf_fn(b, backend)
         out = np.zeros((n, 8), dtype=np.uint32)
         with annotate("alloc_padded"):
-            # The road is synchronous: _launch_leaves blocks in ``h2d``
-            # before ``launch`` and fetches the digest before it returns,
-            # so the slab is free to refill when it does. On the CPU
-            # backend jnp.asarray may alias a 64-byte-aligned host buffer
-            # (models/verifier.py, _upload_must_copy): it is this
-            # ordering, not luck, that keeps a reused slab safe there. A
-            # batch in flight would have to copy on the CPU, as _put_flat
-            # does.
+            # Every launch is staged in these rows. Without a window each
+            # is fetched before the next is staged, so they are free to
+            # refill on any backend. In a window the next launch is staged
+            # while this one runs: a chip's blocking upload has copied the
+            # rows by then, but the CPU backend's jnp.asarray may alias a
+            # 64-byte-aligned host buffer (models/verifier.py,
+            # _upload_must_copy), so there the window uploads a copy.
             padded, kept = _leaf_slab.checkout(b)
+    filled = 0
+
+    def launch(nblocks: np.ndarray, nbytes: int, k: int) -> None:
+        def land(words: np.ndarray) -> None:
+            nonlocal filled
+            out[filled : filled + k] = words[:k]
+            filled += k
+            if on_launch is not None:
+                on_launch(filled)
+
+        if window is None:
+            land(_launch_leaves(_enqueue_leaves(leaf_fn, padded, nblocks, nbytes)))
+        else:
+            window.put(_enqueue_leaves(leaf_fn, padded, nblocks, nbytes, window.copy), land)
+
     try:
         view = padded[:, :BLOCK]
-        start = 0
         for chunk in chunks:
             direct = isinstance(chunk, _PathChunk)
             nbytes = chunk.nbytes if direct else len(chunk)
-            k = -(-nbytes // BLOCK)
             if direct:
                 chunk.read_rows(padded)
             with ledger.track("stage", nbytes):
@@ -412,22 +504,23 @@ def _leaf_words_from_chunks(
                         view[full, :rem] = flat[full * BLOCK :]
                 nblocks = _pad_rows(padded, nbytes)
             _leaf_slab.count_launch(direct)
-            out[start : start + k] = _launch_leaves(leaf_fn, padded, nblocks, nbytes)[:k]
-            start += k
-            if on_launch is not None:
-                on_launch(start)
+            launch(nblocks, nbytes, -(-nbytes // BLOCK))
         if total == 0:  # empty source: single zero-length leaf
             with ledger.track("stage"):
                 nblocks = _pad_rows(padded, 0)
             _leaf_slab.count_launch(False)
-            out[0] = _launch_leaves(leaf_fn, padded, nblocks)[0]
+            launch(nblocks, 0, 1)
     finally:
+        # a launch still in the window has its rows on the device, or a
+        # copy of them: the slab is free for the next check-out
         if kept:
             _leaf_slab.checkin()
     return out
 
 
-def _leaf_words_device(source, backend: str, on_launch=None) -> np.ndarray:
+def _leaf_words_device(
+    source, backend: str, on_launch=None, window: _LeafWindow | None = None
+) -> np.ndarray:
     total = source_len(source)
     chunk_bytes = _leaf_bucket(total) * BLOCK
     engine = None
@@ -442,7 +535,7 @@ def _leaf_words_device(source, backend: str, on_launch=None) -> np.ndarray:
         )
     else:
         chunks = _iter_source(source, chunk_bytes)
-    return _leaf_words_from_chunks(chunks, total, backend, on_launch)
+    return _leaf_words_from_chunks(chunks, total, backend, on_launch, window)
 
 
 def _leaf_words_cpu_from_chunks(chunks) -> np.ndarray:
@@ -892,8 +985,15 @@ def verify_v2(
     total_pieces = sum(f.num_pieces(plen) for f in meta.info.files)
     done = total_pieces - sum(f.num_pieces(plen) for f, _ in todo)
 
+    # the device road keeps one leaf launch in flight across the pass, so
+    # a file's leaves are whole once the next file's first launch (or the
+    # drain at the end) has fetched its last: each entry goes to the fold
+    # one file behind the launches
+    window = None if hasher == "cpu" else _LeafWindow()
+
     def leaf_entries():
         nonlocal done
+        held = None  # the file before, whose last launch may be in flight
         for f, source in todo:
             base = done
             done += f.num_pieces(plen)
@@ -901,28 +1001,42 @@ def verify_v2(
             on_launch = None
             if progress_cb is not None:
 
-                def on_launch(leaves_done, f=f, base=base):
+                def on_launch(leaves_done, f=f, base=base, end=done):
                     # a file's last launch ends inside its (short) last piece
                     at_end = leaves_done * BLOCK >= f.length
-                    progress_cb(done if at_end else base + leaves_done // lpp, total_pieces)
+                    progress_cb(end if at_end else base + leaves_done // lpp, total_pieces)
 
             try:
-                if hasher == "cpu":
+                if window is None:
                     leaves = _leaf_words_cpu(source)
                     if on_launch is not None:
                         on_launch(len(leaves))
-                    yield f.length, leaves
                 else:
-                    yield f.length, _leaf_words_device(source, "auto", on_launch)
+                    leaves = _leaf_words_device(source, "auto", on_launch, window)
             except OSError:
                 # a path source deleted between phases: zero leaf words
                 # can't match any real root, so every piece of this file
-                # lands False — same verdict as a missing file
-                yield f.length, np.zeros(
-                    (max(1, -(-f.length // BLOCK)), 8), dtype=np.uint32
-                )
+                # lands False — same verdict as a missing file. The launch
+                # in flight (the file before's last, or one of this file's)
+                # is fetched first.
+                if window is not None:
+                    window.drain()
+                leaves = np.zeros((max(1, -(-f.length // BLOCK)), 8), dtype=np.uint32)
+            if held is not None:
+                yield held
+            held = f.length, leaves
+        if window is not None:
+            window.drain()
+        if held is not None:
+            yield held
 
-    reduced = roots_batched_windowed(leaf_entries(), plen, device=hasher != "cpu")
+    try:
+        reduced = roots_batched_windowed(leaf_entries(), plen, device=hasher != "cpu")
+    finally:
+        # leaf_entries drained the window; a pass that raised left one
+        # launch in flight at most
+        if window is not None:
+            window.drop()
     for ei, (f, _) in enumerate(todo):
         n_pieces = f.num_pieces(plen)
         ok = np.zeros(max(1, n_pieces), dtype=bool)

@@ -188,7 +188,7 @@ def verify_pieces_v2_tpu(
     """
     from torrent_tpu.codec.metainfo_v2 import BLOCK
     from torrent_tpu.models.merkle import merkle_root, words32_to_digests
-    from torrent_tpu.models.v2 import _launch_leaves, _make_leaf_fn
+    from torrent_tpu.models.v2 import _enqueue_leaves, _launch_leaves, _make_leaf_fn
     from torrent_tpu.ops.padding import alloc_padded, pad_in_place
 
     n = info.num_pieces
@@ -232,7 +232,7 @@ def verify_pieces_v2_tpu(
                     row_len[r] = blen
                 nblocks = pad_in_place(padded, row_len)
                 nblocks[len(chunk) :] = 0
-                words = _launch_leaves(leaf_fn, padded, nblocks)
+                words = _launch_leaves(_enqueue_leaves(leaf_fn, padded, nblocks))
                 for r, (i, bi, _blen) in enumerate(chunk):
                     grid[i, bi] = words[r]
             roots = words32_to_digests(merkle_root(grid))

@@ -293,9 +293,11 @@ def render_leaf_slab_metrics(stats: dict) -> str:
 
     ``stats`` is ``torrent_tpu.models.v2.leaf_slab_stats()``: check-outs
     of the process's one kept slab by what they found, and leaf launches
-    by how their rows were staged. A recheck that keeps allocating, or
+    by how their rows were staged, and the launches that went out while
+    an earlier one was in flight. A recheck that keeps allocating, or
     whose path sources go out ``copied``, has lost the kept slab or the
-    native engine."""
+    native engine; one whose launches stop overlapping has lost its
+    window."""
     lines = []
     for key, text in (
         ("leaf_slab_allocs", "Check-outs that allocated or grew the process's kept leaf slab"),
@@ -310,6 +312,12 @@ def render_leaf_slab_metrics(stats: dict) -> str:
         f"# TYPE {name} counter",
     ]
     lines += [f'{name}{{staged="{how}"}} {stats[how]}' for how in ("copied", "direct")]
+    name = "torrent_tpu_v2_leaf_launches_overlapped_total"
+    lines += [
+        f"# HELP {name} v2 leaf launches dispatched while an earlier one was still unfetched (verify_v2's window of one)",
+        f"# TYPE {name} counter",
+        f"{name} {stats['leaf_launches_overlapped']}",
+    ]
     return "\n".join(lines) + "\n"
 
 

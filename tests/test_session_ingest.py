@@ -874,9 +874,13 @@ def test_the_peer_downloads_on_while_its_piece_is_judged(tmp_path, hasher):
     peer loop that delivered it goes on: it ingests the next blocks and
     asks for more, and every other piece is delivered, judged and
     written before that verdict comes. Every hand-off after the first
-    found the peer's held piece at the judge."""
+    found the peer's held piece at the judge. The torrent is smaller than
+    the peer's judge bound (2 x 16 x 16 KiB), so that holds however late
+    the other verdicts come: one piece more and a loop whose pieces are
+    all still at the judge waits for the held one (the bound's own test
+    is below)."""
     src, dest = str(tmp_path / "src"), str(tmp_path / "dest")
-    length = 16 * PLEN + 5003
+    length = 15 * PLEN + 5003
     meta, _ = write_source(src, length=length)
     n = meta.info.num_pieces
 

@@ -168,6 +168,51 @@ class TestSha256Kernels:
             got = b"".join(int(w).to_bytes(4, "big") for w in words[i])
             assert got == hashlib.sha256(m).digest(), f"msg {i}"
 
+    def test_the_scan_takes_host_order_words(self):
+        """``sha256_pieces_jax`` on the ``u32`` view of a padded batch (the
+        leaf road's upload) equals its ``u8`` form and hashlib."""
+        rng = np.random.default_rng(46)
+        msgs = [rng.bytes(int(n)) for n in (0, 1, 55, 56, 64, 300, BLOCK - 1, BLOCK)]
+        padded, nblocks = pad_pieces(msgs)
+        u8 = np.asarray(sha256_pieces_jax(padded, nblocks))
+        u32 = np.asarray(sha256_pieces_jax(padded.view(np.uint32), nblocks))
+        assert np.array_equal(u8, u32)
+        assert words32_to_digests(u32) == [hashlib.sha256(m).digest() for m in msgs]
+
+    @pytest.mark.parametrize(
+        "blocks, tile_sub",
+        [
+            ((1024, 1024), 8),  # a tile a block
+            ((1024, 1024), 16),  # one tile joins two blocks
+            ((512, 1536), 8),  # tiles cut across a block boundary
+            ((1024, 1024, 1024, 1024), 32),  # the 4,096-row launch: one tile of four blocks
+        ],
+        ids=["tile_a_block", "tile_of_two_blocks", "tiles_across_blocks", "one_tile_of_four"],
+    )
+    def test_pallas_on_row_blocks_equals_one_array(self, blocks, tile_sub):
+        """The Pallas step (interpret) on a tuple of host-order ``u32`` row
+        blocks gives what it gives on the one ``u8`` array, and hashlib."""
+        import jax.numpy as jnp
+
+        from torrent_tpu.ops.sha256_pallas import sha256_pieces_pallas
+
+        rows = sum(blocks)
+        rng = np.random.default_rng(rows + tile_sub + blocks[0])
+        live = sorted(rng.choice(rows, size=24, replace=False))
+        msgs = [rng.bytes(int(n)) for n in rng.integers(0, 300, size=len(live))]
+        small, small_nb = pad_pieces(msgs + [b"x" * 300])
+        padded = np.zeros((rows, small.shape[1]), dtype=np.uint8)
+        nblocks = np.zeros(rows, dtype=np.int32)
+        padded[live] = small[:-1]
+        nblocks[live] = small_nb[:-1]
+        words = padded.view(np.uint32)
+        starts = np.cumsum((0,) + blocks)
+        parts = tuple(jnp.asarray(words[a:b]) for a, b in zip(starts[:-1], starts[1:]))
+        whole = np.asarray(sha256_pieces_pallas(padded, nblocks, interpret=True, tile_sub=tile_sub))
+        got = np.asarray(sha256_pieces_pallas(parts, jnp.asarray(nblocks), interpret=True, tile_sub=tile_sub))
+        assert np.array_equal(got, whole)
+        assert words32_to_digests(got[live]) == [hashlib.sha256(m).digest() for m in msgs]
+
     def test_pairs_matches_hashlib(self):
         rng = np.random.default_rng(3)
         kids = [rng.bytes(32) for _ in range(64)]

@@ -19,7 +19,7 @@ from torrent_tpu.native import io_engine
 from torrent_tpu.obs.ledger import pipeline_ledger
 
 from test_metrics import prom_lint
-from test_v2_stage_spans import PLEN, _corpus, _launches, _on_disk, small_batches  # noqa: F401
+from test_v2_stage_spans import PLEN, _corpus, _launches, _on_disk, pallas_leaves, small_batches  # noqa: F401
 
 
 @pytest.fixture
@@ -132,12 +132,12 @@ class TestCountersAndConcurrency:
         assert all(ok.all() for ok in v2.verify_v2(read_file, meta, hasher="tpu").values())
         first, launches = v2.leaf_slab_stats(), _launches()
         assert first == {"leaf_slab_allocs": 1, "leaf_slab_reuses": 2, "leaf_slab_transient": 0, "direct": 5, "copied": 0,
-                         "leaf_launches_overlapped": 4}
+                         "leaf_launches_overlapped": 4, "leaf_uploads_chunked": 0}
         assert all(ok.all() for ok in v2.verify_v2(read_file, meta, hasher="tpu").values())
         second = v2.leaf_slab_stats()
         assert _launches() - launches == 5
         assert second == {"leaf_slab_allocs": 1, "leaf_slab_reuses": 5, "leaf_slab_transient": 0, "direct": 10, "copied": 0,
-                          "leaf_launches_overlapped": 8}
+                          "leaf_launches_overlapped": 8, "leaf_uploads_chunked": 0}
 
     def test_two_threads_at_once_one_takes_a_transient_slab(self, slab):
         rng = np.random.default_rng(3)
@@ -333,7 +333,7 @@ class TestSlabMetrics:
 
         text = render_leaf_slab_metrics(
             {"leaf_slab_allocs": 2, "leaf_slab_reuses": 82, "leaf_slab_transient": 1, "direct": 84, "copied": 3,
-             "leaf_launches_overlapped": 83}
+             "leaf_launches_overlapped": 83, "leaf_uploads_chunked": 12}
         )
         prom_lint(text)
         assert "torrent_tpu_v2_leaf_slab_allocs_total 2" in text
@@ -342,6 +342,7 @@ class TestSlabMetrics:
         assert 'torrent_tpu_v2_leaf_launches_staged_total{staged="direct"} 84' in text
         assert 'torrent_tpu_v2_leaf_launches_staged_total{staged="copied"} 3' in text
         assert "torrent_tpu_v2_leaf_launches_overlapped_total 83" in text
+        assert "torrent_tpu_v2_leaf_uploads_chunked_total 12" in text
 
     def test_a_recheck_pass_moves_the_overlap_counter_on_metrics(self, small_batches, monkeypatch):
         from torrent_tpu.obs import render_obs_metrics
@@ -354,6 +355,7 @@ class TestSlabMetrics:
         text = render_obs_metrics()
         prom_lint(text)
         assert "torrent_tpu_v2_leaf_launches_overlapped_total 4" in text
+        assert "torrent_tpu_v2_leaf_uploads_chunked_total 0" in text  # 16-row launches: scan, one put each
 
     def test_metrics_carry_them(self, slab):
         from torrent_tpu.obs import render_obs_metrics
@@ -362,3 +364,90 @@ class TestSlabMetrics:
         text = render_obs_metrics()
         assert "torrent_tpu_v2_leaf_slab_reuses_total 1" in text
         assert 'torrent_tpu_v2_leaf_launches_staged_total{staged="copied"} 1' in text
+
+
+# A leaf bucket of every size up to LEAF_BATCH 4,096 (the 16-row floor to
+# the 4,096-row Pallas launch of four row blocks), as a file whose leaves
+# fill more than half of it and end short, and one piece of it bad.
+BUCKETS = [16, 32, 64, 128, 256, 512, 1024, 2048, 4096]
+
+
+class TestHostOrderUpload:
+    """Every leaf launch goes up as host-order ``u32`` rows: a Pallas launch
+    of 2,048 rows and more in concurrent row blocks, any other in one put.
+    The chip's choice of kernel runs here in interpret mode
+    (``pallas_leaves``)."""
+
+    @pytest.fixture
+    def dirty_slab(self, monkeypatch):
+        """LEAF_BATCH 4,096 and a kept slab of 4,096 rows, 0xFF in every byte."""
+        monkeypatch.setattr(v2, "LEAF_BATCH", 4096)
+        fresh = v2._LeafSlab()
+        padded, _ = fresh.checkout(4096)
+        padded[:] = 0xFF
+        fresh.checkin()
+        monkeypatch.setattr(v2, "_leaf_slab", fresh)
+        return fresh
+
+    @pytest.mark.parametrize("road", ["path", "resident"])
+    @pytest.mark.parametrize("rows", BUCKETS)
+    def test_every_bucket_gives_hashlibs_verdicts_and_leaves(self, pallas_leaves, dirty_slab, rows, road, tmp_path):
+        rng = np.random.default_rng(rows)
+        leaves = rows * 3 // 4 + 1
+        files = [(("bucket", "f.bin"), rng.bytes(leaves * BLOCK - 523)), (("after", "f.bin"), rng.bytes(2 * BLOCK + 9))]
+        meta = v2.build_v2(files, "t", PLEN, hasher="cpu")
+        read_file = _on_disk(tmp_path, files)
+        bad = (leaves // 4) // 2
+        _flip(read_file(files[0][0]), bad * PLEN + 11)
+        if road == "resident":
+            on_disk = read_file
+            read_file = lambda path: open(on_disk(path), "rb").read()  # noqa: E731
+        want = v2.verify_v2(read_file, meta, hasher="cpu")
+        assert [int(i) for i in np.flatnonzero(~want[files[0][0]])] == [bad] and want[files[1][0]].all()
+        before = dirty_slab.stats()
+        # the bucket's launch is in flight while the next file's is staged
+        # in the slab it went up from: on the CPU each of its blocks is a copy
+        got = v2.verify_v2(read_file, meta, hasher="tpu")
+        assert {p: list(ok) for p, ok in got.items()} == {p: list(ok) for p, ok in want.items()}
+        after = dirty_slab.stats()
+        assert after["leaf_launches_overlapped"] - before["leaf_launches_overlapped"] == 1
+        assert after["leaf_uploads_chunked"] - before["leaf_uploads_chunked"] == (rows >= 2048)
+        source = read_file(files[0][0])
+        data = open(source, "rb").read() if road == "path" else source
+        assert np.array_equal(v2._leaf_words_device(source, "auto"), _hashlib_words(data))
+
+    @pytest.mark.parametrize("rows", [2048, 4096, 8192])
+    def test_a_windowed_upload_copies_every_block(self, rows):
+        """On the CPU backend ``device_put`` may alias a row block of the
+        slab; the window's launch (``copy``) puts a copy of each, so the
+        slab refilled at once leaves what went up as it was."""
+        padded = v2.alloc_padded(rows, BLOCK)[0]
+        padded[:, :BLOCK] = np.random.default_rng(rows).integers(0, 256, (rows, BLOCK), dtype=np.uint8)
+        nblocks = v2._pad_rows(padded, rows * BLOCK)
+        want = padded.view(np.uint32).copy()
+        as_uploaded = (lambda blocks, nb: blocks), "pallas"  # a step that hands back what it was given
+        flight = v2._enqueue_leaves(as_uploaded, padded, nblocks, copy=True)
+        padded[:] = 0xEE  # the next launch staged into the same rows
+        assert isinstance(flight.out, tuple) and len(flight.out) == min(4, rows // 1024)
+        assert np.array_equal(np.concatenate([np.asarray(blk) for blk in flight.out]), want)
+
+    def test_only_pallas_launches_go_up_in_blocks(self, pallas_leaves, dirty_slab):
+        """``leaf_uploads_chunked`` counts the Pallas launches of 2,048 rows
+        and more, never a scan launch nor a 1,024-row one (one block)."""
+        kernels = {}
+        for rows in BUCKETS:
+            before, launched = dirty_slab.stats()["leaf_uploads_chunked"], v2.leaf_launch_stats()
+            data = os.urandom((rows - 1) * BLOCK + 1)
+            assert np.array_equal(v2._leaf_words_device(data, "auto"), _hashlib_words(data))
+            now = v2.leaf_launch_stats()
+            (kernel,) = [k for k in now if now[k]["launches"] != launched.get(k, {}).get("launches", 0)]
+            kernels[rows] = (kernel, dirty_slab.stats()["leaf_uploads_chunked"] - before)
+        assert kernels == {rows: ("pallas" if rows >= 1024 else "scan", int(rows >= 2048)) for rows in BUCKETS}
+
+    def test_the_sessions_ingest_goes_up_in_one_put(self, pallas_leaves, dirty_slab):
+        from torrent_tpu.session import v2 as session_v2
+
+        pieces = [os.urandom(PLEN), os.urandom(PLEN), os.urandom(PLEN - 600)]
+        roots = [v2.hash_file_v2(p, PLEN, hasher="cpu")[0] for p in pieces]
+        assert list(session_v2.ingest_verdicts(pieces, roots, [4, 4, 4], PLEN)) == [True] * 3
+        assert dirty_slab.stats()["leaf_uploads_chunked"] == 0

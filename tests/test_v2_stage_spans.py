@@ -41,6 +41,28 @@ def _corpus(seed=11):
     ]
 
 
+@pytest.fixture
+def pallas_leaves(monkeypatch):
+    """The chip's choice of leaf kernel on the CPU: ``_make_leaf_fn`` picks
+    as it does on a TPU (Pallas for a 1,024-row multiple, the scan below
+    it), and the Pallas kernel runs in interpret mode."""
+    import functools
+
+    from torrent_tpu.ops import sha1_pallas
+
+    real = v2._make_leaf_fn
+
+    def chip_choice(b, backend):
+        with monkeypatch.context() as m:
+            m.setattr(sha1_pallas, "_auto_interpret", lambda: False)
+            fn, kernel = real(b, backend)
+        if kernel == "pallas":
+            fn = functools.partial(fn, interpret=True)
+        return fn, kernel
+
+    monkeypatch.setattr(v2, "_make_leaf_fn", chip_choice)
+
+
 def _launches() -> int:
     snap = histograms().family_snapshot(v2.LEAF_LAUNCH_HIST[0])
     return 0 if snap is None else snap[1]
@@ -111,7 +133,37 @@ class TestDeviceRoad:
         assert stages["launch"]["ops"] == stages["h2d"]["ops"] == stages["digest"]["ops"] == 5
         assert _launches() - counted == 5
         assert delta == {"launches": 5, "rows_launched": 80, "rows_live": 50}
-        assert set(after) == {"scan"}  # off a TPU the Pallas kernel is never chosen
+        # off a TPU the Pallas kernel is never chosen: this pass launched none
+        # (other tests of the process may have forced it, see pallas_leaves)
+        assert after.get("pallas") == rows.get("pallas")
+
+    @pytest.mark.parametrize("rows, blocks", [(1024, 1), (2048, 2), (4096, 4)])
+    def test_a_chunked_upload_is_one_h2d_entry_of_the_padded_slab(self, pallas_leaves, rows, blocks, monkeypatch):
+        """However many row blocks a Pallas launch goes up in, ``h2d`` is one
+        ledger entry a launch, and its ``moved`` bytes are the padded
+        slab's and its block counts'."""
+        monkeypatch.setattr(v2, "LEAF_BATCH", rows)
+        monkeypatch.setattr(v2, "_leaf_slab", v2._LeafSlab())
+        data = os.urandom((rows - 3) * BLOCK + 77)
+        pipeline_ledger().clear()
+        from torrent_tpu.models import verifier
+
+        puts, real_put = [], verifier.put_row_blocks
+
+        def recording(pool, words, step, copy):
+            blocks = real_put(pool, words, step, copy)
+            puts.append([blk.shape for blk in blocks])
+            return blocks
+
+        monkeypatch.setattr(verifier, "put_row_blocks", recording)
+        got = v2._leaf_words_device(data, "auto")
+        assert got.shape == (rows - 2, 8)
+        h2d = pipeline_ledger().snapshot()["stages"]["h2d"]
+        width = v2.alloc_padded(1, BLOCK)[0].shape[1]
+        assert h2d["ops"] == 1 and h2d["bytes"] == len(data)
+        assert h2d["moved_bytes"] == rows * (width + 4)
+        assert puts == ([[(rows // blocks, width // 4)] * blocks] if blocks > 1 else [])
+        assert v2.leaf_slab_stats()["leaf_uploads_chunked"] == (blocks > 1)
 
     def test_hasher_cpu_charges_no_device_stage(self, recorder, small_batches, tmp_path):
         files = _corpus()
@@ -264,13 +316,19 @@ class TestLeafStepModuleNames:
         width = v2.alloc_padded(1, BLOCK)[0].shape[1]
         u8 = jax.ShapeDtypeStruct((1024, width), np.uint8)
         nb = jax.ShapeDtypeStruct((1024,), np.int32)
-        lowered = [
-            sha256_pieces_jax.lower(u8, nb),
-            sp._sha256_pallas_aligned.lower(u8, nb, interpret=True, tile_sub=8, unroll=sp.UNROLL,
-                                            full_unroll=False, interleave2=False),
-        ]
-        names = {re.search(r"module @(\S+)", lo.as_text()).group(1) for lo in lowered}
-        assert names == v2.LEAF_STEP_MODULE_NAMES
+        # the leaf road's upload: host-order u32 rows, in row blocks for Pallas
+        u32 = jax.ShapeDtypeStruct((1024, width // 4), np.uint32)
+        halves = (jax.ShapeDtypeStruct((512, width // 4), np.uint32),) * 2
+        pallas = dict(interpret=True, tile_sub=8, unroll=sp.UNROLL, full_unroll=False, interleave2=False)
+        lowered = {
+            "scan_u8": sha256_pieces_jax.lower(u8, nb),
+            "scan_u32": sha256_pieces_jax.lower(u32, nb),
+            "pallas_u8": sp._sha256_pallas_aligned.lower(u8, nb, **pallas),
+            "pallas_row_blocks": sp._sha256_pallas_aligned.lower(halves, nb, **pallas),
+        }
+        names = {form: re.search(r"module @(\S+)", lo.as_text()).group(1) for form, lo in lowered.items()}
+        assert set(names.values()) == v2.LEAF_STEP_MODULE_NAMES
+        assert names["scan_u8"] == names["scan_u32"] and names["pallas_u8"] == names["pallas_row_blocks"]
 
     def test_the_merkle_reduce_has_names_of_its_own(self):
         import jax

@@ -22,6 +22,7 @@ from __future__ import annotations
 import functools
 import hashlib
 import time
+from concurrent.futures import ThreadPoolExecutor
 from typing import NamedTuple
 
 import numpy as np
@@ -253,8 +254,12 @@ class _LeafSlab:
         self._out = False
         self._counts = {
             "leaf_slab_allocs": 0, "leaf_slab_reuses": 0, "leaf_slab_transient": 0,
-            "direct": 0, "copied": 0, "leaf_launches_overlapped": 0,
+            "direct": 0, "copied": 0, "leaf_launches_overlapped": 0, "leaf_uploads_chunked": 0,
         }
+        # the leaf road's one pool of upload workers, built on the first
+        # launch that goes up in row blocks (_enqueue_leaves)
+        self._pool_lock = named_lock("models.v2._upload_pool_lock")
+        self._pool: ThreadPoolExecutor | None = None
 
     def checkout(self, b: int) -> tuple[np.ndarray, bool]:
         """``(uint8[b, padded_len], kept)``: rows for the caller alone,
@@ -289,6 +294,21 @@ class _LeafSlab:
             self._cells.write("slab")
             self._counts["leaf_launches_overlapped"] += 1
 
+    def count_chunked(self) -> None:
+        with self._lock:
+            self._cells.write("slab")
+            self._counts["leaf_uploads_chunked"] += 1
+
+    def upload_pool(self) -> ThreadPoolExecutor:
+        """The pool a chunked leaf upload puts its blocks on, as many
+        workers as the SHA-1 flat road's."""
+        with self._pool_lock:
+            if self._pool is None:
+                from torrent_tpu.models.verifier import upload_chunks
+
+                self._pool = ThreadPoolExecutor(max_workers=upload_chunks())
+            return self._pool
+
     def stats(self) -> dict[str, int]:
         with self._lock:
             self._cells.read("slab")
@@ -306,7 +326,9 @@ def leaf_slab_stats() -> dict[str, int]:
     slab, or that were copied in from a resident chunk;
     ``leaf_launches_overlapped``: leaf launches dispatched while an
     earlier one was still unfetched (:class:`_LeafWindow`, n - 1 a
-    ``verify_v2`` pass of n launches). Rendered by ``/metrics``
+    ``verify_v2`` pass of n launches); ``leaf_uploads_chunked``: leaf
+    launches whose slab went up in row blocks (:func:`_leaf_block_rows`:
+    Pallas launches of 2,048 rows and more). Rendered by ``/metrics``
     (utils/metrics.py)."""
     return _leaf_slab.stats()
 
@@ -343,22 +365,52 @@ class _LeafFlight(NamedTuple):
     t0: float
 
 
+def _leaf_block_rows(kernel: str, rows: int) -> int:
+    """Rows a block of a leaf launch's upload: a launch for the Pallas
+    kernel (a ``SUB_TILE_ROWS`` multiple) goes up in as many blocks as
+    the SHA-1 flat road's upload (``models.verifier.upload_chunks``), each
+    a whole number of 1,024-row sub-tiles, so a tile reads its rows from
+    one block or joins a few sub-tile slices; a scan launch (16–512 rows:
+    the small files, the session's ingest) goes up in one put, where the
+    pool's hops would cost more than they save."""
+    if kernel != "pallas":
+        return rows
+    from torrent_tpu.models.verifier import upload_chunks
+    from torrent_tpu.ops.sha256_pallas import SUB_TILE_ROWS
+
+    n = max(1, min(upload_chunks(), rows // SUB_TILE_ROWS))
+    return -(-rows // (n * SUB_TILE_ROWS)) * SUB_TILE_ROWS
+
+
 def _enqueue_leaves(leaf_fn, padded, nblocks, nbytes: int = 0, copy: bool = False) -> _LeafFlight:
     """The first half of a counted launch of a :func:`_make_leaf_fn`
     pair, two ledger stages: ``h2d`` blocks until the batch is on the
     device, ``launch`` enqueues the leaf function. ``nbytes`` is the live
-    payload of the batch; the upload moves the whole padded slab. Once it
-    returns ``padded`` may be refilled, except where the upload aliases
-    it: ``copy`` uploads a copy (:class:`_LeafWindow` on the CPU)."""
+    payload of the batch; the upload moves the whole padded slab, as
+    host-order ``u32`` rows (a free view, which the steps read without
+    a byte-to-word conversion): a Pallas launch in concurrent row blocks
+    on the leaf road's pool (:func:`_leaf_block_rows`, counted
+    ``leaf_uploads_chunked``), which the kernel takes as they are, any
+    other in one put. Once it returns ``padded`` may be refilled, except
+    where the upload aliases it: ``copy`` uploads a copy of every block
+    (:class:`_LeafWindow` on the CPU)."""
     import jax
     import jax.numpy as jnp
 
     fn, kernel = leaf_fn
     ledger = pipeline_ledger()
+    words = padded.view(np.uint32)
+    step = _leaf_block_rows(kernel, padded.shape[0])
     t0 = time.monotonic()
     with ledger.track("h2d", nbytes, moved=padded.nbytes + nblocks.nbytes):
-        rows = padded.copy() if copy else padded
-        on_device = jax.block_until_ready((jnp.asarray(rows), jnp.asarray(nblocks)))
+        if step < padded.shape[0]:
+            from torrent_tpu.models.verifier import put_row_blocks
+
+            rows = tuple(put_row_blocks(_leaf_slab.upload_pool(), words, step, copy))
+            _leaf_slab.count_chunked()
+        else:
+            rows = jax.device_put(words.copy() if copy else words)
+        on_device = jax.block_until_ready((rows, jnp.asarray(nblocks)))
     with ledger.track("launch", nbytes):
         out = fn(*on_device)
     return _LeafFlight(out, kernel, nbytes, padded.shape[0], int(np.count_nonzero(nblocks)), t0)
@@ -390,10 +442,11 @@ class _LeafWindow:
 
     The launches share the process's one slab (:class:`_LeafSlab`). On a
     chip the blocking upload has copied it before the next launch is
-    staged; on the CPU backend ``jnp.asarray`` may alias an aligned slab
-    (``models/verifier._upload_must_copy``), so there a launch that goes in
-    flight uploads a copy. The synchronous roads fetch each launch before
-    they stage the next and need none."""
+    staged; on the CPU backend ``device_put`` may alias an aligned slab,
+    or a row block of it (``models/verifier._upload_must_copy``), so there
+    a launch that goes in flight uploads a copy of every block. The
+    synchronous roads fetch each launch before they stage the next and
+    need none."""
 
     def __init__(self):
         import jax

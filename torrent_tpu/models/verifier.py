@@ -186,6 +186,31 @@ def _build_steps(backend: str, tile_sub: int | None, mesh) -> _Steps:
     )
 
 
+def upload_chunks() -> int:
+    """Concurrent row-block puts an upload makes, and the workers of the
+    pool that makes them: 4, chosen on a retired setup, not measured on
+    this one (the SHA-1 flat road; the v2 leaf road takes at most as
+    many, ``models/v2._leaf_block_rows``)."""
+    return env_int("TORRENT_TPU_UPLOAD_CHUNKS", 4)
+
+
+def put_row_blocks(
+    pool: ThreadPoolExecutor, words: np.ndarray, step: int, copy: bool
+) -> list[jax.Array]:
+    """Upload the rows of ``words`` (host-order ``u32``, a free view of the
+    staged bytes) as blocks of ``step`` rows, one ``device_put`` a block on
+    ``pool``, and block until every block is resident, so the caller may
+    refill the rows at once. ``copy`` puts a copy of each block, in the
+    workers: the CPU backend's ``device_put`` may alias an aligned host
+    buffer (``_upload_must_copy``)."""
+    views = [words[i : i + step] for i in range(0, words.shape[0], step)]
+    put = (lambda v: jax.device_put(v.copy())) if copy else jax.device_put
+    blocks = list(pool.map(put, views))
+    for blk in blocks:
+        blk.block_until_ready()
+    return blocks
+
+
 class _StepCache:
     """The process's jitted steps, one set a (backend, tile_sub, mesh).
 
@@ -399,9 +424,7 @@ class TPUVerifier:
         self._verify_step = steps.verify_step
         self._verify_step_flat = steps.verify_step_flat
         self._digest_step_flat = steps.digest_step_flat
-        # 4 concurrent upload streams: chosen on a retired setup, not
-        # measured on this one.
-        self._upload_chunks = env_int("TORRENT_TPU_UPLOAD_CHUNKS", 4)
+        self._upload_chunks = upload_chunks()
         # Row counts the flat road takes on a one-device mesh: the
         # verifier's own batch, and what a caller warms besides (the
         # scheduler's SHA-1 plane adds its row ladder). Any other shape
@@ -449,19 +472,8 @@ class TPUVerifier:
             if self._upload_pool is None:
                 self._upload_pool = ThreadPoolExecutor(max_workers=self._upload_chunks)
             pool = self._upload_pool
-        rows = padded.shape[0]
-        step = -(-rows // self._upload_chunks)
-        views = [
-            padded[i : i + step].view(np.uint32) for i in range(0, rows, step)
-        ]
-        if self._upload_must_copy:
-            put = lambda v: jax.device_put(v.copy())
-        else:
-            put = jax.device_put
-        chunks = list(pool.map(put, views))
-        for c in chunks:
-            c.block_until_ready()
-        return chunks
+        step = -(-padded.shape[0] // self._upload_chunks)
+        return put_row_blocks(pool, padded.view(np.uint32), step, self._upload_must_copy)
 
     # ------------------------------------------------------------ raw steps
 

@@ -100,17 +100,24 @@ def _compress256(state, w16):
     return tuple(s + n for s, n in zip(state, new))
 
 
-def bytes_to_schedule256(data_u8: jax.Array) -> jax.Array:
-    """``uint8[B, padded]`` → ``uint32[nblk, 16, B]`` big-endian schedule.
+def bytes_to_schedule256(data: jax.Array) -> jax.Array:
+    """``uint8[B, padded]`` or host-order ``uint32[B, padded // 4]`` →
+    ``uint32[nblk, 16, B]`` big-endian schedule.
 
     Identical packing to SHA1 (both are big-endian 64-byte-block Merkle-
-    Damgård); kept separate for call-site clarity.
+    Damgård); kept separate for call-site clarity. ``u32`` rows (the leaf
+    road's upload, a free ``ndarray.view`` on the host) skip the 4-byte
+    quads and their bitcast, which lower through a 4×-widened convert on
+    TPU; either way the words are byte-swapped to big-endian here.
     """
-    b, padded = data_u8.shape
-    nblk = padded // 64
-    quads = data_u8.reshape(b, nblk * 16, 4)
-    words = _bswap32(jax.lax.bitcast_convert_type(quads, jnp.uint32))
-    return jnp.transpose(words.reshape(b, nblk, 16), (1, 2, 0))
+    if data.dtype == jnp.uint32:
+        words = data
+    else:
+        quads = data.reshape(data.shape[0], data.shape[1] // 4, 4)
+        words = jax.lax.bitcast_convert_type(quads, jnp.uint32)
+    b, nwords = words.shape
+    words = _bswap32(words).reshape(b, nwords // 16, 16)
+    return jnp.transpose(words, (1, 2, 0))
 
 
 def sha256_chain(schedule: jax.Array, nblocks: jax.Array) -> jax.Array:
@@ -130,9 +137,10 @@ def sha256_chain(schedule: jax.Array, nblocks: jax.Array) -> jax.Array:
 
 
 @functools.partial(jax.jit, static_argnames=())
-def sha256_pieces_jax(data_u8: jax.Array, nblocks: jax.Array) -> jax.Array:
-    """Batched SHA-256: ``uint8[B, padded]``, ``int32[B]`` → ``uint32[B, 8]``."""
-    return sha256_chain(bytes_to_schedule256(data_u8), nblocks)
+def sha256_pieces_jax(data: jax.Array, nblocks: jax.Array) -> jax.Array:
+    """Batched SHA-256: ``uint8[B, padded]`` or host-order
+    ``uint32[B, padded // 4]``, ``int32[B]`` → ``uint32[B, 8]``."""
+    return sha256_chain(bytes_to_schedule256(data), nblocks)
 
 
 def make_sha256_fn(backend: str = "jax"):

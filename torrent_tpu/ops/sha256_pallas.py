@@ -247,6 +247,32 @@ def _sha256_kernel(
         state_ref[0, i] = state[i]
 
 
+def _row_blocks(data) -> tuple:
+    """The row blocks of a batch: ``data`` itself, or the tuple of
+    arrays it was uploaded as, stacked along the rows in order."""
+    return data if isinstance(data, tuple) else (data,)
+
+
+def _as_words(x: jax.Array) -> jax.Array:
+    """Host-order ``u32`` rows as they are; ``u8`` rows bitcast in 4-byte
+    quads (the widening lowering makes this the slow form on TPU)."""
+    if x.dtype == jnp.uint32:
+        return x
+    return jax.lax.bitcast_convert_type(x.reshape(x.shape[0], x.shape[1] // 4, 4), jnp.uint32)
+
+
+def _rows(blocks, r0: int, r1: int) -> jax.Array:
+    """Rows ``[r0, r1)`` of the row-stacked ``blocks``: a slice of the one
+    block that holds them, or the slices of each joined along the rows."""
+    parts, start = [], 0
+    for x in blocks:
+        lo, hi = max(r0, start), min(r1, start + x.shape[0])
+        if lo < hi:
+            parts.append(x[lo - start : hi - start])
+        start += x.shape[0]
+    return parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=0)
+
+
 @functools.partial(
     jax.jit,
     static_argnames=("interpret", "tile_sub", "unroll", "full_unroll", "interleave2"),
@@ -255,19 +281,11 @@ def _sha256_pallas_aligned(
     data, nblocks, interpret, tile_sub, unroll, full_unroll, interleave2=False
 ):
     tile = tile_sub * TILE_LANE
-    b = data.shape[0]
-    if data.dtype == jnp.uint32:
-        data32 = data
-    else:
-        data32 = jax.lax.bitcast_convert_type(
-            data.reshape(b, data.shape[1] // 4, 4), jnp.uint32
-        )
-    nblk = data32.shape[1] // 16
+    blocks = [_as_words(x) for x in _row_blocks(data)]
+    b = sum(x.shape[0] for x in blocks)
+    nblk = blocks[0].shape[1] // 16
     unroll = min(unroll, nblk)
     nblk_pad = ((nblk + unroll - 1) // unroll) * unroll
-    if nblk_pad != nblk:
-        data32 = jnp.pad(data32, ((0, 0), (0, (nblk_pad - nblk) * 16)))
-        nblk = nblk_pad
     nb = nblocks.astype(jnp.int32).reshape(b // tile, tile_sub, TILE_LANE)
     kc = jnp.asarray(np.array(_K256[16:], dtype=np.uint32).reshape(3, 16))
 
@@ -281,7 +299,7 @@ def _sha256_pallas_aligned(
             full=bool(full_unroll) and not interpret,
             interleave2=interleave2,
         ),
-        grid=(1, nblk // unroll),
+        grid=(1, nblk_pad // unroll),
         in_specs=[
             pl.BlockSpec(
                 (1, unroll, 16, tile_sub, TILE_LANE),
@@ -305,7 +323,10 @@ def _sha256_pallas_aligned(
 
     states = []
     for r0 in range(0, b, tile):
-        words = _swizzle_tile(data32[r0 : r0 + tile], nblk, tile_sub)
+        tile_words = _rows(blocks, r0, r0 + tile)
+        if nblk_pad != nblk:
+            tile_words = jnp.pad(tile_words, ((0, 0), (0, (nblk_pad - nblk) * 16)))
+        words = _swizzle_tile(tile_words, nblk_pad, tile_sub)
         states.append(call(words, nb[r0 // tile : r0 // tile + 1], kc))
     state = jnp.concatenate(states, axis=0) if len(states) > 1 else states[0]
     return jnp.transpose(state, (0, 2, 3, 1)).reshape(b, 8)
@@ -321,6 +342,11 @@ def sha256_pieces_pallas(
     interleave2: bool | None = None,
 ) -> jax.Array:
     """Batched SHA-256 via Pallas; pads the batch to a tile multiple.
+
+    ``data`` is ``uint8[B, padded]``, host-order ``uint32[B, padded//4]``,
+    or a tuple of such row blocks stacked along the rows (the leaf road's
+    concurrent upload): each tile then reads its rows from the block that
+    holds them, and only a tile that spans blocks joins its slices.
 
     ``interleave2`` (env ``TORRENT_TPU_SHA256_INTERLEAVE2``, default
     off) alternates two half-tiles' round chains — see the SHA-1
@@ -339,10 +365,11 @@ def sha256_pieces_pallas(
             f"interleave2 needs tile_sub >= 16 with 8-sublane halves, got {ts}"
         )
     tile = ts * TILE_LANE
-    b = data.shape[0]
+    blocks = _row_blocks(data)
+    b = sum(x.shape[0] for x in blocks)
     bp = ((b + tile - 1) // tile) * tile
     if bp != b:
-        data = jnp.pad(data, ((0, bp - b), (0, 0)))
+        data = (*blocks[:-1], jnp.pad(blocks[-1], ((0, bp - b), (0, 0))))
         nblocks = jnp.pad(nblocks, (0, bp - b))
     out = _sha256_pallas_aligned(data, nblocks, interpret, ts, un, fu, il2)
     return out[:b]

@@ -1233,6 +1233,13 @@ class Torrent:
                     await proto.send_message(p.writer, proto.Choke())
                 except (ConnectionError, OSError):
                     pass
+        # Pieces a peer loop already put to the judge get their verdicts
+        # before pause returns, so no bit is set while paused. A hand-off
+        # held by the judge bound puts its piece once the one it waits for
+        # is judged, so the queues are read again until nothing is left.
+        while pending := [t for p in list(self.peers.values()) for t, _ in p.at_judge if not t.done()]:
+            await asyncio.wait(pending)
+            await asyncio.sleep(0)  # let a held hand-off take its piece
 
     async def resume(self) -> None:
         """Undo ``pause``: refill pipelines; the choke loop re-unchokes."""

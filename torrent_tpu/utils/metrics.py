@@ -294,10 +294,10 @@ def render_leaf_slab_metrics(stats: dict) -> str:
     ``stats`` is ``torrent_tpu.models.v2.leaf_slab_stats()``: check-outs
     of the process's one kept slab by what they found, and leaf launches
     by how their rows were staged, and the launches that went out while
-    an earlier one was in flight. A recheck that keeps allocating, or
-    whose path sources go out ``copied``, has lost the kept slab or the
-    native engine; one whose launches stop overlapping has lost its
-    window."""
+    an earlier one was in flight, and those whose slab went up in row
+    blocks. A recheck that keeps allocating, or whose path sources go out
+    ``copied``, has lost the kept slab or the native engine; one whose
+    launches stop overlapping has lost its window."""
     lines = []
     for key, text in (
         ("leaf_slab_allocs", "Check-outs that allocated or grew the process's kept leaf slab"),
@@ -317,6 +317,12 @@ def render_leaf_slab_metrics(stats: dict) -> str:
         f"# HELP {name} v2 leaf launches dispatched while an earlier one was still unfetched (verify_v2's window of one)",
         f"# TYPE {name} counter",
         f"{name} {stats['leaf_launches_overlapped']}",
+    ]
+    name = "torrent_tpu_v2_leaf_uploads_chunked_total"
+    lines += [
+        f"# HELP {name} v2 leaf launches whose slab went up in concurrent row blocks (Pallas launches); a scan launch goes up in one put",
+        f"# TYPE {name} counter",
+        f"{name} {stats['leaf_uploads_chunked']}",
     ]
     return "\n".join(lines) + "\n"
 
